@@ -55,14 +55,7 @@ def main():
 
     os.environ["HOROVOD_TPU_PLATFORM"] = "cpu"
     import jax
-    try:
-        jax.config.update("jax_num_cpu_devices", max(args.np, 2))
-    except AttributeError:
-        # older jax: partition the host platform via XLA_FLAGS
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count="
-            f"{max(args.np, 2)}").strip()
+    jax.config.update("jax_num_cpu_devices", max(args.np, 2))
 
     import numpy as np
     import horovod_tpu as hvd
@@ -108,4 +101,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

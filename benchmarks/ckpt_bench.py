@@ -99,4 +99,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

@@ -614,17 +614,9 @@ def main():
 
     if args.cpu:
         os.environ["HOROVOD_TPU_PLATFORM"] = "cpu"
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={max(args.np, 2)}")
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
-        try:
-            jax.config.update("jax_num_cpu_devices", max(args.np, 2))
-        except AttributeError:
-            # older jax: the XLA_FLAGS partitioning above is the only
-            # way to get virtual CPU devices (tests/conftest.py note)
-            pass
+        jax.config.update("jax_num_cpu_devices", max(args.np, 2))
 
     import horovod_tpu as hvd
 
@@ -651,4 +643,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

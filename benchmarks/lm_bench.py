@@ -416,17 +416,11 @@ def main():
 
     if args.cpu:
         os.environ["HOROVOD_TPU_PLATFORM"] = "cpu"
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={args.cpu}")
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         # jax captured JAX_PLATFORMS at import; the config update is
         # what actually forces CPU on a TPU host (scaling.py idiom)
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu)
-        except AttributeError:
-            pass   # older jax: XLA_FLAGS is the only lever
+        jax.config.update("jax_num_cpu_devices", args.cpu)
 
     if args.overlap_compare:
         dp, tp, pp = parse_parallelism(args.parallelism) \
@@ -564,4 +558,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

@@ -15,8 +15,8 @@ program.
 
 MFU convention: model FLOPs = 6 * (matmul params incl. the logits
 projection) + causal attention matmuls, with NO credit for remat
-recompute — divided by the chip's measured bf16 matmul peak
-(141 TFLOP/s on this part, docs/benchmarks.md).
+recompute — divided by the PUBLISHED bf16 peak of the device the run
+is on (``PUBLISHED_PEAK_TFLOPS``, keyed by ``device_kind``).
 
     python benchmarks/lm_mfu_bench.py
     python benchmarks/lm_mfu_bench.py --raw   # plain-jit ceiling too
@@ -31,7 +31,23 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-MEASURED_PEAK_TFLOPS = 141.0          # docs/benchmarks.md matmul probe
+# Published dense bf16 peak of one chip, keyed by
+# ``jax.devices()[0].device_kind``.  A device that is not here is an
+# error, never a default: add it with its source.
+PUBLISHED_PEAK_TFLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197.0,
+}
+
+
+def published_peak_tflops(device_kind):
+    try:
+        return PUBLISHED_PEAK_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device_kind {device_kind!r}: add "
+            f"it to PUBLISHED_PEAK_TFLOPS with its source") from None
+
 
 # headline config: ~436M params (402.7M block + 32.8M embedding)
 HEADLINE = dict(vocab_size=32000, d_model=1024, n_layers=24, n_heads=8,
@@ -49,7 +65,10 @@ def lm_train_flops_per_token(cfg):
     return 6 * (n_block + n_logits) + attn
 
 
-def build(args):
+def build(args, widths=HEADLINE):
+    """(config, fixed token batch) of the headline model; ``widths``
+    swaps in a small model for a rehearsal (chip_smoke.py's CPU
+    test)."""
     import jax
     import jax.numpy as jnp
 
@@ -58,11 +77,34 @@ def build(args):
     remat = getattr(args, "remat", "dots_flash")
     cfg = TransformerConfig(dtype=jnp.bfloat16, remat=remat != "none",
                             remat_policy=remat if remat != "none"
-                            else "full", **HEADLINE)
+                            else "full", **widths)
     tokens = jax.random.randint(
         jax.random.PRNGKey(1), (args.batch, cfg.max_seq_len), 0,
         cfg.vocab_size)
     return cfg, tokens
+
+
+def model_and_loss(cfg, attention_fn=None, fused_ce=True, ce_chunks=16):
+    """(model, loss_fn(params, tokens)) of the headline objective —
+    shared with chip_smoke.py and tests/test_chip_compile.py so all
+    three train the same program.  ``attention_fn`` defaults to the
+    Pallas flash kernel."""
+    from horovod_tpu.models import TransformerLM, lm_loss, \
+        make_fused_lm_loss
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    model = TransformerLM(cfg, attention_fn=attention_fn
+                          or flash_attention)
+    if fused_ce:
+        # logits projection fused into a chunked loss: the (B, S, V)
+        # f32 logits + log-softmax (2.6 GB at B=5) never exist —
+        # the SAME objective make_lm_train_step(fused_ce=True) builds
+        return model, make_fused_lm_loss(model, n_chunks=ce_chunks)
+
+    def loss_fn(params, batch):
+        logits = model.apply({"params": params}, batch)
+        return lm_loss(logits[:, :-1], batch[:, 1:])
+    return model, loss_fn
 
 
 def bench_framework(cfg, tokens, iters, warmup, fused_ce=True,
@@ -74,38 +116,26 @@ def bench_framework(cfg, tokens, iters, warmup, fused_ce=True,
     import optax
 
     import horovod_tpu as hvd
-    from horovod_tpu.models import TransformerLM, lm_loss, \
-        make_fused_lm_loss
     from horovod_tpu.ops.pallas_kernels import flash_attention
 
     hvd.init()
-    attn = flash_attention if bwd_block is None else functools.partial(
+    attn = None if bwd_block is None else functools.partial(
         flash_attention, bwd_block_q=bwd_block, bwd_block_k=bwd_block)
-    model = TransformerLM(cfg, attention_fn=attn)
+    model, loss_fn = model_and_loss(cfg, attn, fused_ce, ce_chunks)
     params = jax.jit(model.init)(jax.random.PRNGKey(0),
                                  tokens)["params"]
-
-    if fused_ce:
-        # logits projection fused into a chunked loss: the (B, S, V)
-        # f32 logits + log-softmax (2.6 GB at B=5) never exist —
-        # the SAME objective make_lm_train_step(fused_ce=True) builds
-        loss_fn = make_fused_lm_loss(model, n_chunks=ce_chunks)
-    else:
-        def loss_fn(params, batch):
-            logits = model.apply({"params": params}, batch)
-            return lm_loss(logits[:, :-1], batch[:, 1:])
-
     step = hvd.make_compiled_train_step(loss_fn, optax.adamw(1e-3))
     state = step.init_state(params)
     staged = step.place_batch(tokens)
     for _ in range(warmup):
         state, loss = step(state, staged)
-    float(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(iters):
         state, loss = step(state, staged)
-    lv = float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
+    lv = float(loss)
     hvd.shutdown()
     return tokens.size * iters / dt, lv
 
@@ -127,11 +157,11 @@ def bench_raw(cfg, tokens, iters, warmup, fused_ce=True):
     toks = jax.device_put(tokens, tok_shd)
     for _ in range(warmup):
         state, loss = compiled(state, toks)
-    float(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(iters):
         state, loss = compiled(state, toks)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
     return tokens.size * iters / dt
 
@@ -141,19 +171,25 @@ def make_report(tps, loss, cfg, n_chips=1):
     the MFU convention and metric key cannot drift apart.  Multi-chip
     runs (``--parallelism``) report PER-CHIP tok/s and MFU against
     the single-chip peak, so the number stays comparable to the
-    headline."""
+    headline.  The peak is the published one of the device the
+    process runs on; a device without one fails the report."""
+    import jax
+
+    device_kind = jax.devices()[0].device_kind
+    peak = published_peak_tflops(device_kind)
     fpt = lm_train_flops_per_token(cfg)
     per_chip = tps / max(n_chips, 1)
     out = {
         "metric": "lm436m_train_tokens_per_sec_per_chip_hvd",
         "value": round(per_chip, 1),
         "unit": "tokens/sec",
+        "device_kind": device_kind,
         "loss": round(loss, 4),
         "model_tflops_per_sec": round(per_chip * fpt / 1e12, 2),
-        "mfu_vs_measured_peak_pct": round(
-            100 * per_chip * fpt / 1e12 / MEASURED_PEAK_TFLOPS, 1),
+        "mfu_vs_published_peak_pct": round(
+            100 * per_chip * fpt / 1e12 / peak, 1),
         "flops_per_token_g": round(fpt / 1e9, 3),
-        "peak_tflops": MEASURED_PEAK_TFLOPS,
+        "published_peak_tflops": peak,
     }
     if n_chips > 1:
         out["n_chips"] = n_chips
@@ -183,13 +219,13 @@ def bench_pipelined(cfg, tokens, iters, warmup, parallelism,
     state = init(jax.random.PRNGKey(0), tokens)
     for _ in range(warmup):
         state, loss = step(state, tokens)
-    float(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(iters):
         state, loss = step(state, tokens)
-    lv = float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
-    return tokens.size * iters / dt, lv, spec.resolved()
+    return tokens.size * iters / dt, float(loss), spec.resolved()
 
 
 def main():
@@ -255,4 +291,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

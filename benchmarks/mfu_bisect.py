@@ -27,6 +27,7 @@ import optax
 # headline bench so the two cannot drift apart
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from lm_mfu_bench import lm_train_flops_per_token as model_flops_per_token  # noqa: E402,E501
+from lm_mfu_bench import published_peak_tflops  # noqa: E402
 
 
 def time_step(cfg, mesh, tokens, impl, iters, warmup,
@@ -111,7 +112,6 @@ def main():
     p.add_argument("--vocab", type=int, default=32000)
     p.add_argument("--iters", type=int, default=8)
     p.add_argument("--warmup", type=int, default=2)
-    p.add_argument("--peak-tflops", type=float, default=141.0)
     p.add_argument("--variants",
                    default="base,novocab,dense,noremat,attn")
     p.add_argument("--remat-policy", default="full",
@@ -139,7 +139,10 @@ def main():
         jax.random.PRNGKey(1), (args.batch, args.seq), 0, 2000)
 
     fpt = model_flops_per_token(base_cfg)
-    out = {"flops_per_token_g": round(fpt / 1e9, 3)}
+    device_kind = jax.devices()[0].device_kind
+    peak = published_peak_tflops(device_kind)
+    out = {"flops_per_token_g": round(fpt / 1e9, 3),
+           "device_kind": device_kind, "published_peak_tflops": peak}
     for v in args.variants.split(","):
         v = v.strip()
         try:
@@ -186,10 +189,13 @@ def main():
             vf /= 3.0       # forward-only is 2N of the 6N convention
         out[f"{v}_tokens_per_sec"] = round(tps, 1)
         out[f"{v}_tflops"] = round(tps * vf / 1e12, 2)
-        out[f"{v}_mfu_pct"] = round(
-            100 * tps * vf / 1e12 / args.peak_tflops, 1)
+        out[f"{v}_mfu_vs_published_peak_pct"] = round(
+            100 * tps * vf / 1e12 / peak, 1)
     print(json.dumps(out))
 
 
 if __name__ == "__main__":
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

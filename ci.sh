@@ -97,7 +97,8 @@ PART2="tests/test_elastic.py tests/test_examples.py \
   tests/test_op_matrix.py \
   tests/test_ray_strategy.py tests/test_spark_streaming.py \
   tests/test_tensorflow.py"
-PART3="tests/test_parallel.py tests/test_torch.py"
+PART3="tests/test_chip_compile.py tests/test_parallel.py \
+  tests/test_torch.py"
 PART4="tests/test_aggregator.py tests/test_api_parity.py \
   tests/test_chaos.py tests/test_data_plane.py tests/test_fleet.py \
   tests/test_pallas.py tests/test_runner.py tests/test_serving.py"

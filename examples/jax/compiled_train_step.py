@@ -29,15 +29,7 @@ if args.cpu_devices:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", args.cpu_devices)
-    except AttributeError:
-        # older jax: partition the host platform via XLA_FLAGS (must
-        # land before the backends initialize)
-        _os.environ["XLA_FLAGS"] = (
-            _os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count="
-            f"{args.cpu_devices}").strip()
+    jax.config.update("jax_num_cpu_devices", args.cpu_devices)
 
 import jax.numpy as jnp
 import numpy as np

@@ -35,15 +35,7 @@ def main():
     args = parser.parse_args()
     if args.cpu_devices:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu_devices)
-        except AttributeError:
-            # older jax: partition the host platform via XLA_FLAGS (must
-            # land before the backends initialize)
-            _os.environ["XLA_FLAGS"] = (
-                _os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count="
-                f"{args.cpu_devices}").strip()
+        jax.config.update("jax_num_cpu_devices", args.cpu_devices)
 
     mesh = build_mesh(MeshSpec(dp=args.dp, sp=args.sp, tp=args.tp))
     cfg = TransformerConfig(vocab_size=1024, d_model=256, n_layers=4,

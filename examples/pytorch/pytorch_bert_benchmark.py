@@ -5,7 +5,7 @@ broadcast, synthetic token batches, sentences/sec reporting (the
 protocol of ``pytorch_synthetic_benchmark.py``, applied to BERT).
 
   python examples/pytorch/pytorch_bert_benchmark.py --tiny
-  python -m horovod_tpu.runner.launch -np 2 -- \
+  python -m horovod_tpu.runner.launch -np 2 --cpu -- \
       python examples/pytorch/pytorch_bert_benchmark.py --tiny
 """
 

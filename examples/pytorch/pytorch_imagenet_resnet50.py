@@ -5,7 +5,7 @@ batches-per-allreduce accumulation, lr warmup scaled by world size,
 rank-0 checkpointing, averaged metrics).
 
 Real data needs torchvision (gated; absent from this image):
-    python -m horovod_tpu.runner.launch -np 4 -- \
+    python -m horovod_tpu.runner.launch -np 4 --cpu -- \
         python examples/pytorch/pytorch_imagenet_resnet50.py \
         --train-dir /data/train --val-dir /data/val
 Synthetic smoke mode runs anywhere:

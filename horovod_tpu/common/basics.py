@@ -42,24 +42,13 @@ def _apply_platform_env(jax):
             jax.config.update("jax_platforms", plat)
         ncpu = os.environ.get("JAX_NUM_CPU_DEVICES")
         if ncpu:
-            try:
-                jax.config.update("jax_num_cpu_devices", int(ncpu))
-            except AttributeError:
-                # older jax spells CPU-device partitioning only as an
-                # XLA flag; an inherited flag (e.g. a parent test
-                # process forcing 8 devices) must be OVERRIDDEN, not
-                # appended to — the launcher's count is the contract
-                flags = os.environ.get("XLA_FLAGS", "")
-                flags = " ".join(
-                    f for f in flags.split()
-                    if not f.startswith(
-                        "--xla_force_host_platform_device_count"))
-                os.environ["XLA_FLAGS"] = (
-                    flags + f" --xla_force_host_platform_device_count="
-                    f"{int(ncpu)}").strip()
-    except Exception:  # noqa: BLE001 — best effort: private API moved,
-        # config absent on this jax version, or malformed env value;
-        # init proceeds with whatever jax resolves from env alone
+            # wins over an inherited XLA_FLAGS device count (e.g. a
+            # parent test process forcing 8 devices): the launcher's
+            # count is the contract
+            jax.config.update("jax_num_cpu_devices", int(ncpu))
+    except Exception:  # noqa: BLE001 — best effort: private API moved
+        # or malformed env value; init proceeds with whatever jax
+        # resolves from env alone
         return
 
 
@@ -259,17 +248,14 @@ def init(comm=None, process_sets=None, num_ranks=None, devices=None):
             if num_procs > 1 and coordinator:
                 # the TFRT CPU client can't launch cross-process
                 # computations without a collectives transport; jax's
-                # gloo implementation (when this jax has it) makes the
-                # virtual CPU mesh behave like a real multi-host TPU
-                # slice.  Must be set before the backends initialize.
-                try:
-                    if jax.config.jax_platforms in ("cpu", None) or \
-                            env_mod.get_str(
-                                env_mod.HOROVOD_TPU_PLATFORM) == "cpu":
-                        jax.config.update(
-                            "jax_cpu_collectives_implementation", "gloo")
-                except Exception:  # pragma: no cover - option missing
-                    pass
+                # gloo implementation makes the virtual CPU mesh behave
+                # like a real multi-host TPU slice.  Must be set before
+                # the backends initialize.
+                if jax.config.jax_platforms in ("cpu", None) or \
+                        env_mod.get_str(
+                            env_mod.HOROVOD_TPU_PLATFORM) == "cpu":
+                    jax.config.update(
+                        "jax_cpu_collectives_implementation", "gloo")
                 jax.distributed.initialize(
                     coordinator_address=coordinator,
                     num_processes=num_procs, process_id=proc_id,
@@ -284,18 +270,10 @@ def init(comm=None, process_sets=None, num_ranks=None, devices=None):
                 # demand a distributed client that no longer exists
                 # (make_gloo_tcp_collectives(None) TypeError) — reset
                 # it before first backend use
-                try:
-                    current = getattr(
-                        jax.config,
-                        "jax_cpu_collectives_implementation",
-                        None) or jax.config._read(
-                        "jax_cpu_collectives_implementation")
-                    if current == "gloo":
-                        jax.config.update(
-                            "jax_cpu_collectives_implementation",
-                            None)
-                except Exception:  # pragma: no cover - option missing
-                    pass
+                if jax.config.jax_cpu_collectives_implementation \
+                        == "gloo":
+                    jax.config.update(
+                        "jax_cpu_collectives_implementation", None)
             # heterogeneous host:slots jobs (reference -H h1:4,h2:2,
             # gloo_run.py:66-103) carry per-process rank counts; the
             # uniform path is the table [num_ranks] * num_procs

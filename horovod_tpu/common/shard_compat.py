@@ -1,40 +1,7 @@
-"""Canonical shard_map import shim + small axis helpers.
+"""The one import point for ``shard_map`` and ``axis_size``: every
+shard_map user in this codebase imports them from here (directly, or
+via ``parallel._shard_map`` / ``ops.xla_ops`` which re-export them), so
+a move in jax's API is repaired in exactly one place."""
 
-jax moved shard_map between releases (``jax.shard_map`` vs
-``jax.experimental.shard_map``) and renamed its replication checker
-(``check_rep`` -> ``check_vma``).  Every shard_map user in this
-codebase imports the resolved symbol from here (directly, or via
-``parallel._shard_map`` which re-exports it) so an API change is
-fixed exactly once.  Callers always pass the modern ``check_vma``
-name; the legacy wrapper renames it.
-"""
-
-from jax import lax
-
-try:
-    from jax import shard_map as _sm  # jax >= 0.6 style
-    shard_map = _sm.shard_map if hasattr(_sm, "shard_map") else _sm
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm_legacy
-    import inspect as _inspect
-
-    if "check_vma" in _inspect.signature(_sm_legacy).parameters:
-        shard_map = _sm_legacy
-    else:
-        import functools as _functools
-
-        @_functools.wraps(_sm_legacy)
-        def shard_map(f, *args, **kwargs):
-            # pre-0.6 jax spells the replication checker `check_rep`
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _sm_legacy(f, *args, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` where jax has it; the psum-of-one idiom
-    (constant-folded to the mapped axis size, no collective emitted)
-    everywhere else."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+from jax import shard_map  # noqa: F401
+from jax.lax import axis_size  # noqa: F401

@@ -3,10 +3,12 @@
 The reference binds its native core with ctypes the same way
 (``horovod/common/basics.py:29`` loads the shared lib).  If the
 library is missing it is built once with g++ (the toolchain is part of
-the image); failing that, a numpy fallback keeps everything working.
+the image); failing that, a numpy fallback keeps everything working —
+the choice is logged once at WARNING and reported by :func:`status`.
 """
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -19,52 +21,67 @@ logger = logging.getLogger("horovod_tpu")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_status = "numpy-fallback"
 
 _PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_LIB_PATH = os.path.join(_PKG_DIR, "_native", "libhvdnative.so")
+_LIB_DIR = os.path.join(_PKG_DIR, "_native")
 _SRC_DIR = os.path.join(os.path.dirname(_PKG_DIR), "csrc")
 _SRC_NAMES = ("fusion.cpp", "arena.cpp", "timeline.cpp")
 
 
 def _srcs():
-    return [os.path.join(_SRC_DIR, s) for s in _SRC_NAMES
-            if os.path.exists(os.path.join(_SRC_DIR, s))]
+    return [os.path.join(_SRC_DIR, s) for s in _SRC_NAMES]
 
 
-def _build():
-    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+def _lib_path():
+    """The library's file name carries a hash of its sources' CONTENT:
+    ``_native/`` is git-ignored, so a copied tree can bring along a
+    library built from other sources (or with any mtime), and it must
+    never be the one that loads."""
+    digest = hashlib.sha256()
+    for src in _srcs():
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    return os.path.join(_LIB_DIR,
+                        f"libhvdnative.{digest.hexdigest()[:16]}.so")
+
+
+def _build(lib_path):
+    os.makedirs(_LIB_DIR, exist_ok=True)
     # compile to a per-process temp file and rename atomically so
     # concurrently launched workers never dlopen a half-written .so
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fPIC", "-std=c++17", "-shared",
            "-o", tmp] + _srcs() + ["-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
 
 
-def _stale():
-    """Rebuild when any source is newer than the shared lib."""
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in _srcs())
+def status() -> str:
+    """How this process got its host path: ``"built"`` (compiled just
+    now), ``"loaded"`` (a library of these sources was already there)
+    or ``"numpy-fallback"``."""
+    get_lib()
+    return _status
 
 
 def get_lib():
     """Load (building if needed) the native lib; None on failure."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         try:
-            if _srcs() and _stale():
-                _build()
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib_path = _lib_path()
+            found = os.path.exists(lib_path)
+            if not found:
+                _build(lib_path)
+            lib = ctypes.CDLL(lib_path)
             lib.hvd_pack.argtypes = [
                 ctypes.POINTER(ctypes.c_void_p),
                 ctypes.POINTER(ctypes.c_int64),
@@ -113,9 +130,13 @@ def get_lib():
                         ctypes.c_double]
                 lib.hvd_tl_close.argtypes = [ctypes.c_void_p]
             _lib = lib
+            _status = "loaded" if found else "built"
         except Exception as exc:  # noqa: BLE001 — fall back to numpy
-            logger.info("native lib unavailable (%s); using numpy "
-                        "fallback", exc)
+            # (no compiler, no sources in an installed package, a
+            # library this machine cannot load): slower, same results
+            logger.warning("native host library unavailable (%r); the "
+                           "numpy path packs fusion buckets and writes "
+                           "timelines instead", exc)
             _lib = None
         return _lib
 

@@ -323,6 +323,9 @@ class _TimedFirstCall:
             self._timed = True
             _cache_metrics()[2].inc(_time.perf_counter() - t0)
 
+    def lower(self, *args):
+        return self._fn.lower(*args)
+
 
 def _shared_program(key, builder):
     hits, misses, _ = _cache_metrics()
@@ -2633,7 +2636,27 @@ class _CompiledTrainStep:
         optimizer state as FLAT dp-sharded leaves — each device holds
         1/R of every moment buffer, the ÷R memory the mode exists
         for — plus, under a quantized gradient wire, the per-rank EF
-        residual as device state."""
+        residual as device state.
+
+        Rank threads of one process get ONE state: it is replicated,
+        so the last rank to arrive builds it from the first rank's
+        ``params`` and every rank receives that object — as every rank
+        receives the one new state a step returns.  (Each thread
+        placing its own copy of a GB-scale state holds one per rank on
+        every device.)"""
+        eng, ps = _ps_state(self.process_set)
+        n_local = len(ps.executor.local_positions)
+        pos = _caller_pos(eng, ps) if n_local > 1 else None
+        if pos is None:
+            return self._build_state(params, aux)
+        rdv = _rendezvous_for(
+            ps, ("init_state",) + self._step_tag(
+                ps, basics.context().rank), n_local)
+        return rdv.run(
+            pos, (params, aux),
+            lambda slots: self._build_state(*slots[min(slots)]))
+
+    def _build_state(self, params, aux):
         if self.sharded:
             return self._init_state_sharded(params, aux)
         eng, ps = _ps_state(self.process_set)
@@ -2861,6 +2884,15 @@ class _CompiledTrainStep:
                 "rendezvous instead)")
         return StagedBatch(
             self._stage_batch(ex, {ex.local_positions[0]: batch}))
+
+    def lower(self, state, batch):
+        """``jax.stages.Lowered`` of the step's one program for
+        ``state`` and a ``place_batch``-staged ``batch``: what the
+        compiler is handed — its text shows, e.g., whether a Pallas
+        kernel went in as a ``tpu_custom_call`` or was interpreted.
+        One rank per process, like ``place_batch``."""
+        _, ps = _ps_state(self.process_set)
+        return self._program(ps.executor).lower(state, batch.tree)
 
     def __call__(self, state, batch):
         """Run one step with THIS rank's ``batch``; returns

@@ -24,8 +24,9 @@ helping (``horovod/common/ops/cuda/cuda_kernels.cu:27-292``); this is
 the TPU analogue.  Used by ``models/resnet.py`` ``ResNet(fused=True)``
 and ``bench.py``.
 
-Kernels run under ``interpret=True`` on CPU (tests) and compile to
-Mosaic on TPU.  Gradient note: the op returns ``(y, s1, s2)`` and the
+``interpret=None`` follows the process's platform
+(``pallas_kernels.default_interpret``): Mosaic on a TPU, the Pallas
+interpreter elsewhere (the CPU tests).  Gradient note: the op returns ``(y, s1, s2)`` and the
 custom VJP consumes cotangents for all three, so BN's use of the batch
 stats in the downstream fold differentiates exactly (the stats chain
 flows through ``ds1``/``ds2``).
@@ -38,14 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_kernels import default_interpret
+
 __all__ = ["conv1x1_bn", "bn_fold", "supported_m"]
-
-
-def _is_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
 
 
 # VMEM block budget for picking the M-block size: double-buffered
@@ -111,7 +107,8 @@ def _compiler_params(interpret):
     """The stage-4 backward kernels hold a (K, N) f32 grad accumulator
     (up to 8 MB) beside the weight tile — past the compiler's default
     16 MB scoped-vmem limit, well inside the part's physical VMEM
-    (measured working on the bench chip at 64 MB)."""
+    (the chip compiler takes 64 MB for a v5e:
+    tests/test_chip_compile.py)."""
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
@@ -337,7 +334,7 @@ def conv1x1_bn(x, w, fold=None, *, interpret=None, use_pallas=None):
     if not use_pallas:
         return _reference(x, a, b, w, do_fold)
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     return _conv1x1_bn(x, a, b, w, do_fold, interpret)
 
 

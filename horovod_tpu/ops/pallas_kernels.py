@@ -22,8 +22,9 @@ scale).  The TPU equivalents that XLA does NOT already fuse well:
   to the DEQUANTIZED value (straight-through), so a training step that
   fake-quantizes its gradient wire differentiates cleanly.
 
-Kernels run under ``interpret=True`` on CPU (tests) and compile to
-Mosaic on TPU.
+Every entry takes ``interpret=``; left at ``None`` it follows the
+platform the process computes on (:func:`default_interpret`): Mosaic
+on a TPU, the Pallas interpreter elsewhere (the CPU tests).
 """
 
 import functools
@@ -36,11 +37,14 @@ from jax.experimental import pallas as pl
 _NEG_INF = -1e30
 
 
-def _is_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+def default_interpret():
+    """``interpret=`` for a kernel whose caller left it ``None``:
+    False (compile to Mosaic) where the process's default backend —
+    the platform its jitted programs run on — is a TPU, True
+    elsewhere.  A backend that cannot start raises here: a process
+    that failed to get its chip must not drop to the interpreter and
+    carry on."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -57,7 +61,7 @@ def fused_scale_cast(x, factor, out_dtype=None, *, block=4096,
     ScaleBufferCudaImpl, cuda_kernels.cu half2-vectorized scale)."""
     out_dtype = out_dtype or x.dtype
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     n = x.size
     flat = x.reshape(-1)
     pad = (-n) % block
@@ -123,7 +127,7 @@ def quantize_blockwise(x, *, interpret=None):
     slice with the true length).  Same semantics as
     quantize.np_quantize_blockwise / quantize_blockwise_xla."""
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     flat, rows = _pad_to_rows(x.reshape(-1), _QBLOCK)
     xb = flat.reshape(rows, _QBLOCK)
     q, s = pl.pallas_call(
@@ -144,7 +148,7 @@ def dequantize_blockwise(q, scales, n, out_dtype=jnp.float32, *,
     """Inverse pass: (q, scales) from quantize_blockwise -> flat (n,)
     array of ``out_dtype``."""
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     rows = scales.shape[0]
     out = pl.pallas_call(
         _dequantize_kernel,
@@ -184,6 +188,21 @@ fake_quantize_blockwise.defvjp(_fq_fwd, _fq_bwd)
 # ---------------------------------------------------------------------------
 # block-scaled int4 wire codec (cross-hop / DCN wire format)
 
+def _nibble_weights(even, odd):
+    """(BLOCK, BLOCK // 2) bf16 matrix W with W[2j, j] = ``even`` and
+    W[2j + 1, j] = ``odd``.  ``codes @ W(1, 16)`` is the
+    np_pack_nibbles layout (even index low nibble).  Mosaic cannot
+    de-interleave lanes (a reshape to (..., 2) is refused), so the
+    pack and unpack ride the otherwise idle MXU; small integers are
+    exact in bf16 operands with f32 accumulation."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (_QBLOCK, _QBLOCK // 2), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (_QBLOCK, _QBLOCK // 2), 1)
+    w = jnp.where(r == 2 * c, np.float32(even),
+                  jnp.where(r == 2 * c + 1, np.float32(odd),
+                            np.float32(0.0)))
+    return w.astype(jnp.bfloat16)
+
+
 def _quantize_int4_kernel(x_ref, q_ref, s_ref):
     x = x_ref[:].astype(jnp.float32)                   # (_QROWS, BLOCK)
     absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
@@ -193,20 +212,27 @@ def _quantize_int4_kernel(x_ref, q_ref, s_ref):
         .astype(jnp.bfloat16).astype(jnp.float32)
     safe = jnp.where(scale > 0, scale, np.float32(1.0))
     q = jnp.clip(jnp.round(x / safe), -7, 7)
-    # biased-nibble pack, two codes per byte (np_pack_nibbles layout:
-    # even index low nibble) fused into the same VMEM pass
-    b = (q + 8).astype(jnp.uint8).reshape(_QROWS, _QBLOCK // 2, 2)
-    q_ref[:] = b[:, :, 0] | (b[:, :, 1] << 4)
+    # biased-nibble pack fused into the same VMEM pass:
+    # (q_even + 8) | (q_odd + 8) << 4 == q_even + 16 * q_odd + 136
+    packed = jnp.dot(q.astype(jnp.bfloat16), _nibble_weights(1, 16),
+                     preferred_element_type=jnp.float32)
+    q_ref[:] = (packed + np.float32(136.0)) \
+        .astype(jnp.int32).astype(jnp.uint8)
     s_ref[:] = scale.reshape(1, _QROWS)
 
 
 def _dequantize_int4_kernel(q_ref, s_ref, o_ref):
-    p = q_ref[:]                                  # (_QROWS, BLOCK//2)
-    lo = (p & 0x0F).astype(jnp.int8) - 8
-    hi = (p >> 4).astype(jnp.int8) - 8
-    q = jnp.stack([lo, hi], axis=-1).reshape(_QROWS, _QBLOCK)
-    x = q.astype(jnp.float32) * s_ref[:].reshape(_QROWS, 1)
-    o_ref[:] = x.astype(o_ref.dtype)
+    p = q_ref[:].astype(jnp.int32)                # (_QROWS, BLOCK//2)
+    lo = ((p & 0x0F) - 8).astype(jnp.bfloat16)
+    hi = ((p >> 4) - 8).astype(jnp.bfloat16)
+    # interleave back to (_QROWS, BLOCK): code[2j] = lo[j],
+    # code[2j + 1] = hi[j] — the transposed selection products
+    contract = (((1,), (1,)), ((), ()))
+    q = jax.lax.dot_general(lo, _nibble_weights(1, 0), contract,
+                            preferred_element_type=jnp.float32) + \
+        jax.lax.dot_general(hi, _nibble_weights(0, 1), contract,
+                            preferred_element_type=jnp.float32)
+    o_ref[:] = (q * s_ref[:].reshape(_QROWS, 1)).astype(o_ref.dtype)
 
 
 def quantize_blockwise_int4(x, *, interpret=None):
@@ -216,7 +242,7 @@ def quantize_blockwise_int4(x, *, interpret=None):
     re-reading the block from HBM.  Same semantics as
     quantize.np_quantize_blockwise_int4 / quantize_blockwise_int4_xla."""
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     flat, rows = _pad_to_rows(x.reshape(-1), _QBLOCK)
     xb = flat.reshape(rows, _QBLOCK)
     q, s = pl.pallas_call(
@@ -239,7 +265,7 @@ def dequantize_blockwise_int4(q, scales, n, out_dtype=jnp.float32, *,
     """Inverse pass: (packed, scales) from quantize_blockwise_int4 ->
     flat (n,) array of ``out_dtype`` (unpack fused with the rescale)."""
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     rows = scales.shape[0]
     out = pl.pallas_call(
         _dequantize_int4_kernel,
@@ -555,7 +581,7 @@ def flash_attention(q, k, v, *, block_q=512, block_k=512,
     ``dense_causal_attention(window=...)``.
     """
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = default_interpret()
     B, S, H, D = q.shape
     if window is not None:
         window = int(window)

@@ -1,9 +1,4 @@
-"""Package-local re-export of the shard_map shim + attention wrapper.
-
-Every shard_map user in this package imports the resolved symbol from
-here; the actual version-compat logic lives once, in
-``common/shard_compat.py`` (shared with ops/xla_ops.py).
-"""
+"""Package-local re-export of shard_map + the attention wrappers."""
 
 from functools import partial
 
@@ -23,3 +18,22 @@ def make_attention_fn(kernel, mesh, *, batch_axes=("dp", "fsdp"),
     return shard_map(partial(kernel, axis_name=seq_axis), mesh=mesh,
                      in_specs=(spec, spec, spec), out_specs=spec,
                      check_vma=False)
+
+
+def make_flash_attention_fn(mesh, *, batch_axes=("dp", "fsdp"),
+                            head_axis="tp"):
+    """The pallas flash kernel as a ``TransformerLM(attention_fn=...)``
+    under a jit over ``mesh``.  The TPU compiler refuses to partition
+    a Mosaic kernel on its own ("wrap the call in a shard_map"), so
+    each device runs the kernel on its batch/head shard of the
+    (B, S, H, D) operands; the sequence stays whole."""
+    from ..ops.pallas_kernels import flash_attention
+
+    spec = P(batch_axes, None, head_axis, None)
+
+    def attention(q, k, v, window=None):
+        return shard_map(partial(flash_attention, window=window),
+                         mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+    return attention

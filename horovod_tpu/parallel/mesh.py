@@ -23,6 +23,7 @@ ICI-adjacent dimension.  ``dp`` is outermost so multi-host DCN hops
 only carry gradient reductions.
 """
 
+import logging
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -85,14 +86,19 @@ def build_mesh(spec: Optional[MeshSpec] = None,
     devices = list(devices) if explicit else jax.devices()
     spec = spec.resolve(len(devices))
     if not explicit and devices and devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
+        try:
             arr = mesh_utils.create_device_mesh(spec.sizes(),
                                                 devices=devices)
             return Mesh(arr, AXIS_ORDER)
-        except Exception:  # noqa: BLE001 — odd topologies: row-major
-            pass
+        except NotImplementedError as exc:
+            # a logical axis that is no product of physical torus axes
+            # (a size-2 axis on a 4x4 slice): still a correct mesh in
+            # device order, but its collectives may cross the torus
+            logging.getLogger("horovod_tpu").warning(
+                "no torus assignment for mesh %s (%s); using devices "
+                "in row-major order", spec.sizes(), exc)
     arr = np.array(devices).reshape(spec.sizes())
     return Mesh(arr, AXIS_ORDER)
 
@@ -158,7 +164,7 @@ class TwoLevelPlan:
       over the host-leader devices — so intra-host traffic rides ICI
       and each host crosses DCN once.  (One in-program grouped psum
       would be preferable, but ``axis_index_groups`` is not
-      implemented under shard_map in this jax.)
+      implemented under shard_map.)
     """
 
     def __init__(self, topology, devices=None):

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-# the package-wide import shim resolves jax's moving shard_map API
-# (and maps check_vma -> check_rep on pre-0.6 jax)
 from ._shard_map import axis_size, shard_map
 
 

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.transformer import (
     TransformerConfig, TransformerLM, lm_loss, make_fused_lm_loss,
 )
+from ._shard_map import make_flash_attention_fn
 from .mesh import BATCH_AXES
 from .ring_attention import make_ring_attention_fn
 from .sharding import (
@@ -99,8 +100,7 @@ def make_lm_train_step(mesh: Mesh, cfg: TransformerConfig,
                     from .ulysses import make_ulysses_attention_fn
                     att_factory = make_ulysses_attention_fn
             elif attention_impl == "flash":
-                from ..ops.pallas_kernels import flash_attention
-                att_factory = lambda _mesh: flash_attention  # noqa: E731
+                att_factory = make_flash_attention_fn
             return make_mpmd_lm_train_step(
                 mesh, cfg, pipeline, optimizer,
                 attention_fn_factory=att_factory)
@@ -124,8 +124,8 @@ def make_lm_train_step(mesh: Mesh, cfg: TransformerConfig,
     elif attention_impl == "flash":
         # pallas flash kernel on the MXU (ops/pallas_kernels.py):
         # O(S) memory instead of the S^2 score matrix
-        from ..ops.pallas_kernels import flash_attention
-        model = TransformerLM(cfg, attention_fn=flash_attention)
+        model = TransformerLM(
+            cfg, attention_fn=make_flash_attention_fn(mesh))
     else:
         model = TransformerLM(cfg)
 

@@ -374,16 +374,21 @@ def _run_static(args):
     env = {}
     set_env_from_args(env, args)
     fusion = int((args.fusion_threshold_mb or 64) * 1024 * 1024)
-    codes = launch_procs(
-        args.command, np=args.np, hosts=args.hosts,
-        ranks_per_proc=args.ranks_per_proc, env=env,
-        platform="cpu" if args.cpu else None,
-        verbose=args.verbose, fusion_threshold_bytes=fusion,
-        start_timeout=args.start_timeout,
-        output_filename=args.output_filename,
-        # a serving fleet DEGRADES on a replica death (survivors keep
-        # answering; docs/serving.md) — only training jobs collapse
-        stop_on_failure=not getattr(args, "serve", False))
+    try:
+        codes = launch_procs(
+            args.command, np=args.np, hosts=args.hosts,
+            ranks_per_proc=args.ranks_per_proc, env=env,
+            platform="cpu" if args.cpu else None,
+            verbose=args.verbose, fusion_threshold_bytes=fusion,
+            start_timeout=args.start_timeout,
+            output_filename=args.output_filename,
+            # a serving fleet DEGRADES on a replica death (survivors
+            # keep answering; docs/serving.md) — only training jobs
+            # collapse
+            stop_on_failure=not getattr(args, "serve", False))
+    except ValueError as exc:      # a layout the launcher refuses
+        print(f"horovodrun: {exc}", file=sys.stderr)
+        return 2
     return max(codes) if codes else 0
 
 

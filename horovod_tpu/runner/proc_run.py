@@ -271,6 +271,20 @@ def launch_procs(command: List[str], np: int, hosts: str = None,
                 f"one process per host driving that many chips)")
         num_procs = np // ranks_per_proc
         slots = get_host_assignments(host_infos, num_procs)
+    if platform != "cpu":
+        # a chip belongs to one process, and nothing here gives a
+        # worker a chip of its own: every process opens every chip of
+        # its host.  The launcher cannot ask jax what the host holds
+        # (a parent that touched the backend would hold the chip), so
+        # the layout itself is refused.
+        crowded = sorted({s.hostname for s in slots if s.local_size > 1})
+        if crowded:
+            raise ValueError(
+                f"{', '.join(crowded)}: more than one worker process on "
+                f"a host, and each would open all of its chips; run "
+                f"one process per host that drives the host's chips as "
+                f"rank threads (--ranks-per-worker host, or hvd.run in "
+                f"one process), or pass --cpu")
 
     secret_hex = _secrets.token_hex(16)
     launcher_env = dict(os.environ)

@@ -1,0 +1,316 @@
+"""What can be known about the chip without one.
+
+* The TPU compiler is installed here and compiles for a chip that is
+  described, not attached: every Pallas kernel of the main path at
+  lm436m / ResNet-50 widths must be taken by it as a Mosaic
+  ``tpu_custom_call``.  Interpret mode cannot show this — the int4
+  codec passed every interpret-mode test while the compiler refused
+  it.  Nothing runs, so these say nothing about results or times.
+* ``chip_smoke.py``'s control flow, the compile-cache placement and
+  the one-process-per-chip rules, on the CPU.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described TPU v5e of a 2x2 host, the persistent compile
+    cache off around the module: a compile for a described device is
+    written to the cache but cannot be read back without a chip, and
+    the next one would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+# lm436m attention operands (benchmarks/lm_mfu_bench.py HEADLINE, B5)
+_QKV = [((5, 2048, 8, 128), jnp.bfloat16)] * 3
+# the long-context windowed shape: S8192, W1024
+_QKV_LONG = [((1, 8192, 8, 128), jnp.bfloat16)] * 3
+_N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
+# ResNet-50 b128 stage-1 1x1 conv as a matmul: (B*H*W, Cin) @ (Cin, Cout)
+_CONV = [((128 * 56 * 56, 64), jnp.bfloat16), ((64, 256), jnp.bfloat16),
+         ((1, 64), jnp.float32), ((1, 64), jnp.float32)]
+
+
+def _flash(**kw):
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False, **kw)
+    return fwd
+
+
+def _grad_of_sum(fn, n_args):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=tuple(range(n_args)))
+
+
+def _conv(x, w, a, b):
+    from horovod_tpu.ops.pallas_conv_bn import conv1x1_bn
+
+    y, s1, s2 = conv1x1_bn(x, w, (a, b), interpret=False)
+    return y.astype(jnp.float32).sum() + s1.sum() + s2.sum()
+
+
+def _kernel_cases():
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    blocks = _N // 256
+    return {
+        "flash_fwd": (_flash(), _QKV, 1),
+        "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 3),
+        "flash_window_bwd": (
+            _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 3),
+        "quantize_int8": (
+            lambda x: pk.quantize_blockwise(x, interpret=False),
+            [((_N,), jnp.float32)], 1),
+        "dequantize_int8": (
+            lambda q, s: pk.dequantize_blockwise(q, s, _N,
+                                                 interpret=False),
+            [((_N,), jnp.int8), ((blocks,), jnp.float32)], 1),
+        "quantize_int4": (
+            lambda x: pk.quantize_blockwise_int4(x, interpret=False),
+            [((_N,), jnp.float32)], 1),
+        "dequantize_int4": (
+            lambda q, s: pk.dequantize_blockwise_int4(q, s, _N,
+                                                      interpret=False),
+            [((_N // 2,), jnp.uint8), ((blocks,), jnp.float32)], 1),
+        "fused_scale_cast": (
+            lambda x: pk.fused_scale_cast(x, 0.5, jnp.bfloat16,
+                                          interpret=False),
+            [((_N,), jnp.float32)], 1),
+        "conv1x1_bn_fwd": (_conv, _CONV, 1),
+        "conv1x1_bn_bwd": (jax.grad(_conv, argnums=(0, 1, 2, 3)),
+                           _CONV, 2),
+    }
+
+
+def _lm436m_step_case():
+    """The framework's own one-chip lm436m step program
+    (ops/compiled.py), handed the described device and eval_shape
+    shapes — the stacked single-rank program chip_smoke.py runs."""
+    import argparse
+    import functools
+
+    import lm_mfu_bench as mod
+    import optax
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.ops.compiled import make_compiled_train_step
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+    from horovod_tpu.ops.xla_ops import MeshExecutor
+
+    cfg, _ = mod.build(argparse.Namespace(batch=mod.HEADLINE_BATCH))
+    tokens = ((mod.HEADLINE_BATCH, cfg.max_seq_len), jnp.int32)
+    _, loss_fn = mod.model_and_loss(cfg, functools.partial(
+        flash_attention, interpret=False))
+    optimizer = optax.adamw(1e-3)
+    step = make_compiled_train_step(loss_fn, optimizer)
+    params = jax.eval_shape(
+        lambda t: TransformerLM(cfg).init(jax.random.PRNGKey(0),
+                                          t)["params"],
+        jax.ShapeDtypeStruct(*tokens))
+    state = jax.eval_shape(
+        lambda p: {"params": p, "opt_state": optimizer.init(p)}, params)
+
+    def program(device):
+        return step._build(MeshExecutor([device], 1))
+
+    # (R=1, B, S) batch rows: the stacked single-device layout
+    return program, state, ((1,) + tokens[0], tokens[1])
+
+
+@pytest.mark.parametrize("name", [
+    "flash_fwd", "flash_bwd", "flash_window_bwd", "quantize_int8",
+    "dequantize_int8", "quantize_int4", "dequantize_int4",
+    "fused_scale_cast", "conv1x1_bn_fwd", "conv1x1_bn_bwd",
+    pytest.param("lm436m_step", marks=pytest.mark.slow)])
+def test_chip_compiler_takes(chip, name):
+    one_chip = SingleDeviceSharding(chip)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if name == "lm436m_step":
+        program, state, batch = _lm436m_step_case()
+        fn, min_calls = program(chip), 3
+        args = [jax.tree.map(lambda s: shaped(s.shape, s.dtype), state),
+                shaped(*batch)]
+    else:
+        fn, shapes, min_calls = _kernel_cases()[name]
+        fn, args = jax.jit(fn), [shaped(*s) for s in shapes]
+    # the suite runs with 64-bit types on (conftest); a chip process
+    # does not, and Mosaic has no 64-bit index
+    with jax.enable_x64(False):
+        compiled = fn.lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= min_calls
+    if name == "lm436m_step":
+        mem = compiled.memory_analysis()
+        # donated state in, the same bytes out, and the step's
+        # temporaries: what B5 needs of a 16 GB chip
+        assert mem.alias_size_in_bytes > 5e9
+        assert mem.temp_size_in_bytes < 12.5e9
+
+
+def test_int4_codec_matmul_pack_is_exact():
+    """The repaired int4 kernels pack and unpack nibbles with MXU
+    products of small integers: every code pair, at both parities,
+    round-trips bit-exactly against the numpy codec."""
+    from horovod_tpu.ops import quantize as qz
+    from horovod_tpu.ops.pallas_kernels import (
+        dequantize_blockwise_int4, quantize_blockwise_int4)
+
+    lo, hi = np.meshgrid(np.arange(-7, 8), np.arange(-7, 8))
+    codes = np.stack([lo.ravel(), hi.ravel()], axis=1).reshape(-1)
+    x = np.zeros(512, np.float32)
+    x[:codes.size] = codes          # absmax 7 per block: scale 1.0
+    x[256] = 7.0
+    qn, sn, n = qz.np_quantize_blockwise_int4(x)
+    q, s = quantize_blockwise_int4(jnp.asarray(x), interpret=True)
+    np.testing.assert_array_equal(np.asarray(q)[:qn.size], qn)
+    out = dequantize_blockwise_int4(q, s, n, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), x)
+
+
+def test_build_mesh_on_described_chips(chip, monkeypatch, caplog):
+    """On TPU devices build_mesh maps the axes onto the torus; where
+    no assignment exists (a size-2 axis on a 4x4 slice) it says so and
+    falls back to device order — and nothing else is swallowed."""
+    from jax.experimental import topologies
+
+    from horovod_tpu.parallel import mesh as mesh_mod
+
+    def described(name):
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name=name).devices
+        monkeypatch.setattr(mesh_mod.jax, "devices", lambda: devices)
+        return devices
+
+    described("v5e:2x2")
+    mesh = mesh_mod.build_mesh(dp=2, tp=2)
+    assert [d.id for d in mesh.devices.flat] == [0, 1, 3, 2]
+    assert not caplog.records
+
+    devices = described("v5e:4x4")
+    with caplog.at_level("WARNING", logger="horovod_tpu"):
+        mesh = mesh_mod.build_mesh(dp=8, tp=2)
+    assert "row-major" in caplog.text
+    assert list(mesh.devices.flat) == list(devices)
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py control flow, compile cache, one process per chip
+
+def _json_lines(text):
+    return [json.loads(line) for line in text.splitlines()
+            if line.startswith("{")]
+
+
+def test_smoke_rehearsal_passes_every_phase(hvd_shutdown, capsys):
+    """A tiny config through every one-chip phase, the kernels in the
+    interpret mode the rehearsal asks for."""
+    import chip_smoke
+
+    assert chip_smoke.run(chips=1, rehearse=True) == 0
+    lines = _json_lines(capsys.readouterr().out)
+    assert [ln.get("phase") for ln in lines[:-1]] == [
+        "environment", "native", "eager", "train"]
+    train = lines[-2]
+    assert train["program_cache_misses_after_warmup"] == 0
+    assert train["losses"][-1] < train["losses"][0]
+    assert train["tpu_custom_calls"] == 0       # interpreted, as asked
+    assert lines[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": len(jax.devices())}}
+
+
+def test_smoke_fails_off_the_chip():
+    """``python chip_smoke.py`` where the platform is not tpu: a
+    non-zero exit and ``"ok": false`` on the last line."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert "not 'tpu'" in proc.stdout
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, from_env):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from horovod_tpu.utils.compile_cache import place_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            # jax read the variable itself at import: nothing is set
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+            place_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR",
+                               raising=False)
+            fixed = os.path.join(REPO, ".jax_cache")
+            assert place_compile_cache() == fixed
+            assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
+def test_unknown_device_kind_has_no_peak():
+    import lm_mfu_bench as mod
+
+    assert mod.published_peak_tflops("TPU v5 lite") == 197.0
+    with pytest.raises(ValueError, match="no published peak"):
+        mod.published_peak_tflops(jax.devices()[0].device_kind)
+
+
+def test_importing_the_launcher_starts_no_backend():
+    """A launcher parent that touched a backend would hold the chip
+    its one worker needs."""
+    code = ("import horovod_tpu, horovod_tpu.runner.launch, "
+            "horovod_tpu.runner.proc_run\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], cwd=REPO, check=True,
+                   timeout=120)
+
+
+def test_launcher_refuses_two_chip_processes_on_one_host():
+    """Without --cpu every local worker process would open every chip
+    of the host; the launcher says so before it spawns anything."""
+    from horovod_tpu.runner.proc_run import launch_procs
+
+    with pytest.raises(ValueError, match="one process"):
+        launch_procs([sys.executable, "-c", "pass"], np=2, platform=None)

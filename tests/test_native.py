@@ -8,6 +8,25 @@ from horovod_tpu.core import native
 
 def test_native_builds_and_loads():
     assert native.available(), "native lib should build in this image"
+    assert native.status() in ("built", "loaded")
+
+
+def test_lib_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """A library built from other sources can never be the one that
+    loads: the file name carries a hash of csrc/*.cpp, not an mtime
+    comparison."""
+    import os
+    import shutil
+
+    here = native._lib_path()
+    assert os.path.exists(here)          # the loaded library's own name
+    for name in native._SRC_NAMES:
+        shutil.copy(os.path.join(native._SRC_DIR, name), tmp_path)
+    monkeypatch.setattr(native, "_SRC_DIR", str(tmp_path))
+    assert native._lib_path() == here    # same content, same library
+    with open(tmp_path / "fusion.cpp", "a") as f:
+        f.write("\n// edited\n")
+    assert native._lib_path() != here
 
 
 def test_pack_unpack_roundtrip():

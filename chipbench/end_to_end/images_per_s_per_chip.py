@@ -1,0 +1,3 @@
+"""Images trained per second per chip: the mix's samples are images."""
+
+from chipbench.end_to_end.samples_per_s_per_chip import read  # noqa: F401

@@ -1,0 +1,169 @@
+#!/usr/bin/env python3
+"""Read the numbers a cell's limits are set from, on the chip, in one
+process: for each seed the program's first steps against the float32
+reference (the sound readings), and for the first ``--control`` seeds
+the reference computed in the control's precision against itself (the
+readings the limit has to stay under).
+
+    python3 chipbench/tools/calibrate.py --workload <cell> \\
+        --seeds 101,102,... --control 3 [--rehearse 1]
+
+Prints one JSON line a seed, then the largest sound and the smallest
+control reading of every number compared.  The benchmark's own runs
+never run this.
+
+A cell of several ranks is read on one chip (several seeds' states of
+several ranks in one process hung the machine once): ``--control-only
+1`` reads the control, which needs no program, and ``--one-chip 1`` the
+sound readings from a stand-in for the ranks' step: the program's own
+loss function and optimizer, the ranks' rows one rank after another,
+their gradients averaged as the step's allreduce averages them.  Read a
+few of its seeds in the cell's own runs too: they have to agree.
+"""
+
+import argparse
+import functools
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+
+def ranks_on_one_chip(c, key, batch):
+    """What ``Cell.first_steps`` finds, for a cell of several ranks
+    whose adapter has ``loss_fn`` and ``optimizer``, without the ranks:
+    each rank's loss and gradient from the program's loss function on
+    its own rows, the means of both over the ranks, one update of the
+    program's optimizer, and the loss after it."""
+    import jax
+    import optax
+
+    from chipbench import weights
+
+    n = c.ranks
+    loss_fn = c.adapter.loss_fn(c.config, c.workload, c.rehearse)
+    optimizer = c.adapter.optimizer(c.workload)
+    shards = [jax.tree.map(
+        lambda a: a.reshape((n, -1) + a.shape[1:])[rank], batch)
+        for rank in range(n)]
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def add(total, params, shard):
+        loss, grads = jax.value_and_grad(loss_fn)(params, shard)
+        return {"loss": total["loss"] + loss / n,
+                "grads": jax.tree.map(lambda t, g: t + g / n,
+                                      total["grads"], grads)}
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def update(params, grads):
+        updates, _ = optimizer.update(grads, optimizer.init(params), params)
+        return optax.apply_updates(params, updates), \
+            weights.leaf_norms(grads)
+
+    params, _ = c.make_weights(key)
+    total = {"loss": 0.0, "grads": jax.tree.map(jax.numpy.zeros_like,
+                                                params)}
+    for shard in shards:
+        total = add(total, params, shard)
+    losses = [float(total["loss"])]
+    params, grad_norms = update(params, total.pop("grads"))
+    delta_norms = jax.jit(lambda p, k: weights.leaf_norms(jax.tree.map(
+        lambda a, b: a - b, p, weights.make(k, c.spec))))(params, key)
+    if c.workload.get("check_loss_after"):
+        loss_only = jax.jit(loss_fn)
+        losses.append(sum(float(loss_only(params, shard))
+                          for shard in shards) / n)
+    return {"losses": losses, "grad_norms": jax.device_get(grad_norms),
+            "delta_norms": jax.device_get(delta_norms)}
+
+
+def main():
+    from chipbench import run as harness
+
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seeds", required=True)
+    parser.add_argument("--control", type=int, default=3,
+                        help="read the control too on this many seeds, "
+                             "the first")
+    parser.add_argument("--rehearse", type=int, default=0)
+    parser.add_argument("--control-only", type=int, default=0,
+                        help="1: only the control against the reference, "
+                             "which takes one chip whatever the cell has")
+    parser.add_argument("--one-chip", type=int, default=0,
+                        help="1: a cell of several ranks on one chip, by "
+                             "ranks_on_one_chip")
+    args = parser.parse_args()
+    seeds = [int(s) for s in args.seeds.split(",")]
+    one_chip = args.control_only or args.one_chip
+    c = harness.Cell(args.workload, args.rehearse, need_chips=not one_chip)
+
+    import jax
+
+    from chipbench import weights
+
+    rows = []
+
+    def numbers(found, ref):
+        return {name: value
+                for name, value, _, _ in harness.gaps(found, ref, c.limits)}
+
+    def read(seed, with_control, program):
+        key = weights.seed_key(seed)
+        batch = c.make_batch(key)
+        ref = c.follow_reference(key, batch)
+        row = {"seed": seed}
+        if with_control:
+            row["control"] = numbers(
+                c.follow_reference(key, batch, c.control), ref)
+        if program:
+            found = program(key, batch)
+            if found:
+                row["sound"] = numbers(found, ref)
+                row["losses"] = {"program": found["losses"],
+                                 "reference": ref["losses"]}
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    if one_chip:
+        for i, seed in enumerate(seeds):
+            read(seed, args.control_only or i < args.control,
+                 None if args.control_only
+                 else functools.partial(ranks_on_one_chip, c))
+    else:
+        shared = harness.Shared(c.ranks)
+
+        def drive(rank, n_ranks):
+            lead = rank == 0
+
+            def program(key, batch):
+                step, state, staged = c.start(key, batch, rank, lead)
+                return c.first_steps(step, state, staged, key, lead,
+                                     shared)[1]
+
+            for i, seed in enumerate(seeds):
+                if lead:
+                    read(seed, i < args.control, program)
+                else:
+                    key = weights.seed_key(seed)
+                    program(key, c.make_batch(key))
+                shared.sync()
+
+        c.adapter.launch(c.workload, shared.guarded(drive))
+
+    summary = {}
+    for name in rows[0].get("sound") or rows[0]["control"]:
+        sound = [r["sound"][name] for r in rows if "sound" in r]
+        controls = [r["control"][name] for r in rows if "control" in r]
+        summary[name] = {
+            "sound_largest": max(sound) if sound else None,
+            "control_smallest": min(controls) if controls else None}
+    print(json.dumps({"summary": summary, "seeds": len(rows),
+                      "device": jax.devices()[0].device_kind}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

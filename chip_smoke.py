@@ -65,30 +65,6 @@ def emit(phase, **fields):
     print(json.dumps({"phase": phase, **fields}), flush=True)
 
 
-class CompileCacheEvents:
-    """Counts jax's persistent-compilation-cache reads that hit and
-    entries written while the block runs."""
-
-    HIT = "/jax/compilation_cache/cache_hits"
-    WRITE = "/jax/compilation_cache/cache_misses"
-
-    def __enter__(self):
-        import jax
-
-        self.hits = self.writes = 0
-        jax.monitoring.register_event_listener(self._on_event)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-    def _on_event(self, event, **_):
-        self.hits += event == self.HIT
-        self.writes += event == self.WRITE
-
-
 def device_memory():
     import jax
 
@@ -242,13 +218,20 @@ def phase_train_one_chip(cfg, tokens, attn, steps, rehearse):
         state = step.init_state(params)
         del params              # the step donates the state's buffers
         staged = step.place_batch(tokens)
-        with CompileCacheEvents() as cache:
-            t0 = time.perf_counter()
-            state, loss = step(state, staged)
-            losses = [float(loss)]          # fetching the value syncs
-            first_step = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        state, loss = step(state, staged)
+        losses = [float(loss)]              # fetching the value syncs
+        first_step = time.perf_counter() - t0
         compile_seconds = telemetry.counter_total(
             "horovod_compile_seconds_total")
+        # the first call by stage, and what the persistent compile
+        # cache did in it (the program's own listener)
+        stages = {stage: round(telemetry.counter_total(
+            f"horovod_compile_{stage}_seconds_total"), 2)
+            for stage in ("trace", "lower", "backend", "cache_read")}
+        cache_hits, cache_writes = (int(telemetry.counter_total(
+            f"horovod_compile_cache_{what}_total"))
+            for what in ("hits", "writes"))
         misses = telemetry.counter_total(
             "horovod_program_cache_misses_total")
         step_seconds = []
@@ -269,9 +252,10 @@ def phase_train_one_chip(cfg, tokens, attn, steps, rehearse):
          **lm_describe(cfg, tokens), params=int(n_params), steps=steps,
          losses=[round(v, 4) for v in losses],
          compile_seconds=round(compile_seconds, 2),
-         step_compile="cache-hit" if cache.hits and not cache.writes
+         compile_stage_seconds=stages,
+         step_compile="cache-hit" if cache_hits and not cache_writes
          else "compiled",
-         compile_cache={"hits": cache.hits, "writes": cache.writes},
+         compile_cache={"hits": cache_hits, "writes": cache_writes},
          first_step_seconds=round(first_step, 2),
          step_seconds_of_a_smoke_not_a_benchmark=step_seconds,
          tpu_custom_calls=custom_calls,

@@ -352,7 +352,8 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         emb = self.param("embed", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
-        x = emb[tokens].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = emb[tokens].astype(cfg.dtype)
         angles = jnp.asarray(
             rope_angles(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta))
         angles = jax.lax.dynamic_slice_in_dim(
@@ -398,9 +399,10 @@ class TransformerLM(nn.Module):
         # logits matmul in the activation dtype with f32 accumulation:
         # a (B*S, M) @ (M, V) f32 matmul would run at a fraction of the
         # MXU's bf16 rate and dominate the step at large vocab
-        logits = jnp.einsum("bsm,vm->bsv", x,
-                            emb.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bsm,vm->bsv", x,
+                                emb.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
         return logits
 
 
@@ -504,7 +506,6 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
         raise ValueError(f"seq len {s} not divisible by n_chunks "
                          f"{n_chunks}")
     c = s // n_chunks
-    embd = emb.astype(x.dtype)
     if weights is None:
         weights = jnp.ones((b, s), jnp.float32)
 
@@ -525,12 +526,14 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
         return jnp.moveaxis(a.reshape(b, n_chunks, c, *a.shape[2:]),
                             1, 0)
 
-    total, _ = jax.lax.scan(
-        body, jnp.zeros((), jnp.float32),
-        (chunked(x), chunked(targets), chunked(weights)))
-    denom = jnp.sum(weights)
-    # all-padding batches (weight sum 0) yield loss 0, not 0/0 = NaN
-    return total / jnp.where(denom > 0, denom, 1.0)
+    with jax.named_scope("lm_head_ce"):
+        embd = emb.astype(x.dtype)
+        total, _ = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (chunked(x), chunked(targets), chunked(weights)))
+        denom = jnp.sum(weights)
+        # all-padding batches (weight sum 0) yield loss 0, not 0/0 = NaN
+        return total / jnp.where(denom > 0, denom, 1.0)
 
 
 def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16):

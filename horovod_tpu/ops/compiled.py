@@ -35,8 +35,10 @@ Two deliverables live here:
   fuses.
 """
 
+import contextlib
 import logging
 import threading
+import time
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -51,6 +53,8 @@ from ..common import basics
 from ..common.process_sets import ProcessSet, global_process_set
 from ..common.topology import normalize_algorithm, plan_decomposition
 from ..core.message import Adasum, Average, ReduceOp, Sum
+from ..telemetry import programs
+from ..utils import profiler
 from . import adasum as adasum_ops
 from . import quantize as quantize_mod
 from .xla_ops import shard_map, _is_float
@@ -64,6 +68,16 @@ __all__ = [
 ]
 
 logger = logging.getLogger("horovod_tpu")
+
+# ``jax.named_scope`` names of the compiled train step's parts: a
+# stable contract (docs/observability.md), read from the ``op_name`` of
+# the program's instructions.  Inside ``loss_and_grad`` jax's own
+# ``jvp(...)`` / ``transpose(jvp(...))`` tell forward from backward and
+# ``checkpoint`` / ``rematted_computation`` mark recomputation.
+SCOPE_LOSS_AND_GRAD = "hvd_step/loss_and_grad"
+SCOPE_GRAD_REDUCE = "hvd_step/grad_reduce"
+SCOPE_AUX_REDUCE = "hvd_step/aux_reduce"
+SCOPE_OPTIMIZER = "hvd_step/optimizer"
 
 
 @dataclass(frozen=True)
@@ -176,10 +190,14 @@ class _Rendezvous:
         self._result = None
         self._computing = None     # generation the leader is running
         self._generation = 0
+        self._arrivals = {}        # {pos: perf_counter at arrival}
 
-    def run(self, pos, value, fn):
+    def run(self, pos, value, fn, wait_seconds=None):
         """Deliver ``value`` for participant ``pos``; returns ``fn``'s
-        result (computed once per generation on the full slot dict)."""
+        result (computed once per generation on the full slot dict).
+        ``wait_seconds`` (a counter child) gets, from the last arrival,
+        every participant's time from its own arrival to that one: the
+        skew between the threads, not the launch they then wait for."""
         with self._cond:
             gen = self._generation
             if pos in self._slots:
@@ -187,8 +205,14 @@ class _Rendezvous:
                     f"participant {pos} entered the compiled collective "
                     "twice in one round (peer missing?)")
             self._slots[pos] = value
+            if wait_seconds is not None:
+                self._arrivals[pos] = time.perf_counter()
             if len(self._slots) == self.n:
                 slots, self._slots = self._slots, {}
+                if wait_seconds is not None:
+                    arrivals, self._arrivals = self._arrivals, {}
+                    wait_seconds.inc(sum(arrivals[pos] - t
+                                         for t in arrivals.values()))
                 self._computing = gen
                 try:
                     self._result = (fn(slots), None)
@@ -199,21 +223,27 @@ class _Rendezvous:
                 self._generation = gen + 1
                 self._cond.notify_all()
             else:
-                while self._generation == gen:
-                    if not self._cond.wait(timeout=self.ARRIVAL_TIMEOUT) \
-                            and self._generation == gen \
-                            and self._computing != gen:
-                        # leader never formed: a peer is missing.  Take
-                        # our stale delivery back so a caller-level
-                        # retry re-enters cleanly.
-                        self._slots.pop(pos, None)
-                        raise RuntimeError(
-                            "compiled collective rendezvous timed out "
-                            "(a local rank never arrived)")
+                with _span("rendezvous wait") \
+                        if wait_seconds is not None else _NO_SPAN:
+                    self._await_leader(pos, gen)
             result, err = self._result
             if err is not None:
                 raise err
             return result
+
+    def _await_leader(self, pos, gen):
+        while self._generation == gen:
+            if not self._cond.wait(timeout=self.ARRIVAL_TIMEOUT) \
+                    and self._generation == gen \
+                    and self._computing != gen:
+                # leader never formed: a peer is missing.  Take
+                # our stale delivery back so a caller-level
+                # retry re-enters cleanly.
+                self._slots.pop(pos, None)
+                self._arrivals.pop(pos, None)
+                raise RuntimeError(
+                    "compiled collective rendezvous timed out "
+                    "(a local rank never arrived)")
 
 
 def _caller_pos(eng, ps):
@@ -298,42 +328,143 @@ def _cache_metrics():
     return cached
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(phase, seconds=None, beside=None):
+    """The one way this file opens a host span: ``hvd: <phase>`` in
+    the jax profiler's trace, and the elapsed seconds into the counter
+    child ``seconds`` (see ``utils/profiler.annotate``)."""
+    return profiler.annotate("hvd: " + phase, seconds, beside)
+
+
+def _step_metrics():
+    """(calls, rendezvous wait seconds, stage-batch seconds, staged
+    bytes, program-call seconds) counter children of the compiled
+    train step, resolved once per registry like ``_cache_metrics``."""
+    from .. import telemetry
+
+    reg = telemetry.registry()
+    cached = getattr(reg, "_compiled_step_metrics", None)
+    if cached is None:
+        cached = tuple(
+            reg.counter(name, help_text).labels()
+            for name, help_text in (
+                (telemetry.STEP_CALLS_FAMILY,
+                 telemetry.STEP_CALLS_HELP),
+                (telemetry.STEP_RENDEZVOUS_WAIT_FAMILY,
+                 telemetry.STEP_RENDEZVOUS_WAIT_HELP),
+                (telemetry.STEP_STAGE_BATCH_FAMILY,
+                 telemetry.STEP_STAGE_BATCH_HELP),
+                (telemetry.STEP_STAGED_BYTES_FAMILY,
+                 telemetry.STEP_STAGED_BYTES_HELP),
+                (telemetry.STEP_PROGRAM_CALL_FAMILY,
+                 telemetry.STEP_PROGRAM_CALL_HELP)))
+        reg._compiled_step_metrics = cached
+    return cached
+
+
+def _abstract(x):
+    """Shape, dtype and placement of an argument, without its array."""
+    if hasattr(x, "shape") and hasattr(x, "dtype"):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None))
+    return x
+
+
 class _TimedFirstCall:
-    """Wraps a fresh jitted program so its FIRST invocation — the one
-    that pays the XLA compile — lands in
-    ``horovod_compile_seconds_total``.  jax.jit is lazy, so timing the
-    builder alone would record microseconds of tracing setup and miss
-    the multi-second compile the metric exists to surface."""
+    """Wraps a fresh jitted program so its FIRST invocation lands in
+    ``horovod_compile_seconds_total``: everything that call pays
+    before it returns, which is the trace, the lowering, the XLA
+    compile or the persistent cache's read (booked apart in the
+    ``horovod_compile_*_seconds_total`` stage families by
+    ``telemetry.first_call``) and the enqueue of the first execution.
+    jax.jit is lazy, so timing the builder alone would record
+    microseconds of tracing setup and miss the multi-second compile
+    the metric exists to surface.
 
-    __slots__ = ("_fn", "_timed")
+    It keeps the abstract arguments of that call (shapes, dtypes,
+    shardings; no arrays) and answers ``report()`` from them, lazily."""
 
-    def __init__(self, fn):
+    __slots__ = ("_fn", "_scope", "_timed", "_args", "_report")
+
+    def __init__(self, fn, scope=None):
         self._fn = fn
+        self._scope = scope     # a ``jax.named_scope`` the program has
         self._timed = False
+        self._args = self._report = None
 
     def __call__(self, *args):
         if self._timed:
             return self._fn(*args)
-        import time as _time
+        from .. import telemetry
 
-        t0 = _time.perf_counter()
+        # before the call: it may donate, and delete, the arrays
+        self._args = jax.tree.map(_abstract, args)
+        telemetry.keep_program(self)
+        t0 = time.perf_counter()
         try:
-            return self._fn(*args)
+            with telemetry.first_call():
+                return self._fn(*args)
         finally:
             self._timed = True
-            _cache_metrics()[2].inc(_time.perf_counter() - t0)
+            _cache_metrics()[2].inc(time.perf_counter() - t0)
 
     def lower(self, *args):
         return self._fn.lower(*args)
 
+    def report(self):
+        """What the compiled program says about itself, or ``None``
+        before its first call: ``module`` (the HLO module's name, as a
+        device trace prints it before the fingerprint), ``scopes``
+        (``telemetry.programs.instruction_scopes`` of the optimized
+        module: the join
+        between a trace's events and the ``jax.named_scope`` names),
+        ``memory`` (bytes of ``memory_analysis()``: argument, output,
+        temp, alias, generated_code) and ``cost`` (``flops``,
+        ``bytes_accessed`` of ``cost_analysis()``).  Computed at the
+        first request, from the program lowered and compiled for the
+        first call's abstract arguments (jax hands back the lowering
+        and the executable it holds for them; where it has dropped
+        them, a retrace and a read of the persistent compile cache),
+        then kept."""
+        if self._report is None and self._args is not None:
+            lowered = self._fn.lower(*self._args)
+            compiled = lowered.compile()
+            text = compiled.as_text()
+            scopes = programs.instruction_scopes(text)
+            if self._scope is not None and not any(
+                    self._scope in path for path in scopes.values()):
+                # a scope the program is known to name is missing:
+                # jax's persistent compile cache leaves metadata out of
+                # its key, so it hands back an executable that another
+                # version of this code compiled, under that version's
+                # names.  Compile once more past the cache (an option
+                # at its default changes the key and nothing else; the
+                # floor on the compile time keeps the copy from being
+                # written)
+                floor = "jax_persistent_cache_min_compile_time_secs"
+                kept = getattr(jax.config, floor)
+                jax.config.update(floor, float("inf"))
+                try:
+                    compiled = lowered.compile(compiler_options={
+                        "xla_embed_ir_in_executable": False})
+                finally:
+                    jax.config.update(floor, kept)
+                text = compiled.as_text()
+                scopes = programs.instruction_scopes(text)
+            self._report = programs.executable_report(
+                compiled, text, scopes)
+        return self._report
 
-def _shared_program(key, builder):
+
+def _shared_program(key, builder, scope=None):
     hits, misses, _ = _cache_metrics()
     with _PROGRAM_LOCK:
         prog = _PROGRAM_CACHE.get(key)
         if prog is None:
             misses.inc()
-            prog = _TimedFirstCall(builder())
+            prog = _TimedFirstCall(builder(), scope)
             _PROGRAM_CACHE[key] = prog
         else:
             hits.inc()
@@ -1400,14 +1531,10 @@ class _BucketStream:
                     eng, ps, self._tag(), (self.sig, bb))
                 with red._lock:
                     red._validated.add(vkey)
-            import contextlib
-
-            from ..utils import profiler
-
-            span = timeline.span(f"compiled.{red.name or 'reduce'}",
-                                 "COMPILED_ALLREDUCE") \
-                if timeline is not None else contextlib.nullcontext()
-            with span, profiler.annotate("hvd_compiled_dispatch"):
+            with _span("compiled dispatch", beside=timeline.span(
+                    f"compiled.{red.name or 'reduce'}",
+                    "COMPILED_ALLREDUCE")
+                    if timeline is not None else None):
                 staged = []
                 for j in range(len(mp)):
                     rows = [slot_values[p][1][j]
@@ -1882,8 +2009,7 @@ class CompiledAlltoall:
                         _EF_STATE[ef_key] = res
                     self._ef_keys.add(ef_key)
                 staged.append(res)
-            from ..utils import profiler
-            with profiler.annotate("hvd_compiled_alltoall"):
+            with _span("compiled alltoall"):
                 # jax dispatch is asynchronous: device futures come
                 # back while the exchange runs
                 return prog(*staged)
@@ -2221,19 +2347,23 @@ class _CompiledTrainStep:
         if ex.shard_mode:
             def body(state, batch_rows):
                 batch = jax.tree.map(lambda x: x[0], batch_rows)
-                loss, new_aux, grads = grad_call(
-                    state["params"], state.get("aux"), batch)
-                grads = jax.tree.map(reduce_leaf_sharded, grads)
-                loss = lax.pmean(loss, "hvd")
+                with jax.named_scope(SCOPE_LOSS_AND_GRAD):
+                    loss, new_aux, grads = grad_call(
+                        state["params"], state.get("aux"), batch)
+                with jax.named_scope(SCOPE_GRAD_REDUCE):
+                    grads = jax.tree.map(reduce_leaf_sharded, grads)
+                    loss = lax.pmean(loss, "hvd")
                 if has_aux:
                     # cross-replica averaged aux (float leaves): the
                     # sync-BN convention for running statistics; other
                     # dtypes are taken as replicated
-                    new_aux = jax.tree.map(
-                        lambda a: lax.pmean(a, "hvd")
-                        if _is_float(a.dtype) else a, new_aux)
-                params, opt_state = update(
-                    state["params"], state["opt_state"], grads)
+                    with jax.named_scope(SCOPE_AUX_REDUCE):
+                        new_aux = jax.tree.map(
+                            lambda a: lax.pmean(a, "hvd")
+                            if _is_float(a.dtype) else a, new_aux)
+                with jax.named_scope(SCOPE_OPTIMIZER):
+                    params, opt_state = update(
+                        state["params"], state["opt_state"], grads)
                 return pack(params, opt_state, new_aux), loss
 
             # check_vma=False: jax 0.9's varying-manual-axes checker
@@ -2247,27 +2377,32 @@ class _CompiledTrainStep:
                              check_vma=False)
         else:
             def prog(state, batch_rows):   # stacked: (R, ...) leaves
-                losses, new_aux, grads = jax.vmap(
-                    lambda b: grad_call(state["params"],
-                                        state.get("aux"), b))(batch_rows)
-                if op == Average:
-                    grads = jax.tree.map(lambda g: jnp.mean(g, axis=0),
-                                         grads)
-                elif op == Sum:
-                    grads = jax.tree.map(lambda g: jnp.sum(g, axis=0),
-                                         grads)
-                else:       # Adasum over the stacked rank axis
-                    grads = jax.tree.map(adasum_ops.adasum_reduce,
-                                         grads)
-                loss = jnp.mean(losses)
+                with jax.named_scope(SCOPE_LOSS_AND_GRAD):
+                    losses, new_aux, grads = jax.vmap(
+                        lambda b: grad_call(
+                            state["params"], state.get("aux"), b)
+                    )(batch_rows)
+                with jax.named_scope(SCOPE_GRAD_REDUCE):
+                    if op == Average:
+                        grads = jax.tree.map(
+                            lambda g: jnp.mean(g, axis=0), grads)
+                    elif op == Sum:
+                        grads = jax.tree.map(
+                            lambda g: jnp.sum(g, axis=0), grads)
+                    else:       # Adasum over the stacked rank axis
+                        grads = jax.tree.map(adasum_ops.adasum_reduce,
+                                             grads)
+                    loss = jnp.mean(losses)
                 if has_aux:
-                    new_aux = jax.tree.map(
-                        lambda a: jnp.mean(a, axis=0)
-                        if _is_float(a.dtype) else a[0], new_aux)
+                    with jax.named_scope(SCOPE_AUX_REDUCE):
+                        new_aux = jax.tree.map(
+                            lambda a: jnp.mean(a, axis=0)
+                            if _is_float(a.dtype) else a[0], new_aux)
                 else:
                     new_aux = None
-                params, opt_state = update(
-                    state["params"], state["opt_state"], grads)
+                with jax.named_scope(SCOPE_OPTIMIZER):
+                    params, opt_state = update(
+                        state["params"], state["opt_state"], grads)
                 return pack(params, opt_state, new_aux), loss
 
         donate = (0,) if self.donate else ()
@@ -2515,100 +2650,107 @@ class _CompiledTrainStep:
         def body(state, batch_rows):
             batch = jax.tree.map(lambda x: x[0], batch_rows)
             params = state["params"]
-            loss, new_aux, grads = grad_call(params,
-                                             state.get("aux"), batch)
-            loss = lax.pmean(loss, "hvd") if hint is None else \
-                lax.pmean(lax.pmean(loss, ax_in), ax_out)
+            with jax.named_scope(SCOPE_LOSS_AND_GRAD):
+                loss, new_aux, grads = grad_call(
+                    params, state.get("aux"), batch)
+            with jax.named_scope(SCOPE_GRAD_REDUCE):
+                loss = lax.pmean(loss, "hvd") if hint is None else \
+                    lax.pmean(lax.pmean(loss, ax_in), ax_out)
             if has_aux:
-                new_aux = jax.tree.map(
-                    lambda a: lax.pmean(a, "hvd")
-                    if hint is None and _is_float(a.dtype) else
-                    (lax.pmean(lax.pmean(a, ax_in), ax_out)
-                     if _is_float(a.dtype) else a), new_aux)
+                with jax.named_scope(SCOPE_AUX_REDUCE):
+                    new_aux = jax.tree.map(
+                        lambda a: lax.pmean(a, "hvd")
+                        if hint is None and _is_float(a.dtype) else
+                        (lax.pmean(lax.pmean(a, ax_in), ax_out)
+                         if _is_float(a.dtype) else a), new_aux)
             leaves, treedef = jax.tree.flatten(grads)
             p_leaves = jax.tree.leaves(params)
             ef_in = state.get("grad_ef")
             ef_leaves = jax.tree.leaves(ef_in) if ef_in is not None \
                 else [None] * len(leaves)
             shard_g, shard_p, new_ef = [], [], []
-            for g, p, r in zip(leaves, p_leaves, ef_leaves):
-                n = g.size
-                pad = self._shard_pad(n, R)
-                # bucket-granular rs (the overlap tentpole, sharded
-                # flavor): segment the flat leaf so XLA gets
-                # bucket-sized collectives to pipeline against the
-                # remaining backward — segments are whole shard
-                # units, so the reduction is bitwise identical to
-                # the unsegmented program
-                segs = self._seg_bounds(pad, R, hint)
-                flat = jnp.pad(g.reshape(-1).astype(jnp.float32),
-                               (0, pad - n))
-                if quant and hint is not None:
-                    y, nr = scatter_quant_2d(flat, r.reshape(-1))
-                    new_ef.append(nr.reshape(r.shape))
-                elif quant:
-                    rr = r.reshape(-1)
-                    if len(segs) == 1:
-                        y, nr = scatter_quant(flat, rr)
+            # the reducescatter (and this rank's slice of the parameters)
+            with jax.named_scope(SCOPE_GRAD_REDUCE):
+                for g, p, r in zip(leaves, p_leaves, ef_leaves):
+                    n = g.size
+                    pad = self._shard_pad(n, R)
+                    # bucket-granular rs (the overlap tentpole, sharded
+                    # flavor): segment the flat leaf so XLA gets
+                    # bucket-sized collectives to pipeline against the
+                    # remaining backward — segments are whole shard
+                    # units, so the reduction is bitwise identical to
+                    # the unsegmented program
+                    segs = self._seg_bounds(pad, R, hint)
+                    flat = jnp.pad(g.reshape(-1).astype(jnp.float32),
+                                   (0, pad - n))
+                    if quant and hint is not None:
+                        y, nr = scatter_quant_2d(flat, r.reshape(-1))
+                        new_ef.append(nr.reshape(r.shape))
+                    elif quant:
+                        rr = r.reshape(-1)
+                        if len(segs) == 1:
+                            y, nr = scatter_quant(flat, rr)
+                        else:
+                            ys, nrs = zip(*[
+                                scatter_quant(flat[s:e], rr[s:e])
+                                for s, e in segs])
+                            y, nr = jnp.concatenate(ys), \
+                                jnp.concatenate(nrs)
+                        new_ef.append(nr.reshape(r.shape))
                     else:
-                        ys, nrs = zip(*[
-                            scatter_quant(flat[s:e], rr[s:e])
-                            for s, e in segs])
-                        y, nr = jnp.concatenate(ys), \
-                            jnp.concatenate(nrs)
-                    new_ef.append(nr.reshape(r.shape))
-                else:
+                        if len(segs) == 1:
+                            y, _ = scatter_plain(flat)
+                        else:
+                            y = jnp.concatenate(
+                                [scatter_plain(flat[s:e])[0]
+                                 for s, e in segs])
+                    if op == Average:
+                        y = y * np.float32(1.0 / R)
+                    shard_g.append(y)
+                    pflat = jnp.pad(p.reshape(-1), (0, pad - n))
                     if len(segs) == 1:
-                        y, _ = scatter_plain(flat)
+                        shard_p.append(lax.dynamic_slice(
+                            pflat, (shard_start(pad),), (pad // R,)))
                     else:
-                        y = jnp.concatenate(
-                            [scatter_plain(flat[s:e])[0]
-                             for s, e in segs])
-                if op == Average:
-                    y = y * np.float32(1.0 / R)
-                shard_g.append(y)
-                pflat = jnp.pad(p.reshape(-1), (0, pad - n))
-                if len(segs) == 1:
-                    shard_p.append(lax.dynamic_slice(
-                        pflat, (shard_start(pad),), (pad // R,)))
-                else:
-                    # segment-major ownership: this rank's shard is
-                    # its slice of EACH segment, concatenated — the
-                    # layout _init_state_sharded permutes the flat
-                    # opt-state leaves into
-                    shard_p.append(jnp.concatenate(
-                        [lax.dynamic_slice(
-                            pflat, (s + shard_start(e - s),),
-                            ((e - s) // R,)) for s, e in segs]))
-            shard_g_tree = jax.tree.unflatten(treedef, shard_g)
-            shard_p_tree = jax.tree.unflatten(treedef, [
-                sp.astype(pl.dtype)
-                for sp, pl in zip(shard_p, p_leaves)])
-            updates, opt2 = optimizer.update(
-                jax.tree.map(lambda y, pl: y.astype(pl.dtype),
-                             shard_g_tree, shard_p_tree),
-                state["opt_state"], shard_p_tree)
-            new_shard = optax.apply_updates(shard_p_tree, updates)
-            out_leaves = []
-            for u, p in zip(jax.tree.leaves(new_shard), p_leaves):
-                pad = self._shard_pad(p.size, R)
-                segs = self._seg_bounds(pad, R, hint)
-                if len(segs) == 1:
-                    full = gather_shard(u)
-                else:
-                    # segment-granular ag, mirroring the scatter:
-                    # each segment's gather reassembles that
-                    # contiguous range, concat restores leaf order
-                    off, fulls = 0, []
-                    for s, e in segs:
-                        mi = (e - s) // R
-                        fulls.append(gather_shard(
-                            lax.dynamic_slice(u, (off,), (mi,))))
-                        off += mi
-                    full = jnp.concatenate(fulls)
-                out_leaves.append(
-                    full[:p.size].reshape(p.shape).astype(p.dtype))
-            new_params = jax.tree.unflatten(treedef, out_leaves)
+                        # segment-major ownership: this rank's shard is
+                        # its slice of EACH segment, concatenated — the
+                        # layout _init_state_sharded permutes the flat
+                        # opt-state leaves into
+                        shard_p.append(jnp.concatenate(
+                            [lax.dynamic_slice(
+                                pflat, (s + shard_start(e - s),),
+                                ((e - s) // R,)) for s, e in segs]))
+            # the 1/R shard's update and the allgather back
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                shard_g_tree = jax.tree.unflatten(treedef, shard_g)
+                shard_p_tree = jax.tree.unflatten(treedef, [
+                    sp.astype(pl.dtype)
+                    for sp, pl in zip(shard_p, p_leaves)])
+                updates, opt2 = optimizer.update(
+                    jax.tree.map(lambda y, pl: y.astype(pl.dtype),
+                                 shard_g_tree, shard_p_tree),
+                    state["opt_state"], shard_p_tree)
+                new_shard = optax.apply_updates(shard_p_tree, updates)
+                out_leaves = []
+                for u, p in zip(jax.tree.leaves(new_shard), p_leaves):
+                    pad = self._shard_pad(p.size, R)
+                    segs = self._seg_bounds(pad, R, hint)
+                    if len(segs) == 1:
+                        full = gather_shard(u)
+                    else:
+                        # segment-granular ag, mirroring the scatter:
+                        # each segment's gather reassembles that
+                        # contiguous range, concat restores leaf order
+                        off, fulls = 0, []
+                        for s, e in segs:
+                            mi = (e - s) // R
+                            fulls.append(gather_shard(
+                                lax.dynamic_slice(u, (off,), (mi,))))
+                            off += mi
+                        full = jnp.concatenate(fulls)
+                    out_leaves.append(
+                        full[:p.size].reshape(p.shape).astype(p.dtype))
+                new_params = jax.tree.unflatten(treedef, out_leaves)
             ef_out = jax.tree.unflatten(jax.tree.structure(ef_in),
                                         new_ef) \
                 if ef_in is not None else None
@@ -2786,9 +2928,10 @@ class _CompiledTrainStep:
         trees = [slots[pos] for pos in ex.local_positions]
         leaves0, treedef = jax.tree.flatten(trees[0])
         all_leaves = [jax.tree.flatten(t)[0] for t in trees]
-        staged = []
+        staged, staged_bytes = [], 0
         for k in range(len(leaves0)):
             rows = [np.asarray(lv[k]) for lv in all_leaves]
+            staged_bytes += sum(r.nbytes for r in rows)
             if ex.shard_mode:
                 shape = (ex.num_ranks,) + rows[0].shape
                 sharding = NamedSharding(
@@ -2800,9 +2943,25 @@ class _CompiledTrainStep:
             else:
                 staged.append(jax.device_put(np.stack(rows),
                                              ex.devices[0]))
+        _step_metrics()[3].inc(staged_bytes)
         return jax.tree.unflatten(treedef, staged)
 
     # -- call ----------------------------------------------------------------
+
+    def _shared_key(self, ex):
+        """The step's key in the shared program cache (tagged steps:
+        rank threads, whose equivalent step objects meet at one
+        program)."""
+        # the sharded decomposition (wire + TopologyHint) is
+        # part of the cache key: the same model under a
+        # different hint/wire is a different XLA program, and
+        # per-stage hints keep pp programs distinct
+        mode = ("sharded", self.wire_dtype, self.wire_inner,
+                self._overlap_bucket_bytes(),
+                self.topology_hint.key()
+                if self.topology_hint is not None else None) \
+            if self.sharded else None
+        return ("step", _ex_uid(ex), self._tag, mode)
 
     def _program(self, ex):
         # built lazily by whichever rank leads first; later leaders
@@ -2818,25 +2977,17 @@ class _CompiledTrainStep:
             if self._prog is None:
                 build = self._build_sharded if self.sharded \
                     else self._build
-                # the sharded decomposition (wire + TopologyHint) is
-                # part of the cache key: the same model under a
-                # different hint/wire is a different XLA program, and
-                # per-stage hints keep pp programs distinct
-                mode = ("sharded", self.wire_dtype, self.wire_inner,
-                        self._overlap_bucket_bytes(),
-                        self.topology_hint.key()
-                        if self.topology_hint is not None else None) \
-                    if self.sharded else None
                 if self._tag is not None:
-                    key = ("step", _ex_uid(ex), self._tag, mode)
                     self._prog = _shared_program(
-                        key, lambda: build(ex))
+                        self._shared_key(ex), lambda: build(ex),
+                        SCOPE_LOSS_AND_GRAD)
                 else:
                     # untagged (single-rank) steps skip the shared
                     # cache but still report cache traffic + compile
                     # time to the registry (bench.py reads these)
                     _cache_metrics()[1].inc()
-                    self._prog = _TimedFirstCall(build(ex))
+                    self._prog = _TimedFirstCall(
+                        build(ex), SCOPE_LOSS_AND_GRAD)
             else:
                 _cache_metrics()[0].inc()
             return self._prog
@@ -2894,6 +3045,19 @@ class _CompiledTrainStep:
         _, ps = _ps_state(self.process_set)
         return self._program(ps.executor).lower(state, batch.tree)
 
+    def report(self):
+        """The step program's account of itself (``None`` before the
+        first step): see ``_TimedFirstCall.report``.  Nothing on the
+        step path computes it; the first request does."""
+        prog = self._prog
+        if prog is None and self._tag is not None:
+            # a rank thread that never led a round: the leaders' program
+            _, ps = _ps_state(self.process_set)
+            key = self._shared_key(ps.executor)
+            with _PROGRAM_LOCK:
+                prog = _PROGRAM_CACHE.get(key)
+        return None if prog is None else prog.report()
+
     def __call__(self, state, batch):
         """Run one step with THIS rank's ``batch``; returns
         ``(new_state, loss)``.  All member ranks call per step."""
@@ -2904,13 +3068,19 @@ class _CompiledTrainStep:
             self._state_template = self._shard_specs(
                 state, self._resolve_shard_hint(ex), ex.num_ranks)
 
+        calls, waited, staging, _, calling = _step_metrics()
+        calls.inc()
         if n_local == 1:
             self._check_step_signature(eng, ps, state, batch)
             prog = self._program(ex)
             if isinstance(batch, StagedBatch):
-                return prog(state, batch.tree)
-            batches = {ex.local_positions[0]: batch}
-            return prog(state, self._stage_batch(ex, batches))
+                tree = batch.tree
+            else:
+                with _span("stage batch", staging):
+                    tree = self._stage_batch(
+                        ex, {ex.local_positions[0]: batch})
+            with _span("program call", calling):
+                return prog(state, tree)
         pos = _caller_pos(eng, ps)
         if pos is None:
             raise ValueError(
@@ -2925,9 +3095,13 @@ class _CompiledTrainStep:
             st = slots[sorted(slots)[0]][0]
             self._check_step_signature(eng, ps, st, slots[sorted(slots)[0]][1])
             batches = {p: slots[p][1] for p in slots}
-            return self._program(ex)(st, self._stage_batch(ex, batches))
+            prog = self._program(ex)
+            with _span("stage batch", staging):
+                tree = self._stage_batch(ex, batches)
+            with _span("program call", calling):
+                return prog(st, tree)
 
-        return rdv.run(pos, (state, batch), launch_rdv)
+        return rdv.run(pos, (state, batch), launch_rdv, waited)
 
 
 class StagedBatch:

@@ -463,6 +463,20 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _named_kernel(name, kernel, **pallas_call_args):
+    """``pl.pallas_call`` under ``jax.named_scope(name)`` and with the
+    same ``name=``: the kernel is then a row of its own in a device
+    trace (the scope ends the custom call's ``op_name``; without it
+    the trace names all three flash kernels after the flax module
+    they sit in)."""
+    call = pl.pallas_call(kernel, name=name, **pallas_call_args)
+
+    def named(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+    return named
+
+
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash(qf, kf, vf, block_q, block_k, bwd_block_q, bwd_block_k,
@@ -476,7 +490,8 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, window,
                     interpret):
     BH, S, D = qf.shape
     scale = 1.0 / np.sqrt(D)
-    out, lse = pl.pallas_call(
+    out, lse = _named_kernel(
+        "flash_fwd",
         functools.partial(_flash_kernel, block_k=block_k, seq_len=S,
                           scale=scale, window=window),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
@@ -521,7 +536,8 @@ def _flash_vjp_bwd(block_q, block_k, bwd_block_q, bwd_block_k,
     # (BH, 1, S) for the TPU block-tiling rule like lse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]              # (BH, 1, S)
-    dq = pl.pallas_call(
+    dq = _named_kernel(
+        "flash_dq",
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
                           scale=scale, window=window),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
@@ -537,7 +553,8 @@ def _flash_vjp_bwd(block_q, block_k, bwd_block_q, bwd_block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
     )(qf, kf, vf, do, lse, delta)
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_kernel(
+        "flash_dkv",
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                           seq_len=S, scale=scale, window=window),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), kf.dtype),

@@ -19,8 +19,10 @@ not buried in per-process logs):
   (runner/http/http_server.py).
 
 User surface: ``hvd.metrics()`` (snapshot dict),
-``hvd.start_metrics_server()`` — exported by every frontend.  See
-docs/observability.md for the family catalogue.
+``hvd.start_metrics_server()`` — exported by every frontend;
+``telemetry.program_reports()`` (:mod:`.programs`) for what the
+compiled programs say about themselves.  See docs/observability.md
+for the family catalogue.
 """
 
 from .registry import (  # noqa: F401
@@ -32,6 +34,9 @@ from .exporter import (  # noqa: F401
     render_prometheus, render_json, MetricsServer,
     start_metrics_server, MetricsPusher, TELEMETRY_KV_PREFIX,
     CONTENT_TYPE_LATEST,
+)
+from .programs import (  # noqa: F401
+    first_call, keep_program, program_reports,
 )
 
 
@@ -163,8 +168,11 @@ PROGRAM_CACHE_MISSES_FAMILY = "horovod_program_cache_misses_total"
 PROGRAM_CACHE_MISSES_HELP = ("Compiled-path program cache misses "
                              "(new builds)")
 COMPILE_SECONDS_FAMILY = "horovod_compile_seconds_total"
-COMPILE_SECONDS_HELP = ("Seconds spent building + first-compiling "
-                        "programs")
+COMPILE_SECONDS_HELP = ("Wall seconds of compiled programs' first "
+                        "calls: trace + lower + backend compile or "
+                        "persistent-cache read (the four "
+                        "horovod_compile_*_seconds_total stages) + "
+                        "the first execution, which is the remainder")
 AUTOTUNE_SAMPLES_FAMILY = "horovod_autotune_samples_total"
 AUTOTUNE_SAMPLES_HELP = "Autotune sample windows scored"
 AUTOTUNE_BEST_SCORE_FAMILY = "horovod_autotune_best_score_bytes_per_sec"
@@ -180,6 +188,58 @@ AUTOTUNE_BEST_CONFIG_LABELS = ("fusion_threshold_bytes",
 ELASTIC_RESIZE_FAMILY = "horovod_elastic_resize_events_total"
 ELASTIC_RESIZE_HELP = ("Elastic membership changes seen by this "
                        "worker")
+
+# -- the compiled train step accounts for itself (docs/observability.md
+#    "The compiled step"; ops/compiled.py): the step call by phase and
+#    the first call by compile stage.  The step-call counters are
+#    always on, bumped from ``_CompiledTrainStep.__call__`` through
+#    ``utils/profiler.annotate`` (which also opens the ``hvd: <phase>``
+#    span the jax profiler shows); the compile-stage counters are fed
+#    by the one ``jax.monitoring`` listener below while a thread is
+#    inside a program's first call.
+
+STEP_CALLS_FAMILY = "horovod_step_calls_total"
+STEP_CALLS_HELP = ("Calls of a compiled train step, one per calling "
+                   "rank (rank threads of one process each count)")
+STEP_RENDEZVOUS_WAIT_FAMILY = "horovod_step_rendezvous_wait_seconds_total"
+STEP_RENDEZVOUS_WAIT_HELP = (
+    "Seconds rank threads spent between their own arrival at the "
+    "compiled step's rendezvous and the last rank's arrival, summed "
+    "over the ranks (the skew between the threads; the leader's "
+    "launch they then wait for is booked in the stage-batch and "
+    "program-call families, once)")
+STEP_STAGE_BATCH_FAMILY = "horovod_step_stage_batch_seconds_total"
+STEP_STAGE_BATCH_HELP = ("Seconds the compiled step spent staging "
+                         "host batches onto its mesh (per process: "
+                         "the launching rank stages for all)")
+STEP_STAGED_BYTES_FAMILY = "horovod_step_staged_bytes_total"
+STEP_STAGED_BYTES_HELP = ("Batch bytes the compiled step staged from "
+                          "the host onto its mesh")
+STEP_PROGRAM_CALL_FAMILY = "horovod_step_program_call_seconds_total"
+STEP_PROGRAM_CALL_HELP = ("Seconds inside the call of the compiled "
+                          "step's jitted program (the enqueue; a "
+                          "first call's compile is in it)")
+COMPILE_TRACE_SECONDS_FAMILY = "horovod_compile_trace_seconds_total"
+COMPILE_TRACE_SECONDS_HELP = ("Seconds of compiled programs' first "
+                              "calls spent tracing to a jaxpr")
+COMPILE_LOWER_SECONDS_FAMILY = "horovod_compile_lower_seconds_total"
+COMPILE_LOWER_SECONDS_HELP = ("Seconds of compiled programs' first "
+                              "calls spent lowering the jaxpr to MLIR")
+COMPILE_BACKEND_SECONDS_FAMILY = "horovod_compile_backend_seconds_total"
+COMPILE_BACKEND_SECONDS_HELP = (
+    "Seconds of compiled programs' first calls spent in the backend "
+    "compiler (the persistent cache's reads left out)")
+COMPILE_CACHE_READ_SECONDS_FAMILY = \
+    "horovod_compile_cache_read_seconds_total"
+COMPILE_CACHE_READ_SECONDS_HELP = (
+    "Seconds of compiled programs' first calls spent reading "
+    "executables from jax's persistent compilation cache")
+COMPILE_CACHE_HITS_FAMILY = "horovod_compile_cache_hits_total"
+COMPILE_CACHE_HITS_HELP = ("Executables read from jax's persistent "
+                           "compilation cache during first calls")
+COMPILE_CACHE_WRITES_FAMILY = "horovod_compile_cache_writes_total"
+COMPILE_CACHE_WRITES_HELP = ("Executables written to jax's persistent "
+                             "compilation cache during first calls")
 
 # -- per-hop wire accounting (docs/concepts.md "Per-hop wire"): the
 #    engine's reduction dispatch and collective_bench both consume

@@ -17,6 +17,7 @@ on-demand remote profiling, a direct ``jax.profiler.trace``).
 
 import contextlib
 import threading
+import time
 
 _lock = threading.Lock()
 _active = False
@@ -50,14 +51,41 @@ def stop_profile():
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str):
+class annotate:
     """Named range in the profile (the reference's NvtxOpRange).
-    Near-zero overhead when no profiler is attached."""
-    import jax
+    Near-zero overhead when no profiler is attached.
 
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    The one way this package opens a host span.  ``seconds``, a
+    counter child of the telemetry registry, also gets the elapsed
+    ``perf_counter`` seconds, so that a phase is on the device trace's
+    clock when someone profiles and in the always-on counters when
+    no one does; ``beside`` is one more context manager entered inside
+    the range (the engine timeline's span on the eager path)."""
+
+    __slots__ = ("_range", "_seconds", "_beside", "_t0")
+
+    def __init__(self, name: str, seconds=None, beside=None):
+        import jax
+
+        self._range = jax.profiler.TraceAnnotation(name)
+        self._seconds = seconds
+        self._beside = beside
+
+    def __enter__(self):
+        self._range.__enter__()
+        if self._beside is not None:
+            self._beside.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        elapsed = time.perf_counter() - self._t0
+        if self._beside is not None:
+            self._beside.__exit__(*exc)
+        self._range.__exit__(*exc)
+        if self._seconds is not None:
+            self._seconds.inc(elapsed)
+        return False
 
 
 @contextlib.contextmanager

@@ -410,3 +410,300 @@ def test_compiled_train_step_adasum(hvd_shutdown):
         return True
 
     assert all(run_ranks(fn))
+
+
+# ---------------------------------------------------------------------------
+# the compiled step accounts for itself: scopes, report, phases, stages
+
+STEP_SCOPES = ("hvd_step/loss_and_grad", "hvd_step/grad_reduce",
+               "hvd_step/optimizer")
+
+
+def _tiny_lm():
+    """(loss_fn, params, tokens) of a two-layer LM with the flash
+    kernel (interpreted) and the fused chunked cross-entropy."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import (TransformerConfig, TransformerLM,
+                                    make_fused_lm_loss)
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq_len=64, dtype=jnp.float32, remat=True,
+        remat_policy="dots_flash")
+    model = TransformerLM(cfg, attention_fn=functools.partial(
+        flash_attention, interpret=True, block_q=32, block_k=32))
+    tokens = np.arange(2 * 64, dtype=np.int32).reshape(2, 64) % 64
+    params = jax.device_get(model.init(
+        jax.random.PRNGKey(0), jnp.asarray(tokens))["params"])
+    return make_fused_lm_loss(model, n_chunks=2), params, tokens
+
+
+def _aux_problem():
+    import jax.numpy as jnp
+
+    def loss_fn(params, aux, batch):
+        loss = jnp.mean((batch @ params["w"]) ** 2)
+        return loss, {"running": aux["running"] * 0.9
+                      + 0.1 * jnp.mean(batch)}
+
+    return (loss_fn, {"w": np.ones((3, 1), np.float32)},
+            {"running": np.zeros((), np.float32)},
+            np.ones((2, 3), np.float32))
+
+
+def _make(model, **kw):
+    """(step, state, batch) of one of the two small problems."""
+    if model == "lm":
+        loss_fn, params, batch = _tiny_lm()
+        step = hvd.make_compiled_train_step(
+            loss_fn, optax.adamw(1e-3), **kw)
+        return step, step.init_state(params), batch
+    loss_fn, params, aux, batch = _aux_problem()
+    step = hvd.make_compiled_train_step(
+        loss_fn, optax.sgd(0.01), has_aux=True, **kw)
+    return step, step.init_state(params, aux=aux), batch
+
+
+@pytest.mark.parametrize("model", ["lm", "has_aux"])
+def test_step_scopes_in_lowered_text(hvd_shutdown, model):
+    """The parts of the step body are named in what the compiler is
+    handed (one rank: the stacked program)."""
+    hvd.init(num_ranks=1)
+    step, state, batch = _make(model)
+    text = step.lower(state, step.place_batch(batch)).as_text(
+        debug_info=True)
+    wanted = STEP_SCOPES + (("hvd_step/aux_reduce",)
+                            if model == "has_aux" else
+                            ("lm_head_ce", "embed", "flash_fwd",
+                             "flash_dq", "flash_dkv"))
+    for scope in wanted:
+        assert scope in text, scope
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["plain", "sharded"])
+@pytest.mark.parametrize("model", ["lm", "has_aux"])
+def test_step_scopes_in_program_table(hvd_shutdown, model, sharded):
+    """...and in the optimized program's own table, under rank
+    threads (the shard_map program), plain and weight-update
+    sharded; forward, backward and recomputation are told apart by
+    jax's own names inside ``loss_and_grad``."""
+    def fn():
+        step, state, batch = _make(model, sharded=sharded)
+        assert step.report() is None
+        state, _ = step(state, batch)
+        paths = set(step.report()["scopes"].values())
+        wanted = STEP_SCOPES + (("hvd_step/aux_reduce",)
+                                if model == "has_aux" else ())
+        missing = [s for s in wanted
+                   if not any(s in p for p in paths)]
+        in_grad = [p for p in paths if "hvd_step/loss_and_grad" in p]
+        kinds = (any("transpose(" in p for p in in_grad),
+                 any("jvp(" in p and "transpose(" not in p
+                     for p in in_grad),
+                 any("rematted_computation" in p for p in in_grad))
+        return missing, kinds
+
+    for missing, (backward, forward, remat) in run_ranks(fn, 2):
+        assert not missing, missing
+        assert backward and forward
+        assert remat == (model == "lm")
+
+
+def test_step_report_table_memory_and_laziness(hvd_shutdown):
+    """``report()``: nothing before the first call, nothing computed
+    until asked, a table that covers every instruction of the
+    optimized module, a memory account whose parts are non-negative
+    and whose arguments are the state and the batch."""
+    import re
+
+    import jax
+
+    hvd.init(num_ranks=1)
+    step, state, batch = _make("lm")
+    assert step.report() is None
+    staged = step.place_batch(batch)
+    for _ in range(2):
+        state, _ = step(state, staged)
+    assert step._prog._report is None       # nobody asked yet
+    report = step.report()
+    assert step.report() is report          # computed once, then kept
+    assert report["module"] == "jit_prog"
+
+    text = step.lower(state, staged).compile().as_text()
+    computations = set(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", text, re.M))
+    instructions = set(re.findall(r"%([\w.\-]+)", text)) - computations
+    assert instructions and instructions <= set(report["scopes"])
+    # a fusion the compiler made is booked to an op of the program
+    fusions = [n for n in report["scopes"] if "fusion" in n]
+    assert fusions and all(report["scopes"][n] for n in fusions)
+
+    memory = report["memory"]
+    assert all(v >= 0 for v in memory.values())
+    held = sum(leaf.nbytes for leaf in jax.tree.leaves(
+        (state, staged.tree)))
+    assert memory["argument"] == held
+    assert 0 < memory["alias"] <= memory["output"]
+    assert report["cost"]["flops"] > 0
+
+
+def test_instruction_scopes_books_a_fusion_to_its_root():
+    from horovod_tpu.telemetry.programs import instruction_scopes
+
+    text = """HloModule jit_prog, is_scheduled=true
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %sin.0 = f32[8]{0} sine(%p), metadata={op_name="jit(prog)/a/sin"}
+  ROOT %mul.0 = f32[8]{0} multiply(%sin.0, %sin.0), metadata={op_name="jit(prog)/b/mul" stack_frame_id=2}
+}
+
+%fused_computation.1 (q: f32[8]) -> (f32[8], f32[8]) {
+  %q = f32[8]{0} parameter(0)
+  %neg.0 = f32[8]{0} negate(%q), metadata={op_name="jit(prog)/c/neg"}
+  ROOT %tuple.0 = (f32[8]{0}, f32[8]{0}) tuple(%neg.0, %q)
+}
+
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %fusion.1 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation
+  %fusion.2 = (f32[8]{0}, f32[8]{0}) fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %own.3 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(prog)/d/own"}
+  %copy-start.5 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(f32[8]{0:T(8)(2,1)} %own.3), cross_program_prefetch_index=0
+  %copy-done.5 = f32[8]{0:S(1)} copy-done(%copy-start.5)
+  %gte.6 = f32[8]{0} get-tuple-element(%fusion.2), index=0
+  %convert.7 = bf16[8]{0} convert(%x)
+  %constant.8 = f32[] constant(0)
+  %broadcast.9 = f32[8]{0} broadcast(%constant.8), dimensions={}
+  ROOT %copy.4 = f32[8]{0} copy(%fusion.1)
+}
+"""
+    scopes = instruction_scopes(text)
+    assert scopes["fusion.1"] == "jit(prog)/b/mul"      # the root's
+    assert scopes["fusion.2"] == "jit(prog)/c/neg"      # root has none
+    assert scopes["own.3"] == "jit(prog)/d/own"         # its own first
+    assert scopes["sin.0"] == "jit(prog)/a/sin"
+    # what only moves a result belongs to who made it, hop by hop
+    assert scopes["copy.4"] == "jit(prog)/b/mul"
+    assert scopes["copy-start.5"] == scopes["copy-done.5"] \
+        == "jit(prog)/d/own"
+    assert scopes["gte.6"] == "jit(prog)/c/neg"
+    # what computes something new without a name stays unnamed
+    assert scopes["convert.7"] == scopes["broadcast.9"] == ""
+    assert set(scopes) == {"p", "sin.0", "mul.0", "q", "neg.0",
+                           "tuple.0", "x", "fusion.1", "fusion.2",
+                           "own.3", "copy.4", "copy-start.5",
+                           "copy-done.5", "gte.6", "convert.7",
+                           "constant.8", "broadcast.9"}
+
+
+def _step_counters():
+    from horovod_tpu import telemetry
+
+    return {name.split("horovod_step_")[1]: telemetry.counter_total(name)
+            for name in (telemetry.STEP_CALLS_FAMILY,
+                         telemetry.STEP_RENDEZVOUS_WAIT_FAMILY,
+                         telemetry.STEP_STAGE_BATCH_FAMILY,
+                         telemetry.STEP_STAGED_BYTES_FAMILY,
+                         telemetry.STEP_PROGRAM_CALL_FAMILY)}
+
+
+def test_step_call_counters_one_rank(hvd_shutdown):
+    hvd.init(num_ranks=1)
+    step, state, batch = _make("has_aux")
+    before = _step_counters()
+    for _ in range(3):
+        state, _ = step(state, batch)           # a host batch: staged
+    mid = _step_counters()
+    staged = step.place_batch(batch)
+    for _ in range(2):
+        state, _ = step(state, staged)          # placed once: not staged
+    after = _step_counters()
+    assert mid["calls_total"] - before["calls_total"] == 3
+    assert after["calls_total"] - mid["calls_total"] == 2
+    assert mid["stage_batch_seconds_total"] \
+        > before["stage_batch_seconds_total"]
+    assert mid["staged_bytes_total"] - before["staged_bytes_total"] \
+        == 3 * batch.nbytes
+    # place_batch stages once more; the steps after it stage nothing
+    assert after["staged_bytes_total"] - mid["staged_bytes_total"] \
+        == batch.nbytes
+    assert after["program_call_seconds_total"] \
+        > mid["program_call_seconds_total"] \
+        > before["program_call_seconds_total"]
+    assert after["rendezvous_wait_seconds_total"] == 0
+
+
+def test_step_call_counters_rank_threads(hvd_shutdown):
+    """Under rank threads every rank's call counts, the launching rank
+    stages and calls once a step for all, and the rendezvous books the
+    skew between the threads' arrivals."""
+    import time
+
+    def fn():
+        step, state, batch = _make("has_aux")
+        state, _ = step(state, batch)       # the compile, out of the way
+        before = _step_counters()
+        for _ in range(3):
+            if hvd.rank() == 1:
+                time.sleep(0.05)            # rank 0 waits for rank 1
+            state, _ = step(state, batch)
+        return before, _step_counters(), batch.nbytes
+
+    for before, after, nbytes in run_ranks(fn, 2):
+        # the other rank's reads fall between the two, so bound them
+        assert 5 <= after["calls_total"] - before["calls_total"] <= 7
+        assert 0.05 * 2 < after["rendezvous_wait_seconds_total"] \
+            - before["rendezvous_wait_seconds_total"] < 0.05 * 3 + 1.0
+        assert after["staged_bytes_total"] - before["staged_bytes_total"] \
+            in (2 * 2 * nbytes, 3 * 2 * nbytes, 4 * 2 * nbytes)
+        assert after["stage_batch_seconds_total"] \
+            > before["stage_batch_seconds_total"]
+        assert after["program_call_seconds_total"] \
+            > before["program_call_seconds_total"]
+
+
+def test_compile_stage_counters_first_call_only(hvd_shutdown):
+    from horovod_tpu import telemetry
+
+    stages = (telemetry.COMPILE_TRACE_SECONDS_FAMILY,
+              telemetry.COMPILE_LOWER_SECONDS_FAMILY,
+              telemetry.COMPILE_BACKEND_SECONDS_FAMILY,
+              telemetry.COMPILE_CACHE_READ_SECONDS_FAMILY)
+
+    def read():
+        return [telemetry.counter_total(n) for n in stages
+                + (telemetry.COMPILE_SECONDS_FAMILY,)]
+
+    hvd.init(num_ranks=1)
+    step, state, batch = _make("lm")
+    assert read()[:3] == [0.0, 0.0, 0.0]
+    state, _ = step(state, batch)
+    first = read()
+    assert all(v > 0 for v in first[:3]), first
+    # each instant is booked to one stage: they fit in the first call
+    assert sum(first[:4]) <= first[4]
+    state, _ = step(state, batch)
+    assert read() == first
+
+
+def test_compile_stage_listener_registered_once(hvd_shutdown):
+    from jax._src import monitoring
+
+    from horovod_tpu.telemetry import programs
+
+    for _ in range(2):
+        hvd.init(num_ranks=1)
+        step, state, batch = _make("has_aux")
+        step(state, batch)
+        hvd.shutdown()
+    assert monitoring.get_event_duration_listeners().count(
+        programs._on_duration) == 1
+    assert monitoring.get_event_listeners().count(
+        programs._on_event) == 1

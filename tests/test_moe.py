@@ -237,6 +237,9 @@ def test_compiled_alltoall_ef_reset_on_wire_state_reset(live_engine):
         a2a(x)
         keys = set(a2a._ef_keys)
         assert keys and all(k in cm._EF_STATE for k in keys)
+        # the residuals are shared by the ranks' equivalent objects:
+        # every rank looks before any rank drops them
+        hvd.barrier()
         a2a.reset_wire_state()
         assert not a2a._ef_keys
         assert all(k not in cm._EF_STATE for k in keys)

@@ -280,3 +280,67 @@ def test_two_process_job_wide_metrics(tmp_path):
     assert proc.returncode == 0, (proc.stdout[-2000:],
                                   proc.stderr[-2000:])
     assert "METRICS SMOKE OK" in proc.stdout
+
+
+# -- the compiled step's own account (telemetry/programs.py) -----------------
+
+def test_exclusive_seconds_books_each_instant_once():
+    from horovod_tpu.telemetry.programs import exclusive_seconds
+
+    trace, lower, backend = 0, 1, 2
+    spans = [
+        (trace, 0.0, 10.0),         # the outer trace
+        (trace, 1.0, 2.0),          # a jitted function traced inside it
+        (lower, 3.0, 4.0),          # a concrete value computed while
+        (backend, 4.0, 6.0),        # tracing: its own lower + compile
+        (lower, 10.0, 13.0),
+        (backend, 13.0, 20.0),
+    ]
+    assert exclusive_seconds(spans) == [7.0, 4.0, 9.0]
+    assert exclusive_seconds([]) == [0.0, 0.0, 0.0]
+
+
+def test_annotate_adds_elapsed_seconds_to_a_counter():
+    import time
+
+    from horovod_tpu.utils import profiler
+
+    reg = MetricRegistry()
+    child = reg.counter("t_seconds_total", "t").labels()
+    entered = []
+
+    class Beside:
+        def __enter__(self):
+            entered.append("in")
+
+        def __exit__(self, *exc):
+            entered.append("out")
+
+    with profiler.annotate("hvd: test", child, Beside()):
+        time.sleep(0.01)
+    assert 0.01 <= child.value < 1.0
+    assert entered == ["in", "out"]
+    with profiler.annotate("hvd: test"):        # a name alone still works
+        pass
+
+
+def test_program_reports_survive_shutdown(hvd_shutdown):
+    """The benchmark asks after ``hvd.shutdown()``: the reports of the
+    programs that ran under the last registry are still answerable,
+    and a fresh registry starts an empty list."""
+    import optax
+
+    hvd.init(num_ranks=1)
+    assert telemetry.program_reports() == []
+    step = hvd.make_compiled_train_step(
+        lambda p, b: ((b @ p["w"]) ** 2).mean(), optax.sgd(0.1))
+    state = step.init_state({"w": np.ones((3, 1), np.float32)})
+    state, _ = step(state, np.ones((2, 3), np.float32))
+    hvd.shutdown()
+    del step, state
+    (report,) = telemetry.program_reports()
+    assert report["module"] == "jit_prog"
+    assert any("hvd_step/optimizer" in path
+               for path in report["scopes"].values())
+    hvd.init(num_ranks=1)
+    assert telemetry.program_reports() == []

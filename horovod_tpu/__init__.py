@@ -56,5 +56,6 @@ from .ops.compiled import (  # noqa: F401
     CompiledAlltoall, CompiledGroupedAllreduce, CompiledPredict,
     TopologyHint, make_compiled_train_step,
 )
+from .ops.grad_hook import reduce_in_backward  # noqa: F401
 from . import serving  # noqa: F401
 from .runner.thread_launcher import run  # noqa: F401

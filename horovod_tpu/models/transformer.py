@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import grad_hook
+
 
 @dataclass(frozen=True)
 class TransformerConfig:
@@ -360,6 +362,18 @@ class TransformerLM(nn.Module):
             angles, seq_offset, tokens.shape[1], axis=0)
 
         block = DecoderBlock
+        if grad_hook.reduces_in_backward() and not self.is_initializing():
+            # a data-parallel compiled step is tracing this call: each
+            # layer's parameter slice passes the hook inside the scan
+            # body (and inside the remat wrapper), so the layer's
+            # gradient is all-reduced in the backward loop's body,
+            # beside the backward's own work, and not after the loop.
+            # Everywhere else the module tree is the plain one
+            covered = self.path + ("layers",)
+            block = nn.map_variables(
+                DecoderBlock, "params",
+                trans_in_fn=lambda layer: grad_hook.reduce_in_backward(
+                    layer, covered))
         if cfg.remat:
             policy = None
             if cfg.remat_policy == "dots":
@@ -379,7 +393,7 @@ class TransformerLM(nn.Module):
                 raise ValueError(
                     f"remat_policy must be 'full', 'dots', or "
                     f"'dots_flash', got {cfg.remat_policy!r}")
-            block = nn.remat(DecoderBlock, prevent_cse=False,
+            block = nn.remat(block, prevent_cse=False,
                              static_argnums=(), policy=policy)
         stack = nn.scan(
             block,

@@ -219,6 +219,19 @@ STEP_PROGRAM_CALL_FAMILY = "horovod_step_program_call_seconds_total"
 STEP_PROGRAM_CALL_HELP = ("Seconds inside the call of the compiled "
                           "step's jitted program (the enqueue; a "
                           "first call's compile is in it)")
+STEP_GRAD_REDUCE_BYTES_FAMILY = "horovod_step_grad_reduce_bytes_total"
+STEP_GRAD_REDUCE_BYTES_HELP = (
+    "Gradient bytes a rank handed the all-reduce of the compiled "
+    "step's program across chips, one program call after another "
+    "(known from the program's trace; a program on one device, and "
+    "the sharded=True program's reduce-scatter, count nothing)")
+STEP_GRAD_REDUCE_IN_BACKWARD_BYTES_FAMILY = \
+    "horovod_step_grad_reduce_in_backward_bytes_total"
+STEP_GRAD_REDUCE_IN_BACKWARD_BYTES_HELP = (
+    "The part of horovod_step_grad_reduce_bytes_total that the "
+    "program reduces inside the backward pass, where the gradient "
+    "is complete (grad_hook.reduce_in_backward: the layers of a "
+    "scanned model), and not after it")
 COMPILE_TRACE_SECONDS_FAMILY = "horovod_compile_trace_seconds_total"
 COMPILE_TRACE_SECONDS_HELP = ("Seconds of compiled programs' first "
                               "calls spent tracing to a jaxpr")

@@ -28,11 +28,11 @@ sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described TPU v5e of a 2x2 host, the persistent compile
-    cache off around the module: a compile for a described device is
-    written to the cache but cannot be read back without a chip, and
-    the next one would warn."""
+def chips():
+    """The four described TPU v5e chips of a 2x2 host, the persistent
+    compile cache off around the module: a compile for a described
+    device is written to the cache but cannot be read back without a
+    chip, and the next one would warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -44,9 +44,14 @@ def chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield topo.devices[0]
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(chips):
+    return chips[0]
 
 
 # lm436m attention operands (benchmarks/lm_mfu_bench.py HEADLINE, B5)
@@ -177,6 +182,80 @@ def test_chip_compiler_takes(chip, name):
         # temporaries: what B5 needs of a 16 GB chip
         assert mem.alias_size_in_bytes > 5e9
         assert mem.temp_size_in_bytes < 12.5e9
+
+
+def test_dp_step_allreduces_run_beside_compute(chips):
+    """The data-parallel step program across four chips, as the TPU
+    compiler schedules it: it takes the step's compiler options, each
+    layer's gradient all-reduce sits in the backward loop's body, and
+    all-reduces there and after the loop are asynchronous collective
+    fusions, with compute beside them (by default every one is a
+    synchronous ``all-reduce``)."""
+    import re
+
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.models import (TransformerConfig, TransformerLM,
+                                    make_fused_lm_loss)
+    from horovod_tpu.ops.compiled import make_compiled_train_step
+    from horovod_tpu.ops.xla_ops import MeshExecutor
+
+    cfg = TransformerConfig(vocab_size=4096, d_model=1024, n_heads=8,
+                            n_layers=2, d_ff=4096, max_seq_len=512,
+                            dtype=jnp.bfloat16, remat=True,
+                            remat_policy="dots")
+    model = TransformerLM(cfg)
+    optimizer = optax.adamw(1e-3)
+    ex = MeshExecutor(chips, 4)
+    assert ex.shard_mode
+
+    def shaped(tree, spec):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(ex.mesh, spec)), tree)
+
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"],
+        jax.ShapeDtypeStruct((1, 512), jnp.int32))
+    state = shaped(jax.eval_shape(
+        lambda p: {"params": p, "opt_state": optimizer.init(p)}, params),
+        P())
+    batch = shaped(jax.ShapeDtypeStruct((4, 4, 512), jnp.int32), P("hvd"))
+    step = make_compiled_train_step(
+        make_fused_lm_loss(model, n_chunks=4), optimizer)
+    assert step._compiler_options(ex)
+    with jax.enable_x64(False):
+        text = step._build(ex).lower(state, batch).compile().as_text()
+    # {computation: its instructions}
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name and line.strip() != "}":
+            bodies[name].append(line)
+    fused = {n: "\n".join(b) for n, b in bodies.items()
+             if n.startswith("async_collective_fusion")
+             and any(" all-reduce(" in line for line in b)}
+    in_loop = [b for b in fused.values()
+               if "while/body" in b and "hvd_step/grad_reduce" in b]
+    # a layer's (1024, 4096) MLP gradient, reduced beside a matmul
+    assert any(re.search(r"f32\[1024,4096\]\S* all-reduce\(", b)
+               and " convolution(" in b for b in in_loop), len(fused)
+    # the embedding's, after the loop, beside the optimizer's update
+    assert any(re.search(r"f32\[4096,1024\]\S* all-reduce\(", b)
+               and "hvd_step/optimizer" in b for b in fused.values())
+    # what stays synchronous is small, and no stacked leaf is among it
+    alone = [hit.group(1) for n, b in bodies.items()
+             if "fused_computation" not in n and n not in fused
+             for line in b
+             for hit in [re.search(r" = (.*?) all-reduce\(", line)] if hit]
+    assert alone and not any(
+        dims.startswith("2,") or np.prod([int(d) for d in dims.split(",")
+                                          if d]) * 4 >= 1 << 20
+        for result in alone
+        for dims in re.findall(r"f32\[([\d,]*)\]", result)), alone
 
 
 def test_int4_codec_matmul_pack_is_exact():

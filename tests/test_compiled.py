@@ -707,3 +707,290 @@ def test_compile_stage_listener_registered_once(hvd_shutdown):
         programs._on_duration) == 1
     assert monitoring.get_event_listeners().count(
         programs._on_event) == 1
+
+
+# ---------------------------------------------------------------------------
+# gradients reduced where the backward pass completes them (ops/grad_hook.py)
+
+def _scanned_lm(policy, n_layers=3):
+    """(model, loss_fn, params) of a small scanned LM in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import (TransformerConfig, TransformerLM,
+                                    make_fused_lm_loss)
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2,
+        n_layers=n_layers, d_ff=64, max_seq_len=16, dtype=jnp.float32,
+        remat=True, remat_policy=policy)
+    model = TransformerLM(cfg)
+    params = jax.device_get(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"])
+    return model, make_fused_lm_loss(model, n_chunks=2), params
+
+
+def _rank_tokens(rank):
+    return (np.arange(2 * 16, dtype=np.int32).reshape(2, 16) * (rank + 3)
+            + rank) % 64
+
+
+def _without_hooks(monkeypatch):
+    """The parent's order of operations: the step opens no context, so
+    every gradient is reduced after the backward pass."""
+    import contextlib
+
+    from horovod_tpu.ops import grad_hook
+
+    monkeypatch.setattr(grad_hook, "reducing_in_backward",
+                        lambda *a: contextlib.nullcontext())
+
+
+def _tree_bytes(tree):
+    import jax
+
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree.leaves(tree))
+
+
+def _reduce_counters():
+    from horovod_tpu import telemetry
+
+    return (telemetry.counter_total(
+                telemetry.STEP_GRAD_REDUCE_BYTES_FAMILY),
+            telemetry.counter_total(
+                telemetry.STEP_GRAD_REDUCE_IN_BACKWARD_BYTES_FAMILY))
+
+
+@pytest.mark.parametrize("policy", ["full", "dots_flash"])
+@pytest.mark.parametrize("op", [hvd.Average, hvd.Sum],
+                         ids=["average", "sum"])
+def test_grad_reduced_in_backward_matches_after(hvd_shutdown, monkeypatch,
+                                                op, policy):
+    """Four ranks, a scanned LM through the fused loss: the loss, the
+    first gradient (AdamW's first moment) and the state after two
+    steps are the parent's to float32 rounding, with ``op=Sum`` the sum
+    and not four times it; the counters hold the exact bytes."""
+    import jax
+
+    _, loss_fn, params = _scanned_lm(policy)
+
+    def fn():
+        step = hvd.make_compiled_train_step(
+            loss_fn, optax.adamw(1e-2), op=op)
+        state = step.init_state(params)
+        batch = _rank_tokens(hvd.rank())
+        before = _reduce_counters()
+        state, loss1 = step(state, batch)
+        first_moment = jax.device_get(state["opt_state"][0].mu)
+        state, loss2 = step(state, batch)
+        return (float(loss1), float(loss2), first_moment,
+                jax.device_get(state["params"]), before,
+                _reduce_counters())
+
+    hooked = run_ranks(fn)
+    _without_hooks(monkeypatch)
+    plain = run_ranks(fn)
+
+    def close(a, b):
+        jax.tree.map(lambda x, y: np.testing.assert_allclose(
+            x, y, rtol=2e-5, atol=1e-7), a, b)
+
+    for mine, theirs in zip(hooked, plain):
+        close(mine[:4], theirs[:4])
+    # the exact bytes, twice (two program calls), on every rank's read
+    everything, layers = _tree_bytes(params), _tree_bytes(params["layers"])
+    assert 0.5 < layers / everything < 1
+    for (*_, before, after), (*_, p_before, p_after) in zip(hooked, plain):
+        assert (after[0] - before[0], after[1] - before[1]) \
+            == (2 * everything, 2 * layers)
+        assert (p_after[0] - p_before[0], p_after[1] - p_before[1]) \
+            == (2 * everything, 0)
+
+
+def test_grad_reduced_in_backward_sum_is_four_averages(hvd_shutdown):
+    """No leaf is reduced twice: the summed gradient is four times the
+    averaged one, leaf for leaf (a second ``psum`` would make the
+    layers' sixteen times it)."""
+    import jax
+
+    _, loss_fn, params = _scanned_lm("full")
+
+    def fn(op):
+        step = hvd.make_compiled_train_step(
+            loss_fn, optax.sgd(1.0), op=op, donate=False)
+        state = step.init_state(params)
+        new, _ = step(state, _rank_tokens(hvd.rank()))
+        return jax.device_get(jax.tree.map(
+            lambda a, b: a - b, state["params"], new["params"]))
+
+    summed = run_ranks(lambda: fn(hvd.Sum))[0]
+    averaged = run_ranks(lambda: fn(hvd.Average))[0]
+    jax.tree.map(lambda s, a: np.testing.assert_allclose(
+        s, 4 * a, rtol=1e-4, atol=1e-6), summed, averaged)
+
+
+def _all_reduces(text):
+    """[(inside a loop body, result type)] of the all-reduces of an
+    optimized module's text."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        hit = re.search(r" = (.*?) all-reduce(?:-start)?\(", line)
+        if hit:
+            found.append(("while/body" in line, hit.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("policy", ["full", "dots_flash"])
+def test_grad_reduce_sits_in_the_backward_loop(hvd_shutdown, policy):
+    """The optimized four-rank program reduces the per-layer shapes
+    inside the backward loop's body and no stacked ``layers/`` shape
+    after it; what is left after the loop is the embedding and the
+    final norm."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.ops.xla_ops import MeshExecutor
+
+    _, loss_fn, params = _scanned_lm(policy)
+    opt = optax.adamw(1e-2)
+    ex = MeshExecutor(jax.devices()[:4], 4)
+    assert ex.shard_mode
+    step = hvd.make_compiled_train_step(loss_fn, opt)
+    state = jax.device_put({"params": params, "opt_state": opt.init(params)},
+                           NamedSharding(ex.mesh, P()))
+    batch = jax.device_put(np.stack([_rank_tokens(r) for r in range(4)]),
+                           NamedSharding(ex.mesh, P("hvd")))
+    text = step._build(ex).lower(state, batch).compile().as_text()
+
+    def dims(shape):
+        return "[" + ",".join(map(str, shape)) + "]"
+
+    stacked = {dims(leaf.shape)
+               for leaf in jax.tree.leaves(params["layers"])}
+    per_layer = {dims(leaf.shape[1:])
+                 for leaf in jax.tree.leaves(params["layers"])}
+    in_loop = " ".join(t for inside, t in _all_reduces(text) if inside)
+    after = " ".join(t for inside, t in _all_reduces(text) if not inside)
+    assert all(shape in in_loop for shape in per_layer), in_loop
+    assert not any(shape in after for shape in stacked), after
+    assert dims(params["embed"].shape) in after
+
+
+def _hook_traces(report):
+    """Paths of a program's table that the hook would have written: a
+    gradient reduction inside the loss-and-gradient scope."""
+    return [p for p in report["scopes"].values()
+            if "hvd_step/loss_and_grad" in p and "hvd_step/grad_reduce" in p]
+
+
+@pytest.mark.parametrize("case", ["one_rank", "sharded", "adasum"])
+def test_no_hook_where_no_step_reduces_alone(hvd_shutdown, monkeypatch,
+                                             case):
+    """With one rank, with ``sharded=True`` and with ``op=Adasum`` the
+    hook returns its argument itself: it is never entered, the
+    program's table holds no reduction inside the backward, the
+    counters stay where they were, and (one rank) the lowered text is
+    the parent's, character for character."""
+    from horovod_tpu.ops import grad_hook
+
+    entered = []
+    real = grad_hook._reduce_cotangent
+    monkeypatch.setattr(
+        grad_hook, "_reduce_cotangent",
+        lambda *a: entered.append(a) or real(*a))
+    _, loss_fn, params = _scanned_lm("full")
+    kw = {"sharded": {"sharded": True}, "adasum": {"op": hvd.Adasum},
+          "one_rank": {}}[case]
+
+    def fn():
+        step = hvd.make_compiled_train_step(loss_fn, optax.adamw(1e-2), **kw)
+        state = step.init_state(params)
+        before = _reduce_counters()
+        state, _ = step(state, _rank_tokens(hvd.rank()))
+        return _hook_traces(step.report()), before, _reduce_counters()
+
+    if case == "one_rank":
+        hvd.init(num_ranks=1)
+        results = [fn()]
+
+        def lowered():
+            step = hvd.make_compiled_train_step(loss_fn, optax.adamw(1e-2))
+            return step.lower(
+                step.init_state(params),
+                step.place_batch(_rank_tokens(0))).as_text()
+
+        mine = lowered()
+        _without_hooks(monkeypatch)
+        assert mine == lowered()
+    else:
+        results = run_ranks(fn, 2)
+    assert not entered
+    for traces, before, after in results:
+        assert not traces
+        # Adasum across devices reduces every byte, none in the backward
+        assert after[1] == before[1]
+        assert (after[0] > before[0]) == (case == "adasum")
+
+
+def test_hooked_path_the_params_lack_is_refused(hvd_shutdown):
+    """A hook that covers a subtree the step's parameters do not hold
+    (the model applied to a part of them) fails the trace loudly: the
+    step could not tell what is left for it to reduce."""
+    model, _, params = _scanned_lm("full")
+
+    def loss_fn(nested, tokens):
+        return model.apply({"params": nested["lm"]}, tokens).mean()
+
+    def fn():
+        step = hvd.make_compiled_train_step(loss_fn, optax.sgd(0.1))
+        state = step.init_state({"lm": params})
+        with pytest.raises(ValueError, match="reduce_in_backward covered"):
+            step(state, _rank_tokens(hvd.rank()))
+        return True
+
+    assert all(run_ranks(fn, 2))
+
+
+@pytest.mark.parametrize("op", [hvd.Average, hvd.Sum],
+                         ids=["average", "sum"])
+def test_reduce_in_backward_in_a_users_own_scan(hvd_shutdown, monkeypatch,
+                                                op):
+    """``hvd.reduce_in_backward`` on the per-layer slice inside a plain
+    ``lax.scan`` under ``jax.checkpoint``: the same update as without
+    it, and the layers' bytes counted as reduced in the backward."""
+    import jax
+    import jax.numpy as jnp
+
+    params = {"layers": {"w": np.linspace(-1, 1, 3 * 8 * 8, dtype=np.float32)
+                         .reshape(3, 8, 8)},
+              "out": np.ones((8,), np.float32)}
+
+    def loss_fn(p, x):
+        @jax.checkpoint
+        def layer(h, w):
+            w = hvd.reduce_in_backward(w, covers=("layers",))
+            return jnp.tanh(h @ w["w"]), None
+
+        h, _ = jax.lax.scan(layer, x, p["layers"])
+        return jnp.mean((h @ p["out"]) ** 2)
+
+    def fn():
+        step = hvd.make_compiled_train_step(loss_fn, optax.sgd(0.5), op=op)
+        state = step.init_state(params)
+        before = _reduce_counters()
+        x = np.full((4, 8), 0.1 * (hvd.rank() + 1), np.float32)
+        state, loss = step(state, x)
+        after = _reduce_counters()
+        return (float(loss), jax.device_get(state["params"]),
+                after[1] - before[1])
+
+    hooked = run_ranks(fn)
+    _without_hooks(monkeypatch)
+    plain = run_ranks(fn)
+    for mine, theirs in zip(hooked, plain):
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-7), mine[:2], theirs[:2])
+        assert mine[2] == _tree_bytes(params["layers"]) and theirs[2] == 0

@@ -475,3 +475,49 @@ def test_resnet_s2d_stem_trains():
     assert out.shape == (2, 5)
     # stem output grid matches the 7x7/s2 stem's
     assert v["params"]["conv_init"]["kernel"].shape == (4, 4, 12, 8)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lm_under_a_reducing_step_is_the_same_model(remat):
+    """While a data-parallel step traces (``grad_hook``'s context),
+    ``TransformerLM`` routes each layer's parameters through the hook:
+    ``init`` gives the same tree and values, the forward pass the same
+    logits, the layers' cotangents pass the step's reduction exactly
+    once and the embedding's not at all, and decoding with a KV cache
+    still runs.  Outside a context the hook is never entered."""
+    from horovod_tpu.models import make_generate_fn
+    from horovod_tpu.ops import grad_hook
+
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_kv_heads=2, n_layers=3, d_ff=64,
+                            max_seq_len=16, dtype=jnp.float32, remat=remat)
+    model = TransformerLM(cfg)
+    tokens = jnp.arange(16, dtype=jnp.int32)[None] % 64
+    key = jax.random.PRNGKey(0)
+
+    def grads(p):
+        return jax.grad(lambda q: model.apply({"params": q},
+                                              tokens).sum())(p)
+
+    params = model.init(key, tokens)["params"]
+    plain = model.apply({"params": params}, tokens), grads(params)
+    generate = make_generate_fn(model, max_new_tokens=3)
+    said = generate(params, tokens[:, :4])
+    with grad_hook.reducing_in_backward(lambda g: 2 * g, "hook") as step:
+        inside = model.init(key, tokens)["params"]
+        assert not step.covered         # no hook while initializing
+        hooked = model.apply({"params": params}, tokens), grads(params)
+        assert step.covered == [("layers",)]
+        said_inside = make_generate_fn(model, max_new_tokens=3)(
+            params, tokens[:, :4])
+    assert jax.tree.structure(inside) == jax.tree.structure(params)
+    jax.tree.map(np.testing.assert_array_equal, inside, params)
+    np.testing.assert_array_equal(hooked[0], plain[0])
+    np.testing.assert_array_equal(said_inside, said)
+    jax.tree.map(lambda h, p: np.testing.assert_allclose(
+        h, 2 * p, rtol=1e-5, atol=1e-6),
+        hooked[1]["layers"], plain[1]["layers"])
+    for name in ("embed", "ln_final"):
+        jax.tree.map(lambda h, p: np.testing.assert_allclose(
+            h, p, rtol=1e-5, atol=1e-6), hooked[1][name], plain[1][name])
+    assert not grad_hook.reduces_in_backward()

@@ -21,6 +21,13 @@ TPU-first choices:
 * Optional mixture-of-experts MLP with dense one-hot dispatch: the
   expert einsum keeps a leading ``experts`` axis that the ``ep`` mesh
   axis shards; XLA inserts the token all_to_all.
+* Layers that differ (``TransformerConfig.layer_types``, the keys of
+  published ``config.json`` files such as Trinity-Mini's ``afmoe``):
+  sliding and full attention in one model, leading dense layers, then
+  routed experts of which this model may hold a share
+  (``parallel/moe.routed_experts_apply``).  Depth still costs no
+  compile time: the leading dense layers are one scan, the others one
+  scan over the periods of their pattern of kinds.
 """
 
 from dataclasses import dataclass, field
@@ -31,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import grad_hook
+from ..ops import device_sums, grad_hook
 
 
 @dataclass(frozen=True)
@@ -69,10 +76,55 @@ class TransformerConfig:
     # ~33% remat recompute FLOPs; "dots_flash" additionally saves the
     # flash-attention kernel outputs (out + lse, checkpoint-named) so
     # the backward replay skips the pallas forward too
+    head_dim: Optional[int] = None     # None => d_model // n_heads
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = True   # False => an output head of its
+    # own, the parameter ``lm_head`` (V, M)
+    # -- attention, as some published models have it (any layer)
+    qk_norm: bool = False         # RMS norm of every query and key head
+    # over head_dim, one learned scale each, shared by the heads
+    attention_gate: bool = False  # out = (o * sigmoid(x Wg)) Wo
+    # -- layers that differ (``layer_types`` set): a stack of
+    # ``num_dense_layers`` leading layers with the dense SwiGLU, then
+    # the others with routed experts where ``num_experts`` > 0, each
+    # layer's attention of its own kind.  The keys mirror the published
+    # ``config.json`` of such models; with ``layer_types`` None the
+    # model is the one identical block above and these are refused
+    layer_types: Optional[tuple] = None   # per layer "sliding_attention"
+    # (sees the last ``sliding_window`` positions) | "full_attention"
+    sliding_window: Optional[int] = None
+    rope_on_full_attention: bool = True   # False: rotary positions on
+    # the sliding layers only
+    sandwich_norm: bool = False   # four norms a layer: the attention's
+    # and the feed-forward's outputs are normed before the residual add
+    mup_enabled: bool = False     # embedding scaled by sqrt(d_model)
+    num_dense_layers: int = 0
+    moe_intermediate_size: Optional[int] = None   # None => d_ff
+    num_shared_experts: int = 0   # a dense SwiGLU of that many expert
+    # widths beside the routed ones
+    score_func: str = "sigmoid"   # over all num_experts; the one kind
+    route_norm: bool = True       # the routed layer has: sigmoid scores,
+    route_scale: float = 1.0      # the selected renormalised, then scaled
+    load_balance_coeff: float = 0.0   # > 0: each routed layer keeps an
+    # ``expert_bias`` (collection ``router_state``) that enters its
+    # top-k selection only and moves by this much a step toward the
+    # experts that got fewer tokens than the mean, where the caller
+    # threads the collection (``make_fused_lm_loss(..., with_state=True)``)
+    # the share of an expert-parallel deployment this model holds: the
+    # router keeps its num_experts outputs and expert_top_k choices a
+    # token, the layer computes experts [first_expert_held,
+    # first_expert_held + num_experts_held) for the tokens routed to
+    # them, and what the absent experts would add is left out
+    num_experts_held: Optional[int] = None   # None => all
+    first_expert_held: int = 0
 
-    @property
-    def head_dim(self):
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types",
+                               tuple(self.layer_types))
 
     @property
     def kv_heads(self):
@@ -150,6 +202,7 @@ def dense_causal_attention(q, k, v, *, offset=0, window=None):
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -157,7 +210,7 @@ class RMSNorm(nn.Module):
                            jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
-                                         keepdims=True) + 1e-6)
+                                         keepdims=True) + self.eps)
         return (y * scale).astype(self.dtype)
 
 
@@ -165,20 +218,32 @@ class Attention(nn.Module):
     cfg: TransformerConfig
     attention_fn: Callable = dense_causal_attention
     decode: bool = False      # KV-cache autoregressive path
+    layer_type: Optional[str] = None   # None: the model-wide
+    # ``attention_window`` and rotary positions; else this layer's kind
+    # of ``cfg.layer_types``
 
     @nn.compact
     def __call__(self, x, angles, offset=0):
         cfg = self.cfg
         H, D = cfg.n_heads, cfg.head_dim
         KV = cfg.kv_heads          # == H unless GQA/MQA configured
+        window, rope = cfg.attention_window, True
+        if self.layer_type is not None:
+            sliding = self.layer_type == "sliding_attention"
+            window = cfg.sliding_window if sliding else None
+            rope = sliding or cfg.rope_on_full_attention
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
         q = dense((H, D), "wq")(x)
         k = dense((KV, D), "wk")(x)
         v = dense((KV, D), "wv")(x)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="k_norm")(k)
+        if rope:
+            q = apply_rope(q, angles)
+            k = apply_rope(k, angles)
 
         def expand_kv(t):
             # training path only: each kv head serves H/KV query
@@ -218,14 +283,12 @@ class Attention(nn.Module):
                 cv.value, v.astype(cv.value.dtype), offset, axis=1)
             if KV == H:
                 o = dense_causal_attention(
-                    q, ck.value, cv.value, offset=offset,
-                    window=cfg.attention_window)
+                    q, ck.value, cv.value, offset=offset, window=window)
             else:
                 o = grouped_causal_attention(
-                    q, ck.value, cv.value, offset=offset,
-                    window=cfg.attention_window)
+                    q, ck.value, cv.value, offset=offset, window=window)
         else:
-            if cfg.attention_window is not None:
+            if window is not None:
                 # config-driven sliding window: forwarded to inners
                 # that accept it (dense reference, pallas flash); the
                 # sequence-parallel inners (ring/ulysses) don't — a
@@ -233,11 +296,10 @@ class Attention(nn.Module):
                 # model than the config says, so fail loudly
                 try:
                     o = self.attention_fn(
-                        q, expand_kv(k), expand_kv(v),
-                        window=cfg.attention_window)
+                        q, expand_kv(k), expand_kv(v), window=window)
                 except TypeError as exc:
                     raise ValueError(
-                        f"attention_window={cfg.attention_window} "
+                        f"attention_window={window} "
                         f"set but attention_fn "
                         f"{getattr(self.attention_fn, '__name__', self.attention_fn)!r} "
                         f"does not accept a window= kwarg (ring/"
@@ -245,6 +307,8 @@ class Attention(nn.Module):
                         f"support sliding windows)") from exc
             else:
                 o = self.attention_fn(q, expand_kv(k), expand_kv(v))
+        if cfg.attention_gate:
+            o = o * nn.sigmoid(dense((H, D), "wg")(x))
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=jnp.float32,
                                name="wo")(o)
@@ -252,15 +316,17 @@ class Attention(nn.Module):
 
 class SwiGLU(nn.Module):
     cfg: TransformerConfig
+    d_ff: Optional[int] = None    # None => cfg.d_ff
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        d_ff = self.d_ff or cfg.d_ff
         dense = lambda feats, name: nn.Dense(  # noqa: E731
             feats, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
-        gate = nn.silu(dense(cfg.d_ff, "wi_gate")(x))
-        up = dense(cfg.d_ff, "wi_up")(x)
+        gate = nn.silu(dense(d_ff, "wi_gate")(x))
+        up = dense(d_ff, "wi_up")(x)
         return dense(cfg.d_model, "wo")(gate * up)
 
 
@@ -334,12 +400,172 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, angles, offset=0):
         cfg = self.cfg
+        eps = cfg.rms_norm_eps
         x = x + Attention(cfg, self.attention_fn, self.decode,
                           name="attn")(
-            RMSNorm(cfg.dtype, name="ln_attn")(x), angles, offset)
+            RMSNorm(cfg.dtype, eps, name="ln_attn")(x), angles, offset)
         mlp = MoE(cfg, name="moe") if cfg.num_experts else \
             SwiGLU(cfg, name="mlp")
-        return x + mlp(RMSNorm(cfg.dtype, name="ln_mlp")(x)), None
+        return x + mlp(RMSNorm(cfg.dtype, eps, name="ln_mlp")(x)), None
+
+
+LAYER_TYPES = ("sliding_attention", "full_attention")
+
+#: the collection of what a routed layer's training loop keeps beside
+#: its parameters: ``expert_bias`` (num_experts,) a layer
+ROUTER_STATE = "router_state"
+
+#: the sums the routed layers make on the device, a step call
+#: (``ops/device_sums.py``; docs/observability.md "The compiled step")
+MOE_DEVICE_SUMS = ("horovod_moe_assignments_total",
+                   "horovod_moe_held_assignments_total",
+                   "horovod_moe_dropped_assignments_total")
+
+
+class RoutedExperts(nn.Module):
+    """The feed-forward of an expert layer: a router over all
+    ``num_experts``, the routed experts this model holds (the dropless
+    grouped product of ``parallel/moe.routed_experts_apply``) and the
+    shared expert beside them.  Returns ``(y, counts)``; ``counts`` is
+    int32 (3,): assignments, those on held experts, those of them not
+    computed (``MOE_DEVICE_SUMS``)."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        # call-time import: parallel imports models, not the reverse
+        from ..parallel import moe as moe_mod
+
+        cfg = self.cfg
+        B, S, M = x.shape
+        E = cfg.num_experts
+        held = cfg.num_experts_held or E
+        F = cfg.moe_intermediate_size or cfg.d_ff
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (M, E), jnp.float32)
+        wi_gate = self.param("wi_gate", init, (held, M, F), jnp.float32)
+        wi_up = self.param("wi_up", init, (held, M, F), jnp.float32)
+        wo = self.param("wo", init, (held, F, M), jnp.float32)
+        # expert_bias enters the selection only.  Its update is a rule
+        # of the training loop: the layer reads it from ``router_state``
+        # and, where the caller made that collection mutable, leaves
+        # the updated one there; without the collection it is zero
+        bias, state = jnp.zeros((E,), jnp.float32), None
+        if cfg.load_balance_coeff and (
+                self.is_initializing()
+                or self.has_variable(ROUTER_STATE, "expert_bias")):
+            state = self.variable(ROUTER_STATE, "expert_bias", jnp.zeros,
+                                  (E,), jnp.float32)
+            bias = state.value
+        y, counts, tokens_per_expert = moe_mod.routed_experts_apply(
+            x.reshape(B * S, M), router, bias,
+            wi_gate.astype(cfg.dtype), wi_up.astype(cfg.dtype),
+            wo.astype(cfg.dtype), first_expert=cfg.first_expert_held,
+            topk=cfg.expert_top_k, route_scale=cfg.route_scale)
+        if state is not None and not self.is_initializing() \
+                and self.is_mutable_collection(ROUTER_STATE):
+            state.value = moe_mod.updated_expert_bias(
+                bias, tokens_per_expert, cfg.load_balance_coeff)
+        y = y.reshape(B, S, M).astype(cfg.dtype)
+        if cfg.num_shared_experts:
+            y = y + SwiGLU(cfg, F * cfg.num_shared_experts,
+                           name="shared")(x)
+        return y, counts
+
+
+class LayeredBlock(nn.Module):
+    """One layer of a model whose layers differ: attention of this
+    layer's kind, then the dense SwiGLU or the routed experts."""
+    cfg: TransformerConfig
+    attention_fn: Callable
+    layer_type: str
+    routed: bool
+
+    @nn.compact
+    def __call__(self, x, angles):
+        cfg = self.cfg
+
+        def norm(name):
+            return RMSNorm(cfg.dtype, cfg.rms_norm_eps, name=name)
+
+        # the device trace's path carries the layer's published kind
+        with jax.named_scope(self.layer_type):
+            h = Attention(cfg, self.attention_fn,
+                          layer_type=self.layer_type,
+                          name="attn")(norm("ln_attn")(x), angles)
+        if cfg.sandwich_norm:
+            h = norm("ln_post_attn")(h)
+        x = x + h
+        m = norm("ln_mlp")(x)
+        if self.routed:
+            f, counts = RoutedExperts(cfg, name="moe")(m)
+        else:
+            f, counts = SwiGLU(cfg, name="mlp")(m), \
+                jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32)
+        if cfg.sandwich_norm:
+            f = norm("ln_post_mlp")(f)
+        return x + f, counts
+
+
+class LayerPeriod(nn.Module):
+    """The layers ``layer_0`` .. of one repetition of a pattern of
+    kinds: the body a scan over the depth repeats."""
+    cfg: TransformerConfig
+    attention_fn: Callable
+    layer_types: tuple
+    routed: bool
+    repeats: int = 1
+
+    @nn.compact
+    def __call__(self, x, angles):
+        # a scan of one iteration is no loop to the compiler, which
+        # would then merge each layer's recomputation with its forward
+        # pass and keep every activation after all
+        block = _with_remat(LayeredBlock, self.cfg,
+                            prevent_cse=self.repeats == 1)
+        counts = 0
+        for i, kind in enumerate(self.layer_types):
+            x, c = block(self.cfg, self.attention_fn, kind, self.routed,
+                         name=f"layer_{i}")(x, angles)
+            counts = counts + c
+        return x, counts
+
+
+def _with_remat(block, cfg, prevent_cse=False):
+    """``block`` under ``cfg``'s remat policy (inside a scan of several
+    iterations the loop itself keeps the recomputation apart from the
+    forward pass, and ``prevent_cse`` can stay off)."""
+    if not cfg.remat:
+        return block
+    policy = None
+    if cfg.remat_policy == "dots":
+        policy = jax.checkpoint_policies.\
+            dots_with_no_batch_dims_saveable
+    elif cfg.remat_policy == "dots_flash":
+        # "dots" + the flash-attention kernel outputs
+        # (checkpoint-named in ops/pallas_kernels.py): a
+        # pallas call is not a dot, so without the names the
+        # backward replay re-runs every flash forward
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.
+            dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(
+                "flash_out", "flash_lse"))
+    elif cfg.remat_policy != "full":
+        raise ValueError(
+            f"remat_policy must be 'full', 'dots', or "
+            f"'dots_flash', got {cfg.remat_policy!r}")
+    return nn.remat(block, prevent_cse=prevent_cse,
+                    static_argnums=(), policy=policy)
+
+
+def _shortest_period(kinds):
+    """The shortest prefix of ``kinds`` whose repetition is ``kinds``,
+    and how often it repeats."""
+    n = len(kinds)
+    for p in range(1, n + 1):
+        if n % p == 0 and kinds == kinds[:p] * (n // p):
+            return kinds[:p], n // p
 
 
 class TransformerLM(nn.Module):
@@ -348,20 +574,20 @@ class TransformerLM(nn.Module):
 
     attention_fn: Callable = dense_causal_attention
 
-    @nn.compact
-    def __call__(self, tokens, *, seq_offset=0, decode=False,
-                 pre_logits=False):
+    @property
+    def device_sums(self):
+        """The names of the sums this model makes on the device inside
+        a compiled step (``ops/device_sums.py``)."""
         cfg = self.cfg
-        emb = self.param("embed", nn.initializers.normal(0.02),
-                         (cfg.vocab_size, cfg.d_model), jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb[tokens].astype(cfg.dtype)
-        angles = jnp.asarray(
-            rope_angles(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta))
-        angles = jax.lax.dynamic_slice_in_dim(
-            angles, seq_offset, tokens.shape[1], axis=0)
+        routed = cfg.layer_types is not None and cfg.num_experts \
+            and cfg.num_dense_layers < cfg.n_layers
+        return MOE_DEVICE_SUMS if routed else ()
 
-        block = DecoderBlock
+    def _stack(self, block, name, length, remat=False, **scan_axes):
+        """``block`` (under the remat policy with ``remat``) scanned
+        ``length`` times over a leading axis of its parameters, each
+        iteration's slice passing the step's gradient hook where one
+        is tracing."""
         if grad_hook.reduces_in_backward() and not self.is_initializing():
             # a data-parallel compiled step is tracing this call: each
             # layer's parameter slice passes the hook inside the scan
@@ -369,53 +595,108 @@ class TransformerLM(nn.Module):
             # gradient is all-reduced in the backward loop's body,
             # beside the backward's own work, and not after the loop.
             # Everywhere else the module tree is the plain one
-            covered = self.path + ("layers",)
+            covered = self.path + (name,)
             block = nn.map_variables(
-                DecoderBlock, "params",
+                block, "params",
                 trans_in_fn=lambda layer: grad_hook.reduce_in_backward(
                     layer, covered))
-        if cfg.remat:
-            policy = None
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.\
-                    dots_with_no_batch_dims_saveable
-            elif cfg.remat_policy == "dots_flash":
-                # "dots" + the flash-attention kernel outputs
-                # (checkpoint-named in ops/pallas_kernels.py): a
-                # pallas call is not a dot, so without the names the
-                # backward replay re-runs every flash forward
-                policy = jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies.
-                    dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names(
-                        "flash_out", "flash_lse"))
-            elif cfg.remat_policy != "full":
-                raise ValueError(
-                    f"remat_policy must be 'full', 'dots', or "
-                    f"'dots_flash', got {cfg.remat_policy!r}")
-            block = nn.remat(block, prevent_cse=False,
-                             static_argnums=(), policy=policy)
-        stack = nn.scan(
+        if remat:
+            block = _with_remat(block, self.cfg)
+        return nn.scan(
             block,
-            variable_axes={"params": 0, "cache": 0},
+            variable_axes={"params": 0, **scan_axes},
             split_rngs={"params": True},
             in_axes=nn.broadcast,
-            length=cfg.n_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, self.attention_fn, decode, name="layers")
-        x, _ = stack(x, angles, seq_offset)
-        x = RMSNorm(cfg.dtype, name="ln_final")(x)
+            length=length,
+            metadata_params={nn.PARTITION_NAME: "layers"})
+
+    def _layered(self, x, angles, decode):
+        """The stack of a model whose layers differ: the leading dense
+        layers one scan, the others one scan over the periods of their
+        pattern of kinds, so depth costs no compile time."""
+        cfg = self.cfg
+        kinds, lead = cfg.layer_types, cfg.num_dense_layers
+        if decode:
+            raise ValueError(
+                "a model with layer_types has no KV-cache path: two "
+                "kinds of layer in one cache (serving/kvcache.py)")
+        if len(kinds) != cfg.n_layers or set(kinds) - set(LAYER_TYPES):
+            raise ValueError(
+                f"layer_types must name n_layers={cfg.n_layers} kinds "
+                f"of {LAYER_TYPES}, got {kinds}")
+        if "sliding_attention" in kinds and not cfg.sliding_window:
+            raise ValueError("sliding_attention layers need "
+                             "sliding_window")
+        if cfg.num_experts and (cfg.score_func != "sigmoid"
+                                or not cfg.route_norm):
+            raise ValueError(
+                "the routed layer scores with a sigmoid and renormalises "
+                f"the selected: score_func={cfg.score_func!r}, "
+                f"route_norm={cfg.route_norm}")
+        groups = [("dense_layers", kinds[:lead], False),
+                  ("periods", kinds[lead:],
+                   bool(cfg.num_experts))]
+        counts = 0
+        for name, group, routed in groups:
+            if not group:
+                continue
+            period, repeats = _shortest_period(group)
+            stack = self._stack(LayerPeriod, name, repeats,
+                                **{ROUTER_STATE: 0})(
+                cfg, self.attention_fn, period, routed, repeats, name=name)
+            x, c = stack(x, angles)
+            counts = counts + jnp.sum(c, axis=0)
+        if self.device_sums:
+            for name, value in zip(MOE_DEVICE_SUMS, counts):
+                device_sums.add(name, value)
+        return x
+
+    @nn.compact
+    def __call__(self, tokens, *, seq_offset=0, decode=False,
+                 pre_logits=False):
+        cfg = self.cfg
+        emb = self.param("embed", nn.initializers.normal(0.02),
+                         (cfg.vocab_size, cfg.d_model), jnp.float32)
+        head = emb if cfg.tie_word_embeddings else self.param(
+            "lm_head", nn.initializers.normal(0.02),
+            (cfg.vocab_size, cfg.d_model), jnp.float32)
+        with jax.named_scope("embed"):
+            x = emb[tokens]
+            if cfg.mup_enabled:
+                x = x * np.sqrt(cfg.d_model).astype(np.float32)
+            x = x.astype(cfg.dtype)
+        angles = jnp.asarray(
+            rope_angles(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta))
+        angles = jax.lax.dynamic_slice_in_dim(
+            angles, seq_offset, tokens.shape[1], axis=0)
+
+        if cfg.layer_types is not None:
+            x = self._layered(x, angles, decode)
+        else:
+            if cfg.sandwich_norm or cfg.num_dense_layers \
+                    or cfg.num_shared_experts or cfg.mup_enabled \
+                    or cfg.num_experts_held is not None:
+                raise ValueError(
+                    "sandwich_norm, mup_enabled, num_dense_layers, "
+                    "num_shared_experts and num_experts_held belong "
+                    "to a model with layer_types")
+            stack = self._stack(DecoderBlock, "layers", cfg.n_layers,
+                                remat=True, cache=0)(
+                cfg, self.attention_fn, decode, name="layers")
+            x, _ = stack(x, angles, seq_offset)
+        x = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="ln_final")(x)
         if pre_logits:
-            # hand the caller the final hidden states + tied embedding
-            # so the logits projection can fuse into a chunked loss
+            # hand the caller the final hidden states + the output
+            # head (the tied embedding, or ``lm_head``) so the logits
+            # projection can fuse into a chunked loss
             # (chunked_lm_loss) instead of materializing (B, S, V)
-            return x, emb
+            return x, head
         # logits matmul in the activation dtype with f32 accumulation:
         # a (B*S, M) @ (M, V) f32 matmul would run at a fraction of the
         # MXU's bf16 rate and dominate the step at large vocab
         with jax.named_scope("lm_head"):
             logits = jnp.einsum("bsm,vm->bsv", x,
-                                emb.astype(cfg.dtype),
+                                head.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
         return logits
 
@@ -550,7 +831,8 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
         return total / jnp.where(denom > 0, denom, 1.0)
 
 
-def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16):
+def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
+                       with_state: bool = False):
     """``loss_fn(params, tokens)`` computing the next-token objective of
     ``lm_loss(model.apply(...)[:, :-1], tokens[:, 1:])`` via
     :func:`chunked_lm_loss` — targets rolled (not sliced, so S stays
@@ -562,7 +844,14 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16):
 
     ``model`` is a ``TransformerLM`` (flax) or any plain
     ``apply(params, tokens, pre_logits=True) -> (x, emb)`` callable
-    (e.g. ``make_pipelined_lm_apply``'s)."""
+    (e.g. ``make_pipelined_lm_apply``'s).
+
+    ``with_state`` (a flax model): ``loss_fn(params, state, tokens) ->
+    (loss, new_state)`` for ``make_compiled_train_step(...,
+    has_aux=True)``, where ``state`` is the model's other collections
+    (``model.init(...)`` without ``"params"``: the routed layers'
+    ``router_state``), which the step threads as it threads batch
+    statistics."""
     if hasattr(model, "apply"):
         def pre(params, tokens):
             return model.apply({"params": params}, tokens,
@@ -571,10 +860,21 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16):
         def pre(params, tokens):
             return model(params, tokens, pre_logits=True)
 
-    def loss_fn(params, tokens):
-        x, emb = pre(params, tokens)
+    def objective(x, emb, tokens):
         targets = jnp.roll(tokens, -1, axis=1)
         w = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
         return chunked_lm_loss(x, emb, targets, n_chunks=n_chunks,
                                weights=w)
+
+    if with_state:
+        def loss_fn(params, state, tokens):
+            (x, emb), new_state = model.apply(
+                {"params": params, **state}, tokens, pre_logits=True,
+                mutable=list(state))
+            return objective(x, emb, tokens), new_state
+    else:
+        def loss_fn(params, tokens):
+            return objective(*pre(params, tokens), tokens)
+    # what the model sums on the device, for the compiled step
+    loss_fn.device_sums = tuple(getattr(model, "device_sums", ()))
     return loss_fn

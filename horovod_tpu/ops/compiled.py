@@ -56,6 +56,7 @@ from ..core.message import Adasum, Average, ReduceOp, Sum
 from ..telemetry import programs
 from ..utils import profiler
 from . import adasum as adasum_ops
+from . import device_sums
 from . import grad_hook
 from . import quantize as quantize_mod
 from .xla_ops import shard_map, _is_float
@@ -413,13 +414,15 @@ class _TimedFirstCall:
     shardings; no arrays) and answers ``report()`` from them, lazily."""
 
     __slots__ = ("_fn", "_scope", "_timed", "_args", "_report",
-                 "reduced_bytes")
+                 "reduced_bytes", "sum_names")
 
     def __init__(self, fn, scope=None):
         self._fn = fn
         # [gradient bytes all-reduced, of them inside the backward]: a
         # train step's program fills it when it is traced
         self.reduced_bytes = getattr(fn, "reduced_bytes", (0, 0))
+        # the sums a train step's model makes on the device
+        self.sum_names = getattr(fn, "sum_names", ())
         self._scope = scope     # a ``jax.named_scope`` the program has
         self._timed = False
         self._args = self._report = None
@@ -2318,7 +2321,10 @@ def _call_program(prog, state, tree):
     if all_bytes:
         reduced.inc(all_bytes)
         in_backward.inc(backward_bytes)
-    return out
+    if len(out) == 3:
+        # the model sums on the device: note where the newest are
+        device_sums.publish(prog, prog.sum_names, out[2])
+    return out[:2]
 
 
 class _CompiledTrainStep:
@@ -2392,22 +2398,49 @@ class _CompiledTrainStep:
             updates, opt_state = optimizer.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state
 
+        sum_names = device_sums.declared(loss_fn)
+
+        def counted_loss(params, *args):
+            """-> (loss, (new_aux, what the model summed on the
+            device)), for a loss function that declares such sums."""
+            with device_sums.collecting() as found:
+                out = loss_fn(params, *args)
+            loss, new_aux = out if has_aux else (out, None)
+            return loss, (new_aux, {n: found[n] for n in sum_names})
+
         def grad_call(params, aux, batch):
-            """-> (loss, new_aux, grads); aux threads mutable model
-            state (e.g. BN batch_stats) through the step."""
-            if has_aux:
+            """-> (loss, new_aux, grads, sums); aux threads mutable
+            model state (e.g. BN batch_stats) through the step, sums
+            are what the model counted on the device ({} for most)."""
+            args = (aux, batch) if has_aux else (batch,)
+            if sum_names:
+                (loss, (new_aux, sums)), grads = jax.value_and_grad(
+                    counted_loss, has_aux=True)(params, *args)
+            elif has_aux:
                 (loss, new_aux), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, aux, batch)
+                    loss_fn, has_aux=True)(params, *args)
+                sums = {}
             else:
                 loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-                new_aux = aux
-            return loss, new_aux, grads
+                new_aux, sums = aux, {}
+            return loss, (aux if new_aux is None else new_aux), grads, sums
 
-        def pack(params, opt_state, aux):
-            state = {"params": params, "opt_state": opt_state}
+        def pack(state, params, opt_state, aux, sums, loss):
+            """The program's result: the new state and the loss, and
+            where the model sums on the device a copy of the new sums
+            (the state's own are donated by the next call)."""
+            new = {"params": params, "opt_state": opt_state}
             if has_aux:
-                state["aux"] = aux
-            return state
+                new["aux"] = aux
+            if not sum_names:
+                return new, loss
+            new[device_sums.STATE_KEY] = totals = {
+                n: device_sums.accumulate(
+                    state[device_sums.STATE_KEY][n], sums[n])
+                for n in sum_names}
+            # one array of all of them: a value of its own, so a buffer
+            # of its own whatever the compiler shares
+            return new, loss, jnp.stack([totals[n] for n in sum_names])
 
         def reduce_leaf_sharded(g):
             if op == Average:
@@ -2440,13 +2473,14 @@ class _CompiledTrainStep:
                 batch = jax.tree.map(lambda x: x[0], batch_rows)
                 with jax.named_scope(SCOPE_LOSS_AND_GRAD), \
                         hooks() as in_backward:
-                    loss, new_aux, grads = grad_call(
+                    loss, new_aux, grads, sums = grad_call(
                         state["params"], state.get("aux"), batch)
                 with jax.named_scope(SCOPE_GRAD_REDUCE):
                     grads, reduced_bytes[:] = _reduce_the_rest(
                         grads, reduce_leaf_sharded,
                         in_backward.covered if in_backward else ())
                     loss = lax.pmean(loss, "hvd")
+                    sums = {n: lax.psum(v, "hvd") for n, v in sums.items()}
                 if has_aux:
                     # cross-replica averaged aux (float leaves): the
                     # sync-BN convention for running statistics; other
@@ -2458,7 +2492,7 @@ class _CompiledTrainStep:
                 with jax.named_scope(SCOPE_OPTIMIZER):
                     params, opt_state = update(
                         state["params"], state["opt_state"], grads)
-                return pack(params, opt_state, new_aux), loss
+                return pack(state, params, opt_state, new_aux, sums, loss)
 
             # check_vma=False: jax 0.9's varying-manual-axes checker
             # mistypes cotangents of values closed over by the loss as
@@ -2467,12 +2501,12 @@ class _CompiledTrainStep:
             # parallel/_shard_map.make_attention_fn)
             prog = shard_map(body, mesh=ex.mesh,
                              in_specs=(P(), P("hvd")),
-                             out_specs=(P(), P()),
+                             out_specs=(P(),) * (3 if sum_names else 2),
                              check_vma=False)
         else:
             def prog(state, batch_rows):   # stacked: (R, ...) leaves
                 with jax.named_scope(SCOPE_LOSS_AND_GRAD):
-                    losses, new_aux, grads = jax.vmap(
+                    losses, new_aux, grads, sums = jax.vmap(
                         lambda b: grad_call(
                             state["params"], state.get("aux"), b)
                     )(batch_rows)
@@ -2487,6 +2521,7 @@ class _CompiledTrainStep:
                         grads = jax.tree.map(adasum_ops.adasum_reduce,
                                              grads)
                     loss = jnp.mean(losses)
+                    sums = {n: jnp.sum(v, axis=0) for n, v in sums.items()}
                 if has_aux:
                     with jax.named_scope(SCOPE_AUX_REDUCE):
                         new_aux = jax.tree.map(
@@ -2497,13 +2532,14 @@ class _CompiledTrainStep:
                 with jax.named_scope(SCOPE_OPTIMIZER):
                     params, opt_state = update(
                         state["params"], state["opt_state"], grads)
-                return pack(params, opt_state, new_aux), loss
+                return pack(state, params, opt_state, new_aux, sums, loss)
 
         donate = (0,) if self.donate else ()
         jitted = jax.jit(
             prog, donate_argnums=donate,
             compiler_options=dict(self._compiler_options(ex)) or None)
         jitted.reduced_bytes = reduced_bytes
+        jitted.sum_names = sum_names
         return jitted
 
     def _compiler_options(self, ex):
@@ -2915,6 +2951,9 @@ class _CompiledTrainStep:
         state = {"params": params, "opt_state": opt_state}
         if self.has_aux:
             state["aux"] = {} if aux is None else aux
+        sum_names = device_sums.declared(self.loss_fn)
+        if sum_names:
+            state[device_sums.STATE_KEY] = device_sums.zeros(sum_names)
         if ex.shard_mode:
             rep = NamedSharding(ex.mesh, P())
             single_proc = jax.process_count() == 1

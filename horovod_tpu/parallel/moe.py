@@ -27,11 +27,12 @@ trades dropped tokens against padded exchange bytes — both move the
 same wire, so they sweep together.
 """
 
-from functools import partial
+from functools import lru_cache, partial
 
 import numpy as np
 
 import jax
+import jax.custom_batching
 import jax.numpy as jnp
 from jax import lax
 
@@ -40,7 +41,8 @@ __all__ = [
     "parse_moe_label", "snap_ep", "expert_capacity", "top_k_gating",
     "make_dispatch_plan", "straight_through", "moe_dispatch",
     "moe_combine", "capacity_moe_apply", "quantized_all_to_all",
-    "dense_flop_matched_ff",
+    "dense_flop_matched_ff", "score_top_k_routing", "updated_expert_bias",
+    "held_buffer_rows", "routed_experts_apply",
 ]
 
 #: expert-parallel degrees the autotuner sweeps (snapped at latch
@@ -227,6 +229,251 @@ def capacity_moe_apply(x, router_w, wi_gate, wi_up, wo, *, topk,
             .reshape(ep * E, cap, M)
     y = moe_combine(out, idx, pos, keep, weights).astype(x.dtype)
     return y, {"n_dropped": n_dropped, "capacity": cap}
+
+
+# ---------------------------------------------------------------------------
+# the dropless routed layer that is told which experts it holds
+
+def score_top_k_routing(x, router_w, expert_bias, topk, *, route_scale=1.0):
+    """Sigmoid scores over ALL experts in float32, the ``topk`` largest
+    of ``scores + expert_bias`` selected, and the selected SCORES (the
+    bias enters the selection only) renormalised and scaled:
+    ``(weights, idx)``, both (T, topk).  The gradient reaches the
+    router through the weights only."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + expert_bias, topk)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * route_scale, idx
+
+
+def updated_expert_bias(expert_bias, tokens_per_expert, coeff):
+    """``expert_bias`` after a step in which the experts got
+    ``tokens_per_expert`` (E,): up by ``coeff`` for an expert that got
+    fewer than the mean, down for one that got more (the balance
+    without an auxiliary loss of arXiv:2412.19437, section 2.1.2).  A
+    rule of the training loop, outside the gradient."""
+    load = tokens_per_expert.astype(jnp.float32)
+    return expert_bias + coeff * jnp.sign(jnp.mean(load) - load)
+
+
+#: rows of the grouped products' buffer are whole tiles of this many
+_ROW_TILE = 512
+
+
+def held_buffer_rows(n_assignments, held, num_experts):
+    """Rows of the buffer the held experts' assignments pass through:
+    5/4 of what a balanced router sends the held experts, in whole
+    tiles, and at most every assignment there is.  A step whose held
+    assignments fit passes once; one with more passes again for each
+    further buffer's worth, so none is dropped."""
+    balanced = -(-n_assignments * held // num_experts)
+    rows = -(-(balanced * 5 // 4) // _ROW_TILE) * _ROW_TILE
+    return min(rows, n_assignments)
+
+
+def _sum_by_owner(rows, slot, valid):
+    """(N, M) from ``rows`` (R, M): owner n's sum over its K slots of
+    the rows ``slot[n, k]``, where ``valid``; float32."""
+    picked = rows[jnp.where(valid, slot, 0)]
+    return jnp.sum(jnp.where(valid[..., None], picked, 0), axis=1,
+                   dtype=jnp.float32)
+
+
+# Rows move between the owners' order (tokens) and the buffer's order
+# (by expert) as GATHERS in both directions and in both passes: each
+# buffer row knows its owner (``owner``), each owner knows its rows
+# (``slot``, the inverse), so the transpose of a gather is the other
+# gather and never the scatter-add autodiff would write (on a v5e a
+# scatter-add of 20,480 rows of 2,048 takes 2.8 ms, the gather of those
+# rows 0.3 ms, the gather over all 131,072 slots 2.0 ms).
+
+@jax.custom_vjp
+def _to_rows(x, owner, owned, slot, valid):
+    """Buffer rows from their owners: ``x[owner]``, zero where no group
+    owns the row."""
+    return jnp.where(owned, x[owner], 0)
+
+
+def _to_rows_fwd(x, owner, owned, slot, valid):
+    return _to_rows(x, owner, owned, slot, valid), (slot, valid)
+
+
+def _to_rows_bwd(res, ct):
+    slot, valid = res
+    return _sum_by_owner(ct, slot, valid).astype(ct.dtype), None, None, \
+        None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(rows, owner, owned, slot, valid):
+    """Each owner's sum of its buffer rows (float32)."""
+    return _sum_by_owner(rows, slot, valid)
+
+
+def _from_rows_fwd(rows, owner, owned, slot, valid):
+    return _sum_by_owner(rows, slot, valid), (owner, owned, rows[:0])
+
+
+def _from_rows_bwd(res, ct):
+    owner, owned, like = res
+    return jnp.where(owned, ct[owner], 0).astype(like.dtype), None, None, \
+        None, None
+
+
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
+def _pass_of_experts(rows_held, topk, start, y, x, order, slot, sizes,
+                     weights, wi_gate, wi_up, wo):
+    """``y`` plus the held experts' part for the assignments ``start``
+    .. ``start + rows_held`` of ``order`` (held ones first, by expert;
+    ``slot`` is its inverse): SwiGLU as grouped products over the
+    ragged groups, weighted and summed back by token; and how many rows
+    some group owned."""
+    with jax.named_scope("dispatch"):
+        rows = lax.dynamic_slice(order, (start,), (rows_held,))
+        ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows_held)
+        groups = jnp.diff(ends, prepend=0)
+        owned = (jnp.arange(rows_held) < ends[-1])[:, None]
+        token = rows // topk
+        local = slot - start
+        valid = (local >= 0) & (local < ends[-1])
+        xs = _to_rows(x, token, owned, local, valid)
+    with jax.named_scope("experts"):
+        gate = jax.nn.silu(lax.ragged_dot(xs, wi_gate, groups))
+        up = lax.ragged_dot(xs, wi_up, groups)
+        out = lax.ragged_dot(gate * up, wo, groups)
+    with jax.named_scope("combine"):
+        w = _to_rows(weights.reshape(-1, 1), rows, owned,
+                     local.reshape(-1, 1), valid.reshape(-1, 1))
+        # a row no group owns holds whatever the product left there
+        out = (jnp.where(owned, out, 0) * w).astype(x.dtype)
+        return y + _from_rows(out, token, owned, local, valid), ends[-1]
+
+
+@lru_cache(maxsize=None)
+def _held_experts(rows_held, topk):
+    """``(x, order, slot, sizes, weights, wi_gate, wi_up, wo) -> (y
+    (T, M) float32, rows computed)``: as many passes through a buffer of
+    ``rows_held`` rows as the held assignments need, one where they
+    fit.
+
+    The number of passes is known only on the device, so both
+    directions are loops of their own (a loop of a data-dependent
+    length has no transpose): the backward pass recomputes each pass
+    and takes its gradients (``jax.vjp``), summing further passes'
+    gradients in the cotangents' own dtype.  Both are loops under
+    ``vmap`` too (the one-device compiled step maps the loss over its
+    rank axis, and a grouped product has no batching rule for that)."""
+
+    def passes(sizes):
+        return -(-jnp.sum(sizes) // rows_held)
+
+    @jax.custom_batching.sequential_vmap
+    def forward(x, order, slot, sizes, weights, wi_gate, wi_up, wo):
+        args = (x, order, slot, sizes, weights, wi_gate, wi_up, wo)
+
+        def one(i, carry):
+            y, computed = carry
+            y, owned = _pass_of_experts(rows_held, topk, i * rows_held,
+                                        y, *args)
+            return y, computed + owned
+
+        first = one(0, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+        return lax.fori_loop(1, passes(sizes), one, first)
+
+    @jax.custom_batching.sequential_vmap
+    def backward(x, order, slot, sizes, weights, wi_gate, wi_up, wo, ct):
+        def gradients(start):
+            _, vjp = jax.vjp(
+                lambda x, *rest: _pass_of_experts(
+                    rows_held, topk, start, jnp.zeros_like(ct), x,
+                    order, slot, sizes, *rest)[0],
+                x, weights, wi_gate, wi_up, wo)
+            return vjp(ct)
+
+        return lax.fori_loop(
+            1, passes(sizes),
+            lambda i, total: jax.tree.map(
+                jnp.add, total, gradients(i * rows_held)),
+            gradients(0))
+
+    @jax.custom_vjp
+    def held_experts(*args):
+        return forward(*args)
+
+    def fwd(*args):
+        return forward(*args), args
+
+    def bwd(args, cts):
+        dx, dweights, dgate, dup, dwo = backward(*args, cts[0])
+        return dx, None, None, None, dweights, dgate, dup, dwo
+
+    held_experts.defvjp(fwd, bwd)
+    return held_experts
+
+
+def routed_experts_apply(x, router_w, expert_bias, wi_gate, wi_up, wo,
+                         *, first_expert=0, topk, route_scale=1.0):
+    """The routed experts of one layer as ONE member of an
+    expert-parallel group computes them: it is told which experts it
+    holds (``wi_gate`` / ``wi_up`` (H, M, F) and ``wo`` (H, F, M) are
+    experts ``first_expert`` .. ``first_expert + H`` of the
+    ``router_w.shape[-1]`` the router scores), routes every token over
+    all of them, and returns the part of ``sum_j w_j
+    SwiGLU_{idx_j}(x)`` that its own experts give.  Assignments to
+    absent experts contribute nothing; nothing stands in for the
+    members that hold them or for the exchange with them.
+
+    No assignment to a held expert is dropped, whatever the routing:
+    the assignments are sorted by expert, held ones first, and the
+    products run over ragged groups (``lax.ragged_dot``) through a
+    buffer of ``held_buffer_rows`` rows, as many times as it takes
+    (once under a router near balance).
+
+    ``x`` (T, M) in the products' dtype; the router's product, scores
+    and top-k are float32.  Returns ``(y (T, M) float32, counts,
+    tokens_per_expert)``; ``counts`` is int32 (3,): the assignments
+    (T * topk), those that fell on held experts, and those of them that
+    were not computed (0, or the layer is not dropless);
+    ``tokens_per_expert`` int32 (num_experts,) is what
+    :func:`updated_expert_bias` reads."""
+    T = x.shape[0]
+    held, num_experts = wi_gate.shape[0], router_w.shape[-1]
+    n = T * topk
+    with jax.named_scope("route"):
+        weights, idx = score_top_k_routing(
+            x, router_w, expert_bias, topk, route_scale=route_scale)
+        tokens_per_expert = jnp.sum(
+            idx[:, :, None] == jnp.arange(num_experts), axis=(0, 1),
+            dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = idx.reshape(n) - first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        rows_held = held_buffer_rows(n, held, num_experts)
+        # the assignments by expert, held ones first, with room for the
+        # last pass to read a whole buffer ...
+        order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                        (0, -n % rows_held))
+        # ... and its inverse for the held ones, without a second sort:
+        # a group's offset plus how many of the group came earlier
+        mine = key[:, None] == jnp.arange(held)[None]
+        sizes = jnp.sum(mine, axis=0, dtype=jnp.int32)
+        earlier = jnp.cumsum(mine, axis=0, dtype=jnp.int32) - 1 \
+            + (jnp.cumsum(sizes) - sizes)[None]
+        slot = jnp.where(key < held, jnp.sum(
+            jnp.where(mine, earlier, 0), axis=1), n).reshape(T, topk)
+    y, computed = _held_experts(rows_held, topk)(
+        x, order, slot, sizes, weights, wi_gate, wi_up, wo)
+    n_held = jnp.sum(sizes)
+    return y, jnp.stack([jnp.int32(n), n_held, n_held - computed]), \
+        tokens_per_expert
 
 
 # ---------------------------------------------------------------------------

@@ -700,6 +700,7 @@ def counter_total(name, **labels):
     """Convenience: current value of a counter/gauge family summed
     over children (or one child when ``labels`` are given).  Benchmarks
     read deltas of these instead of reaching into engine attributes."""
+    registry().refresh()
     fam = registry().get(name)
     if fam is None:
         return 0.0

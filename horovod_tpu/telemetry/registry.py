@@ -260,6 +260,18 @@ class MetricRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families = {}
+        self._before_read = []
+
+    def on_read(self, refresh):
+        """Call ``refresh()`` before every read of the registry
+        (``snapshot``, ``telemetry.counter_total``): for values that
+        are cheap to keep elsewhere and dear to fetch, such as sums
+        kept on the device (ops/device_sums.py)."""
+        self._before_read.append(refresh)
+
+    def refresh(self):
+        for refresh in list(self._before_read):
+            refresh()
 
     def _family(self, name, mtype, help_text, labelnames, buckets=None):
         with self._lock:
@@ -300,6 +312,7 @@ class MetricRegistry:
     def snapshot(self):
         """JSON-able view of every family — the exposition and
         aggregation input format."""
+        self.refresh()
         with self._lock:
             fams = list(self._families.items())
         return {name: fam.snapshot() for name, fam in fams}

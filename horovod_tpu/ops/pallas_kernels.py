@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -357,45 +358,70 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
     o, m, l = jax.lax.fori_loop(first_kb, num_kb, body, (o0, m0, l0))
     l = jnp.maximum(l, np.float32(1e-30))
     o_ref[0] = (o / l[:, None]).astype(o_ref.dtype)
-    # logsumexp per row, consumed by the backward kernels; stored as
+    # logsumexp per row, consumed by the backward kernel; stored as
     # (BH, 1, S) so TPU block shapes satisfy the (8, 128) tiling rule
     lse_ref[0, 0] = m + jnp.log(l)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dq_ref, *, block_k, scale,
-                         window=None):
-    """dq for one query block: loop over key blocks <= this one,
-    recompute p from (q, k, lse), accumulate ds @ k."""
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, block_k,
+                      scale, window=None):
+    """The whole backward for one query block: loop over the key blocks
+    inside the band, recompute p from (q, k, lse) ONCE per block pair
+    and feed all three gradients from it (5 matmuls a pair).  dq of
+    this query block rides the loop's carry; dk / dv of every key block
+    are summed into ``dk_acc`` / ``dv_acc``, float32 (S, D) buffers
+    that stay in VMEM across the query blocks of one (batch, head):
+    zeroed at the first query block, scaled, cast and written out at
+    the last.  So the query-block grid axis must run in order
+    (``"arbitrary"``).
+
+    The scores are held TRANSPOSED, (block_k, block_q): lse and delta
+    are rows that broadcast along sublanes as they lie, dv and dk take
+    plain products, and only dq's takes a transposed left operand."""
     block_q = q_ref.shape[1]
     qi = pl.program_id(1)
     q = q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0]                                 # (1, bq)
+    delta = delta_ref[0]
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, (block_k, block_q), 1)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        cols = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        st = jax.lax.dot_general(                    # (bk, bq) f32
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, (block_k, block_q), 0)
         mask = q_pos >= k_pos
         if window is not None:
             mask = mask & (q_pos - k_pos < window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), np.float32(0.0))
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+        pt = jnp.where(mask, jnp.exp(st - lse), np.float32(0.0))
+        dv_acc[cols, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk_acc[cols, :] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    # the forward's band: key blocks up to this query block's LAST row,
+    # from the first row's window start
     num_kb = ((qi + 1) * block_q - 1) // block_k + 1
     first_kb = 0
     if window is not None:
@@ -403,72 +429,22 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
     dq = jax.lax.fori_loop(
         first_kb, num_kb, body, jnp.zeros((block_q, q_ref.shape[2]),
                                           jnp.float32))
+    # s carried one `scale` factor, so dq = scale * (ds @ k_unscaled)
+    # and dk = scale * (ds^T @ q_unscaled)
     dq_ref[0] = (dq * np.float32(scale)).astype(dq_ref.dtype)
 
-
-def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref,
-                          delta_ref, dk_ref, dv_ref, *, block_q,
-                          seq_len, scale, window=None):
-    """dk/dv for one key block: loop over query blocks >= this one."""
-    block_k = k_ref.shape[1]
-    ki = pl.program_id(1)
-    k = k_ref[0]
-    v = v_ref[0]
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        s = jax.lax.dot_general(                     # (bq, bk) f32
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * np.float32(scale)
-        q_pos = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = q_pos >= k_pos
-        if window is not None:
-            mask = mask & (q_pos - k_pos < window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), np.float32(0.0))
-        pc = p.astype(do.dtype)
-        dv = dv + jax.lax.dot_general(
-            pc, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dsc = ds.astype(q.dtype)
-        dk = dk + jax.lax.dot_general(
-            dsc, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
-
-    # causal: only query blocks whose END reaches this key block; a
-    # sliding window also stops once every query row is PAST the last
-    # key row's window (q_pos >= k_pos_last + window)
-    first_qb = (ki * block_k) // block_q
-    num_qb = seq_len // block_q
-    if window is not None:
-        last_q = (ki + 1) * block_k - 1 + window - 1   # last visible q
-        num_qb = jnp.minimum(num_qb, last_q // block_q + 1)
-    D = k_ref.shape[2]
-    dk0 = jnp.zeros((block_k, D), jnp.float32)
-    dv0 = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_qb, num_qb, body, (dk0, dv0))
-    # s carried one `scale` factor, so dk = scale * (ds^T @ q_unscaled)
-    dk_ref[0] = (dk * np.float32(scale)).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    @pl.when(qi == pl.num_programs(1) - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * np.float32(scale)).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _named_kernel(name, kernel, **pallas_call_args):
     """``pl.pallas_call`` under ``jax.named_scope(name)`` and with the
     same ``name=``: the kernel is then a row of its own in a device
     trace (the scope ends the custom call's ``op_name``; without it
-    the trace names all three flash kernels after the flax module
-    they sit in)."""
+    the trace names both flash kernels after the flax module they
+    sit in)."""
     call = pl.pallas_call(kernel, name=name, **pallas_call_args)
 
     def named(*operands):
@@ -523,55 +499,73 @@ def _flash_vjp_fwd(qf, kf, vf, block_q, block_k, bwd_block_q,
     return out, (qf, kf, vf, out, lse)
 
 
+# VMEM of one TensorCore (v5e, v6e: 128 MiB), less room for what the
+# compiler keeps for itself; its default scoped limit is 16 MiB of it
+_VMEM_USABLE_BYTES = 112 << 20
+
+
+def _flash_bwd_vmem_bytes(S, D, block_q, block_k, dtype):
+    """An upper bound, from the shapes, on what the backward kernel
+    holds in VMEM: k and v whole and the dk / dv output blocks (each
+    double-buffered by the pipeline), the two float32 accumulators,
+    the q / do / dq blocks and the lse / delta rows (a row fills 8
+    sublanes), the loop body's score-shaped and (block, D)
+    temporaries, and 2 MiB to spare.  Bisecting the limit off the chip
+    (a compile for a described v5e), the compiler takes S 8192, D 128,
+    bf16 at 27 MiB where this gives 36.8, and S 32768 at 99 where this
+    gives 108.8."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = max(D, 128)                    # the lanes a row occupies
+    held = 4 * 2 * S * lanes * item + 2 * S * lanes * 4 \
+        + 3 * 2 * block_q * lanes * item + 2 * 2 * 8 * block_q * 4
+    body = 8 * block_q * block_k * 4 \
+        + 4 * (block_q + block_k) * lanes * 4
+    return held + body + (2 << 20)
+
+
 def _flash_vjp_bwd(block_q, block_k, bwd_block_q, bwd_block_k,
                    window, interpret, res, do):
-    # the backward kernels tile independently of the forward: their
-    # per-block dot chain (5 matmuls + exp) has a different
+    # the backward kernel tiles independently of the forward: its
+    # per-pair dot chain (5 matmuls + exp) has a different
     # VMEM/pipeline sweet spot than the forward's 2
     block_q, block_k = bwd_block_q, bwd_block_k
     qf, kf, vf, out, lse = res
     BH, S, D = qf.shape
     scale = 1.0 / np.sqrt(D)
+    vmem = _flash_bwd_vmem_bytes(S, D, block_q, block_k, qf.dtype)
+    if not interpret and vmem > _VMEM_USABLE_BYTES:
+        raise ValueError(
+            f"flash_attention: the backward kernel keeps k, v, dk and "
+            f"dv of one (batch, head) in VMEM and asks {vmem} bytes "
+            f"for S={S}, D={D}, {qf.dtype.name}, blocks "
+            f"{block_q}x{block_k}; a chip has {_VMEM_USABLE_BYTES} to "
+            f"give: shard the sequence (parallel/ring_attention.py) "
+            f"or shorten it")
     # delta = rowsum(dO * O) — cheap elementwise, plain XLA; shaped
     # (BH, 1, S) for the TPU block-tiling rule like lse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]              # (BH, 1, S)
-    dq = _named_kernel(
-        "flash_dq",
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                          scale=scale, window=window),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        interpret=interpret,
-    )(qf, kf, vf, do, lse, delta)
-    dk, dv = _named_kernel(
+    block = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))
+    row = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
+    whole = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0))
+    # ONE kernel, under the old dkv kernel's scope and name: the
+    # benchmark's readers know ``flash_dkv`` (chipbench/scope_join.py)
+    dq, dk, dv = _named_kernel(
         "flash_dkv",
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          seq_len=S, scale=scale, window=window),
-        out_shape=(jax.ShapeDtypeStruct((BH, S, D), kf.dtype),
+        functools.partial(_flash_bwd_kernel, block_k=block_k,
+                          scale=scale, window=window),
+        out_shape=(jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
+                   jax.ShapeDtypeStruct((BH, S, D), kf.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), vf.dtype)),
-        grid=(BH, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0))),
+        grid=(BH, S // block_q),
+        in_specs=[block, block, row, row, whole, whole],
+        out_specs=(block, whole, whole),
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(kf, vf, qf, do, lse, delta)
+    )(qf, do, lse, delta, kf, vf)
     return dq, dk, dv
 
 
@@ -585,14 +579,19 @@ def flash_attention(q, k, v, *, block_q=512, block_k=512,
 
     Memory: O(block_q * S) VMEM per program instead of O(S^2) HBM —
     the long-context single-chip workhorse.  Differentiable: the
-    backward pass is two pallas kernels (dq; dk/dv) recomputing
-    attention probabilities blockwise from the saved logsumexp, per
-    FlashAttention's backward (never materializing the S^2 matrix).
-    ``bwd_block_*`` tile the backward kernels independently (their
+    backward pass is ONE pallas kernel (scope and name ``flash_dkv``,
+    kept from the dk/dv kernel it grew out of; it yields dq too) that
+    recomputes each block pair's probabilities once from the saved
+    logsumexp and feeds dq, dk and dv from them, per FlashAttention's
+    backward (never materializing the S^2 matrix).  It keeps k, v and
+    the float32 dk / dv sums of one (batch, head) in VMEM and asks the
+    compiler for the VMEM its shapes need; a sequence too long for a
+    chip's VMEM raises a ValueError that names the bytes.
+    ``bwd_block_*`` tile the backward kernel independently (its
     5-matmul block body has a different VMEM sweet spot than the
     forward's 2); default: same as the forward blocks.
     ``window`` enables SLIDING-WINDOW attention (mistral-style): each
-    query sees only the last ``window`` positions, and all three
+    query sees only the last ``window`` positions, and both
     kernels skip blocks wholly outside the band — attention cost
     becomes O(S·window) instead of O(S²/2).  Gradient-exact vs
     ``dense_causal_attention(window=...)``.

@@ -58,6 +58,9 @@ def chip(chips):
 _QKV = [((5, 2048, 8, 128), jnp.bfloat16)] * 3
 # the long-context windowed shape: S8192, W1024
 _QKV_LONG = [((1, 8192, 8, 128), jnp.bfloat16)] * 3
+# the benchmark's s8k cells (chipbench/: Mistral-7B and Trinity-Mini at
+# 2 x 8192 tokens, 32 heads of 128): full, Mistral's window, Trinity's
+_QKV_S8K = [((2, 8192, 32, 128), jnp.bfloat16)] * 3
 _N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
 # ResNet-50 b128 stage-1 1x1 conv as a matmul: (B*H*W, Cin) @ (Cin, Cout)
 _CONV = [((128 * 56 * 56, 64), jnp.bfloat16), ((64, 256), jnp.bfloat16),
@@ -90,9 +93,16 @@ def _kernel_cases():
     blocks = _N // 256
     return {
         "flash_fwd": (_flash(), _QKV, 1),
-        "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 3),
+        "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 2),
         "flash_window_bwd": (
-            _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 3),
+            _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 2),
+        # where an over-asked VMEM shows without a chip: the backward
+        # keeps k, v, dk, dv whole and two float32 accumulators
+        "flash_bwd_s8k": (_grad_of_sum(_flash(), 3), _QKV_S8K, 2),
+        "flash_bwd_s8k_w4096": (
+            _grad_of_sum(_flash(window=4096), 3), _QKV_S8K, 2),
+        "flash_bwd_s8k_w2048": (
+            _grad_of_sum(_flash(window=2048), 3), _QKV_S8K, 2),
         "quantize_int8": (
             lambda x: pk.quantize_blockwise(x, interpret=False),
             [((_N,), jnp.float32)], 1),
@@ -153,7 +163,8 @@ def _lm436m_step_case():
 
 
 @pytest.mark.parametrize("name", [
-    "flash_fwd", "flash_bwd", "flash_window_bwd", "quantize_int8",
+    "flash_fwd", "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
+    "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
     "fused_scale_cast", "conv1x1_bn_fwd", "conv1x1_bn_bwd",
     pytest.param("lm436m_step", marks=pytest.mark.slow)])
@@ -165,7 +176,7 @@ def test_chip_compiler_takes(chip, name):
 
     if name == "lm436m_step":
         program, state, batch = _lm436m_step_case()
-        fn, min_calls = program(chip), 3
+        fn, min_calls = program(chip), 2
         args = [jax.tree.map(lambda s: shaped(s.shape, s.dtype), state),
                 shaped(*batch)]
     else:
@@ -175,13 +186,33 @@ def test_chip_compiler_takes(chip, name):
     # does not, and Mosaic has no 64-bit index
     with jax.enable_x64(False):
         compiled = fn.lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= min_calls
+    calls = compiled.as_text().count("tpu_custom_call")
+    assert calls >= min_calls
+    if name.startswith("flash_"):
+        # the forward, and ONE backward kernel: no more
+        assert calls == min_calls
     if name == "lm436m_step":
         mem = compiled.memory_analysis()
         # donated state in, the same bytes out, and the step's
         # temporaries: what B5 needs of a 16 GB chip
         assert mem.alias_size_in_bytes > 5e9
         assert mem.temp_size_in_bytes < 12.5e9
+
+
+def test_flash_backward_that_cannot_fit_vmem_names_the_bytes():
+    """The backward kernel asks for the VMEM its shapes need; a
+    sequence whose k, v, dk, dv and accumulators pass what a chip has
+    is refused while tracing, with the bytes, not by the compiler."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    asked = pk._flash_bwd_vmem_bytes(65536, 128, 512, 512, jnp.bfloat16)
+    assert asked > pk._VMEM_USABLE_BYTES
+    x = jax.ShapeDtypeStruct((1, 65536, 1, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match=f"{asked} bytes.*S=65536"):
+        jax.eval_shape(_grad_of_sum(_flash(), 3), x, x, x)
+    # the cells' own shape asks a third of it
+    assert pk._flash_bwd_vmem_bytes(
+        8192, 128, 512, 512, jnp.bfloat16) < 40 << 20
 
 
 def test_dp_step_allreduces_run_beside_compute(chips):

@@ -480,9 +480,11 @@ def test_step_scopes_in_lowered_text(hvd_shutdown, model):
     wanted = STEP_SCOPES + (("hvd_step/aux_reduce",)
                             if model == "has_aux" else
                             ("lm_head_ce", "embed", "flash_fwd",
-                             "flash_dq", "flash_dkv"))
+                             "flash_dkv"))
     for scope in wanted:
         assert scope in text, scope
+    # the flash backward is ONE kernel, under the old dkv scope
+    assert "flash_dq" not in text
 
 
 @pytest.mark.parametrize("sharded", [False, True],

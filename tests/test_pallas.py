@@ -128,6 +128,65 @@ def test_flash_attention_independent_bwd_blocks():
                                rtol=1e-5, atol=1e-5)
 
 
+# (dtype, B, H, bwd_block_q, bwd_block_k, window): the ONE backward
+# kernel against the dense reference's gradients.  S 64 with blocks of
+# 16 gives every key block up to four query blocks, so the float32
+# dk / dv accumulators are summed into across grid steps; a window of
+# 5 / 17 / 63 ends inside a block; B*H > 1 makes a second (batch, head)
+# start from zeroed accumulators; unequal blocks both ways move the
+# band's block bounds.
+_BWD_CASES = [
+    (jnp.float32, 1, 1, 16, 16, None),
+    (jnp.bfloat16, 1, 1, 16, 16, None),
+    (jnp.float32, 1, 1, 16, 16, 5),
+    (jnp.float32, 1, 1, 16, 16, 17),
+    (jnp.float32, 1, 1, 16, 16, 63),
+    (jnp.bfloat16, 2, 2, 16, 16, 17),
+    (jnp.float32, 1, 1, 32, 8, None),
+    (jnp.float32, 1, 1, 8, 32, None),
+    (jnp.float32, 1, 2, 32, 8, 17),
+    (jnp.float32, 2, 1, 8, 32, 17),
+    (jnp.float32, 2, 3, 16, 16, None),
+    (jnp.bfloat16, 2, 2, 8, 32, 63),
+]
+
+
+@pytest.mark.parametrize(
+    "dtype,B,H,bq,bk,window", _BWD_CASES,
+    ids=[f"{np.dtype(c[0]).name}-B{c[1]}H{c[2]}-{c[3]}x{c[4]}-W{c[5]}"
+         for c in _BWD_CASES])
+def test_flash_backward_one_kernel_matches_dense(dtype, B, H, bq, bk,
+                                                 window):
+    from functools import partial
+
+    S, D = 64, 16
+    keys = jax.random.split(jax.random.PRNGKey(B * 7 + H), 4)
+    q, k, v, w = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
+                  for kk in keys)
+
+    def loss(fn, q, k, v):
+        # a weight per output element, so no head's gradient is a
+        # multiple of another's
+        return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    flash = partial(flash_attention, block_q=16, block_k=16,
+                    bwd_block_q=bq, bwd_block_k=bk, window=window,
+                    interpret=True)
+    dense = partial(dense_causal_attention, window=window)
+    cast = [x.astype(dtype) for x in (q, k, v)]
+    got = jax.grad(partial(loss, flash), argnums=(0, 1, 2))(*cast)
+    # the reference in float32 from the SAME (rounded) inputs
+    want = jax.grad(partial(loss, dense), argnums=(0, 1, 2))(
+        *[x.astype(jnp.float32) for x in cast])
+    tol = 5e-5 if dtype == jnp.float32 else 2.5e-2
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == dtype and a.shape == b.shape
+        a = np.asarray(a.astype(jnp.float32))
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, np.asarray(b), rtol=tol, atol=tol,
+                                   err_msg=f"d{name}")
+
+
 # ---------------------------------------------------------------------------
 # block-scaled int8 wire codec (quantized collectives)
 

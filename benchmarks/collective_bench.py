@@ -173,8 +173,9 @@ def wire_pair_sweep(iters, pair_spec="all", mb=8):
 
     and the headline ratios: ``fused_per_hop_vs_staged_int8`` (best
     per-hop pair over the flat staged-int8 goodput) and
-    ``per_hop_vs_flat_f32`` (the torus-vs-flat figure the per-hop
-    path must push past — docs/benchmarks.md)."""
+    ``per_hop_vs_flat_f32`` (torus vs flat).  Only the byte counts are
+    gated and documented (tools/perf_gate.py, docs/benchmarks.md);
+    the MB/s here measure the CPU backend."""
     import numpy as np
     import horovod_tpu as hvd
     from horovod_tpu import telemetry
@@ -491,8 +492,7 @@ def bypass_worker():
 
 def run_bypass_compare(np_, iters):
     """Spawn the REAL launcher twice — bypass armed (K=3) vs disabled
-    — and report the steady-state cycle-latency ratio, the number
-    ROADMAP item 2 / docs/benchmarks.md track."""
+    — and report the steady-state cycle-latency ratio."""
     import tempfile
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -597,8 +597,7 @@ def main():
     p.add_argument("--bypass-compare", action="store_true",
                    help="steady-state cycle latency with the "
                         "negotiation bypass armed vs the full "
-                        "ready/poll path, on a REAL --np-process job "
-                        "(docs/benchmarks.md; ROADMAP item 2)")
+                        "ready/poll path, on a REAL --np-process job")
     args = p.parse_args()
 
     if args.bypass_compare:

@@ -51,7 +51,7 @@ def memory_verdict(n_params, dp, budget_gb, param_bytes=2,
     """Estimated per-device training footprint (params + grads at the
     model dtype, adam moments f32 — ÷dp under weight-update sharding)
     against the device budget.  The skip-vs-run asymmetry this gate
-    produces IS the sharding memory evidence (docs/benchmarks.md)."""
+    produces IS the sharding memory evidence."""
     opt = opt_bytes / (dp if sharded else 1)
     need_gb = n_params * (2 * param_bytes + opt) / 1e9
     return need_gb, need_gb <= budget_gb
@@ -486,7 +486,7 @@ def main():
 
     out = {"batch": args.batch, "seq": args.seq,
            "d_model": args.d_model, "layers": args.layers, **out_pp}
-    # -- memory fit gate (docs/benchmarks.md "Weight-update sharding"):
+    # -- memory fit gate:
     # big configs must SKIP with a clear verdict when the dense
     # optimizer cannot fit, and run (or at least fit) sharded — the
     # asymmetry is the memory evidence.

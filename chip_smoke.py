@@ -6,11 +6,11 @@ chip.
     python chip_smoke.py --chips 4   # the multi-chip paths, one 4-chip host
 
 One chip: a few eager collectives through the engine (two ranks stacked
-on the chip) against numpy, then lm436m at full width — exactly the
-model ``benchmarks/lm_mfu_bench.py`` builds — through the user's entry
-points (``hvd.init``, ``hvd.make_compiled_train_step``, ``init_state``,
-``place_batch``, ``step``, ``hvd.shutdown``) for ``STEPS`` steps on one
-fixed batch made from ``SEED``.
+on the chip) against numpy, then lm436m at full width (``HEADLINE``
+below) through the user's entry points (``hvd.init``,
+``hvd.make_compiled_train_step``, ``init_state``, ``place_batch``,
+``step``, ``hvd.shutdown``) for ``STEPS`` steps on one fixed batch made
+from ``SEED``.
 
 Four chips (``--chips 4``, and nothing of the above): the eager
 collectives at one rank per chip; the same lm436m step under
@@ -36,7 +36,7 @@ import time
 import traceback
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
+sys.path.insert(0, REPO)
 
 SEED = 0
 STEPS = 5               # one chip
@@ -46,6 +46,11 @@ STEPS_COMPARED = 3      # four chips: every path and its reference
 # split of every matmul's contraction) re-orders bf16 roundings, and
 # three adamw steps carry the difference forward.
 LOSS_ATOL = 0.01
+# lm436m: ~436M parameters (402.7M in the blocks, 32.8M embedding),
+# head_dim 128, SwiGLU, bf16, S=2048
+HEADLINE = dict(vocab_size=32000, d_model=1024, n_layers=24, n_heads=8,
+                d_ff=4096, max_seq_len=2048)
+HEADLINE_BATCH = 5      # what 16 GB of HBM holds beside the step's temporaries
 # rehearsal widths: every phase end to end on the CPU in seconds
 TINY = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=2,
             d_ff=128, max_seq_len=128)
@@ -156,21 +161,45 @@ def phase_eager(np_ranks):
               f" not one rank per chip")
 
 
-def lm_setup(rehearse):
-    """(config, fixed batch, attention kernel) of lm436m as
-    benchmarks/lm_mfu_bench.py builds it; the rehearsal swaps in tiny
-    widths and asks for the kernels' interpret mode."""
-    import lm_mfu_bench as mod
+def build(batch, widths=HEADLINE):
+    """(config, fixed token batch) of lm436m, or of ``widths``."""
+    import jax
+    import jax.numpy as jnp
 
+    from horovod_tpu.models import TransformerConfig
+
+    cfg = TransformerConfig(dtype=jnp.bfloat16, remat=True,
+                            remat_policy="dots_flash", **widths)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (batch, cfg.max_seq_len), 0,
+        cfg.vocab_size)
+    return cfg, tokens
+
+
+def model_and_loss(cfg, attention_fn=None):
+    """(model, loss_fn(params, tokens)): the logits projection fused
+    into a chunked loss, so the (B, S, V) float32 logits never exist.
+    Shared with tests/test_chip_compile.py so both train the same
+    program.  ``attention_fn`` defaults to the Pallas flash kernel."""
+    from horovod_tpu.models import TransformerLM, make_fused_lm_loss
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    model = TransformerLM(cfg, attention_fn=attention_fn
+                          or flash_attention)
+    return model, make_fused_lm_loss(model, n_chunks=16)
+
+
+def lm_setup(rehearse):
+    """(config, fixed batch, attention kernel) of lm436m; the rehearsal
+    swaps in tiny widths and asks for the kernels' interpret mode."""
     from horovod_tpu.ops.pallas_kernels import flash_attention
 
     if rehearse:
-        cfg, tokens = mod.build(
-            argparse.Namespace(batch=TINY_BATCH), TINY)
+        cfg, tokens = build(TINY_BATCH, TINY)
         return cfg, tokens, functools.partial(flash_attention,
                                               interpret=True)
-    cfg, tokens = mod.build(argparse.Namespace(batch=mod.HEADLINE_BATCH))
-    return cfg, tokens, None        # the benchmark's own flash kernel
+    cfg, tokens = build(HEADLINE_BATCH)
+    return cfg, tokens, None        # the flash kernel's own default
 
 
 def lm_describe(cfg, tokens):
@@ -183,9 +212,8 @@ def lm_describe(cfg, tokens):
 
 def lm_loss_and_params(cfg, tokens, attn):
     import jax
-    import lm_mfu_bench as mod
 
-    model, loss_fn = mod.model_and_loss(cfg, attn)
+    model, loss_fn = model_and_loss(cfg, attn)
     params = jax.jit(model.init)(jax.random.PRNGKey(SEED),
                                  tokens)["params"]
     return loss_fn, params

@@ -65,11 +65,12 @@
 #                         #   clean same-seed run, and two same-seed
 #                         #   faulted runs produce byte-identical
 #                         #   evidence
-#   ./ci.sh bench         # smoke: one bench.py run (real chip if any)
-#   ./ci.sh perf          # gate: collective_bench sweeps vs the
-#                         #   checked-in benchmarks/BASELINE.json
-#                         #   tolerance band (goodput + wire-byte
-#                         #   ratios; --update-baseline re-records)
+#   ./ci.sh perf          # gate: what the collective_bench,
+#                         #   lm_bench and ckpt_bench legs COUNT
+#                         #   (wire-byte ratios, per-hop bytes,
+#                         #   recompiles, parity) vs the checked-in
+#                         #   benchmarks/BASELINE.json; no timing
+#                         #   (--update-baseline re-records)
 #   ./ci.sh all           # tiers 1-3 (what the round judge re-runs,
 #                         #   split in four parts to stay under per-
 #                         #   command time caps)
@@ -237,62 +238,20 @@ case "${1:-all}" in
     python tools/integrity_smoke.py
     ;;
   perf)
-    # perf regression gate: re-runs the
-    # collective_bench wire + wire-pair sweeps and compares the
-    # goodput/byte-accounting numbers against the checked-in
-    # benchmarks/BASELINE.json tolerance band — the 3.97x int8 /
-    # 7.88x int4 codec wire, the per-hop cross-byte budgets and the
-    # fused-per-hop-vs-staged-int8 ratio (absolute floor 1.54x, the
-    # bar ISSUE 9 set) cannot silently regress.
+    # the counts a CPU run decides exactly, against the checked-in
+    # benchmarks/BASELINE.json: the 3.97x int8 / 7.88x int4 codec
+    # wire, the per-hop byte budgets, bitwise parity and zero
+    # steady-state recompiles of the bucketized reduction, anchored
+    # async saves, the MoE leg's loss gap.  No wall-clock metric:
+    # speed is measured on the chip (chipbench/, PERF.md).
     # The SAME matrix then re-runs under a seeded fault plan (fabric
     # delays, 5xx bursts, a probabilistic straggler): it must
-    # complete, move byte-identical wire traffic, and hold goodput
-    # within the bounded fault-regression budget — "fast" and
-    # "survives faults" gate as one property (docs/fleet.md).
-    # `./ci.sh perf --update-baseline` re-records after intentional
-    # perf changes; --no-fault-plan skips the faulted pass.
+    # complete and move byte-identical wire traffic.
+    # `./ci.sh perf --update-baseline` re-records after an intentional
+    # change of the codec or the accounting; --no-fault-plan skips
+    # the faulted pass.
     shift
     python tools/perf_gate.py "$@"
-    ;;
-  bench)
-    python bench.py
-    # collective sweeps on the 4-rank virtual mesh: the quantized-wire
-    # section, the PER-HOP wire-pair section (decomposed torus paths
-    # with int8/int4 cross hops vs the flat staged-int8 baseline) and
-    # the topology-aware algorithm section (flat vs hierarchical vs
-    # torus on both paths, with cross-host byte accounting + a
-    # six-dimension autotune pick) — the numbers docs/benchmarks.md
-    # quotes
-    python benchmarks/collective_bench.py --np 4 --cpu \
-      --wire-dtype all --iters 8
-    python benchmarks/collective_bench.py --np 4 --cpu \
-      --wire-pair all --iters 8
-    python benchmarks/collective_bench.py --np 4 --cpu \
-      --algorithm all --iters 8 --sizes-mb 1,8,32
-    # steady-state negotiation bypass vs the full ready/poll path on
-    # a REAL 2-process job (ROADMAP item 2's fast path; the
-    # docs/benchmarks.md control-plane row)
-    python benchmarks/collective_bench.py --np 2 --bypass-compare
-    # serving-tier throughput/latency (batcher + compiled dispatch
-    # under closed-loop load) — the docs/benchmarks.md serving row
-    python benchmarks/serve_bench.py
-    # continuous-batching decode goodput: closed-loop autoregressive
-    # streams through the slot loop + paged KV cache — tokens/sec/chip
-    # at the reported TTFT/TPOT percentiles, zero cache misses
-    # (the docs/benchmarks.md continuous row)
-    python benchmarks/serve_bench.py --continuous --streams 48
-    # pipelined LM training on the 8-device virtual mesh: dp×pp and
-    # dp×tp×pp through the MPMD runtime (1f1b + interleaved vs the
-    # gpipe fallback) — the docs/benchmarks.md pipeline rows report
-    # tok/s next to each schedule's analytic bubble fraction
-    python benchmarks/lm_bench.py --cpu 8 --batch 8 --seq 128 \
-      --d-model 64 --layers 4 --heads 4 --iters 4 --warmup 1 \
-      --impls dense --parallelism 2,1,4 --pipeline-schedule 1f1b \
-      --microbatches 4
-    python benchmarks/lm_bench.py --cpu 8 --batch 8 --seq 128 \
-      --d-model 64 --layers 4 --heads 4 --iters 4 --warmup 1 \
-      --impls dense --parallelism 2,2,2 --pipeline-schedule \
-      interleaved --microbatches 4
     ;;
   pp)
     # pipeline smoke (docs/parallelism.md): a REAL 4-process 2-stage
@@ -364,7 +323,7 @@ case "${1:-all}" in
     python tools/integrity_smoke.py
     ;;
   *)
-    echo "usage: $0 {analyze|fast|matrix|integration|chaos|fleet|scale|trace|metrics|serve|pp|data|integrity|bench|perf|all}" >&2
+    echo "usage: $0 {analyze|fast|matrix|integration|chaos|fleet|scale|trace|metrics|serve|pp|data|integrity|perf|all}" >&2
     exit 2
     ;;
 esac

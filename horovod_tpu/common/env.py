@@ -153,11 +153,8 @@ HOROVOD_TPU_RANKS_PER_PROC = "HOROVOD_TPU_RANKS_PER_PROC"
 HOROVOD_TPU_COORDINATOR = "HOROVOD_TPU_COORDINATOR"
 HOROVOD_TPU_NUM_PROCS = "HOROVOD_TPU_NUM_PROCS"
 HOROVOD_TPU_PROC_INDEX = "HOROVOD_TPU_PROC_INDEX"
-# alltoall SPMD schedule (ops/xla_ops.py: auto | oneshot | diag) and
-# the conv+bn fused-backward kernel selector (ops/pallas_conv_bn.py:
-# pallas | xla)
+# alltoall SPMD schedule (ops/xla_ops.py: auto | oneshot | diag)
 HOROVOD_TPU_ALLTOALL_SCHEDULE = "HOROVOD_TPU_ALLTOALL_SCHEDULE"
-HOROVOD_CONV_BN_BWD = "HOROVOD_CONV_BN_BWD"
 # fusion pack goes multithreaded above this bucket size (csrc
 # hvd_pack_mt); a third autotune dimension
 HOROVOD_TPU_PACK_MT_THRESHOLD = "HOROVOD_TPU_PACK_MT_THRESHOLD"

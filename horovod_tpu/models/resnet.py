@@ -4,8 +4,8 @@ The reference benchmarks data-parallel training of ResNet-50/101 with
 its synthetic benchmark scripts
 (``examples/pytorch/pytorch_synthetic_benchmark.py:24`` uses
 ``models.resnet50``; ``docs/benchmarks.rst:15-43`` records the
-tf_cnn_benchmarks numbers).  This is the flagship model for
-``bench.py``.
+tf_cnn_benchmarks numbers).  This is the model of the benchmark's
+control cell (``chipbench/adapters/cnn_train.py``).
 
 TPU-first choices:
 
@@ -21,123 +21,9 @@ from functools import partial
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
-
-
-class _FoldedBN(nn.Module):
-    """BatchNorm expressed as a per-channel affine fold ``(a, b)`` for
-    the pallas conv+BN kernels (ops/pallas_conv_bn.py): consumes the
-    per-channel ``(sum, sum_sq)`` the producing kernel accumulated in
-    VMEM instead of re-reading the activation, and returns the affine
-    the CONSUMER folds into its input read.  Parameter / batch_stats
-    layout matches ``nn.BatchNorm`` (scale, bias / mean, var)."""
-    use_running_average: bool = False
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-    scale_init: Callable = nn.initializers.ones
-
-    @nn.compact
-    def __call__(self, s1, s2, count):
-        from ..ops.pallas_conv_bn import bn_fold
-
-        c = s1.shape[-1]
-        scale = self.param("scale", self.scale_init, (c,), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (c,),
-                          jnp.float32)
-        ra_mean = self.variable("batch_stats", "mean",
-                                nn.initializers.zeros, None, (c,),
-                                jnp.float32)
-        ra_var = self.variable("batch_stats", "var",
-                               nn.initializers.ones, None, (c,),
-                               jnp.float32)
-        if self.use_running_average:
-            mean, var = ra_mean.value, ra_var.value
-            inv = scale * jax.lax.rsqrt(var + self.epsilon)
-            return inv, bias - mean * inv
-        mean = s1 / count
-        var = s2 / count - mean * mean
-        if not self.is_initializing():
-            m = self.momentum
-            ra_mean.value = m * ra_mean.value + (1 - m) * mean
-            ra_var.value = m * ra_var.value + (1 - m) * var
-        return bn_fold(s1, s2, count, scale, bias, self.epsilon)
-
-
-class FusedBottleneckBlock(nn.Module):
-    """Bottleneck block on the pallas fused conv+BN path.
-
-    Identical math to :class:`BottleneckBlock` (same conv/BN/ReLU
-    order), restructured so that for each 1x1 conv the BN stats ride
-    the kernel's epilogue and the upstream normalize+ReLU rides the
-    next kernel's prologue — see ops/pallas_conv_bn.py.  Only the 3x3
-    conv (1/6 of activation bytes) stays on the XLA conv path."""
-    filters: int
-    strides: Tuple[int, int]
-    dtype: Any = jnp.bfloat16
-    train: bool = True
-
-    @nn.compact
-    def __call__(self, x):
-        from ..ops.pallas_conv_bn import conv1x1_bn
-
-        B, H, W, Cin = x.shape
-        F = self.filters
-        kinit = nn.initializers.lecun_normal()
-        w1 = self.param("conv1", kinit, (Cin, F), jnp.float32)
-        w3 = self.param("conv3", kinit, (F, F * 4), jnp.float32)
-        bn = partial(_FoldedBN, use_running_average=not self.train)
-
-        flat = x.reshape(-1, Cin)
-        y1, s11, s12 = conv1x1_bn(flat, w1.astype(self.dtype))
-        a1, b1 = bn(name="bn1")(s11, s12, flat.shape[0])
-        x2 = jnn_relu_affine(y1, a1, b1, self.dtype).reshape(B, H, W, F)
-
-        y2 = nn.Conv(F, (3, 3), self.strides, use_bias=False,
-                     dtype=self.dtype, param_dtype=jnp.float32,
-                     name="conv2")(x2)
-        Bo, Ho, Wo, _ = y2.shape
-        y2f = y2.reshape(-1, F)
-        y2_32 = y2f.astype(jnp.float32)
-        s21 = jnp.sum(y2_32, axis=0)
-        s22 = jnp.sum(y2_32 * y2_32, axis=0)
-        a2, b2 = bn(name="bn2")(s21, s22, y2f.shape[0])
-
-        y3, s31, s32 = conv1x1_bn(y2f, w3.astype(self.dtype),
-                                  fold=(a2.reshape(1, -1),
-                                        b2.reshape(1, -1)))
-        a3, b3 = bn(name="bn3",
-                    scale_init=nn.initializers.zeros)(
-                        s31, s32, y3.shape[0])
-
-        if x.shape[-1] != F * 4 or self.strides != (1, 1):
-            wp = self.param("conv_proj", kinit, (Cin, F * 4),
-                            jnp.float32)
-            xs = x[:, ::self.strides[0], ::self.strides[1], :]
-            # strided projections route through the XLA matmul: the
-            # strided gather fuses into the dot's operand read there,
-            # while a pallas call would force the slice to materialize
-            # row-major first (measured ~1 ms/block on chip)
-            strided = self.strides != (1, 1)
-            yp, sp1, sp2 = conv1x1_bn(
-                xs.reshape(-1, Cin), wp.astype(self.dtype),
-                use_pallas=False if strided else None)
-            ap, bp = bn(name="bn_proj")(sp1, sp2, yp.shape[0])
-            res = yp.astype(jnp.float32) * ap + bp
-        else:
-            res = x.reshape(-1, F * 4).astype(jnp.float32)
-
-        out = jnp.maximum(y3.astype(jnp.float32) * a3 + b3 + res, 0.0)
-        return out.astype(self.dtype).reshape(Bo, Ho, Wo, F * 4)
-
-
-def jnn_relu_affine(y, a, b, dtype):
-    """relu(y*a + b) — one XLA elementwise fusion (the only BN
-    normalize on the fused path that must materialize, because its
-    consumer is the XLA 3x3 conv)."""
-    return jnp.maximum(y.astype(jnp.float32) * a + b, 0.0).astype(dtype)
 
 
 class BottleneckBlock(nn.Module):
@@ -167,17 +53,11 @@ class BottleneckBlock(nn.Module):
 
 
 class ResNet(nn.Module):
-    """ResNet v1.5.  ``stage_sizes``: blocks per stage.
-
-    ``fused=True`` routes the bottleneck blocks through the pallas
-    conv+BN kernels (same math; see :class:`FusedBottleneckBlock`) —
-    the single-chip perf path ``bench.py`` measures."""
+    """ResNet v1.5.  ``stage_sizes``: blocks per stage."""
     stage_sizes: Sequence[int]
     num_classes: int = 1000
     num_filters: int = 64
     dtype: Any = jnp.bfloat16
-    fused: bool = False
-    s2d_stem: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -187,40 +67,17 @@ class ResNet(nn.Module):
                        momentum=0.9, epsilon=1e-5, dtype=self.dtype,
                        param_dtype=jnp.float32, axis_name=None)
         x = x.astype(self.dtype)
-        if self.s2d_stem:
-            # space-to-depth stem (the MLPerf ResNet trick): 2x2
-            # blocks fold into channels so the stem conv contracts
-            # over 4x4x12 = 192 inputs instead of 7x7x3 = 147 with 3
-            # channels underfeeding the MXU lanes.  Same receptive
-            # field and output grid as 7x7/s2 (a 7x7/s2 tap window
-            # spans exactly 4 s2d rows/cols); the 4x4x12 kernel spans
-            # a slightly larger function class — the MLPerf-accepted
-            # equivalence.  Measured SLOWER on the bench chip (0.53x —
-            # docs/benchmarks.md round-4 notes); kept for parts where
-            # the stem is the bottleneck.
-            B, H, W, C = x.shape
-            x = x.reshape(B, H // 2, 2, W // 2, 2, C)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
-                B, H // 2, W // 2, 4 * C)
-            x = conv(self.num_filters, (4, 4), (1, 1),
-                     padding=[(2, 1), (2, 1)], name="conv_init")(x)
-        else:
-            x = conv(self.num_filters, (7, 7), (2, 2),
-                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+        x = conv(self.num_filters, (7, 7), (2, 2),
+                 padding=[(3, 3), (3, 3)], name="conv_init")(x)
         x = norm(name="bn_init")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                if self.fused:
-                    x = FusedBottleneckBlock(
-                        self.num_filters * 2 ** i, strides=strides,
-                        dtype=self.dtype, train=train)(x)
-                else:
-                    x = BottleneckBlock(
-                        self.num_filters * 2 ** i, strides=strides,
-                        conv=conv, norm=norm, act=nn.relu)(x)
+                x = BottleneckBlock(
+                    self.num_filters * 2 ** i, strides=strides,
+                    conv=conv, norm=norm, act=nn.relu)(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=jnp.float32,
                      param_dtype=jnp.float32, name="head")(x)

@@ -839,8 +839,8 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
     chunkable and sp-shard-aligned) with the final position weighted 0.
 
     The single definition of the fused objective, shared by
-    ``parallel.make_lm_train_step(fused_ce=True)``, the pipelined
-    step, and the MFU benchmark so they cannot drift apart.
+    ``parallel.make_lm_train_step(fused_ce=True)``, the pipelined step,
+    ``chip_smoke.py`` and the benchmark so they cannot drift apart.
 
     ``model`` is a ``TransformerLM`` (flax) or any plain
     ``apply(params, tokens, pre_logits=True) -> (x, emb)`` callable

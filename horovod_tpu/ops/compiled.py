@@ -521,8 +521,8 @@ def shared_program(key, builder):
 def program_cache_stats():
     """(hits, misses) of the process-wide compiled-program cache as
     integers — the in-process twin of the Prometheus counters, for
-    callers (tests, the continuous-serving smoke, serve_bench) that
-    assert zero steady-state recompiles without scraping."""
+    callers (tests, the continuous-serving smoke) that assert zero
+    steady-state recompiles without scraping."""
     hits, misses, _ = _cache_metrics()
     return int(hits.value()), int(misses.value())
 
@@ -3132,7 +3132,7 @@ class _CompiledTrainStep:
                 else:
                     # untagged (single-rank) steps skip the shared
                     # cache but still report cache traffic + compile
-                    # time to the registry (bench.py reads these)
+                    # time to the registry (chipbench/run.py reads these)
                     _cache_metrics()[1].inc()
                     self._prog = _TimedFirstCall(
                         build(ex), SCOPE_LOSS_AND_GRAD)

@@ -686,12 +686,12 @@ class MeshExecutor:
             return [empty.copy() for _ in self.local_positions], recv_local
         diag_max = [max(splits[r][(r + d) % R] for r in range(R))
                     for d in range(R)]
-        # schedule pick: the diagonal path wins once one-shot padding
-        # inflates wire bytes >1.25x (measured at R=8: 8% slower at
-        # ratio 1.0, 2.9x faster already at ratio 1.31 — the old >2x
-        # threshold left that win on the table; docs/benchmarks.md
-        # alltoall table).  HOROVOD_TPU_ALLTOALL_SCHEDULE=
-        # {auto,oneshot,diag} forces it for experiments.
+        # schedule pick: the diagonal path once one-shot padding
+        # inflates wire bytes >1.25x (a threshold chosen on the
+        # virtual CPU mesh at R=8, not measured on a chip;
+        # docs/benchmarks.md "Alltoall padding").
+        # HOROVOD_TPU_ALLTOALL_SCHEDULE={auto,oneshot,diag} forces it
+        # for experiments.
         from ..common import env as env_mod
         mode = env_mod.get_str(
             env_mod.HOROVOD_TPU_ALLTOALL_SCHEDULE, "auto")

@@ -186,7 +186,7 @@ class LMStagePrograms:
 
     def __init__(self, cfg, total_chunks, attention_fn=None):
         from ..models.transformer import (
-            DecoderBlock, RMSNorm, lm_loss, rope_angles)
+            DecoderBlock, RMSNorm, rope_angles)
         from jax import lax
 
         if cfg.n_layers % total_chunks != 0:
@@ -222,7 +222,17 @@ class LMStagePrograms:
             logits = jnp.einsum(
                 "bsm,vm->bsv", x, emb.astype(cfg.dtype),
                 preferred_element_type=jnp.float32)
-            return lm_loss(logits[:, :-1], tokens[:, 1:])
+            # lm_loss(logits[:, :-1], tokens[:, 1:]) with the target
+            # gathered BEFORE the last position is dropped: under a
+            # stage mesh with an sp axis the sequence arrives tiled
+            # over sp, S - 1 positions cannot be tiled evenly, and
+            # XLA's SPMD partitioner aborts the process on the
+            # gather's transpose (a scatter) over an uneven tiling
+            logp = jax.nn.log_softmax(logits)
+            ll = jnp.take_along_axis(
+                logp, jnp.roll(tokens, -1, axis=1)[..., None],
+                axis=-1)[..., 0]
+            return -jnp.mean(ll[:, :-1])
 
         # forward fns -----------------------------------------------------
         def fwd_first(emb, lc, tokens):

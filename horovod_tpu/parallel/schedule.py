@@ -317,7 +317,6 @@ def build_schedule(schedule, n_stages, n_micro, n_chunks=1):
 
 
 def bubble_fraction(schedule, n_stages, n_micro, n_chunks=1):
-    """Analytic idle fraction of the stage×tick grid for a schedule
-    (benchmarks + docs report this next to measured MFU)."""
+    """Analytic idle fraction of the stage×tick grid for a schedule."""
     return build_schedule(schedule, n_stages, n_micro,
                           n_chunks).bubble_fraction()

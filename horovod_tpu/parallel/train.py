@@ -52,9 +52,7 @@ def make_lm_train_step(mesh: Mesh, cfg: TransformerConfig,
 
     ``fused_ce=True`` fuses the logits projection into a
     sequence-chunked cross-entropy (``ce_chunks`` chunks) so the
-    (B, S, V) logits tensor never hits HBM — worth ~9% tok/s and
-    +1 batch step on the 436M single-chip headline
-    (docs/benchmarks.md).
+    (B, S, V) logits tensor never hits HBM.
 
     ``pipeline`` opts the step into the MPMD pipeline runtime
     (runtime.py; docs/parallelism.md): a :class:`~.runtime.

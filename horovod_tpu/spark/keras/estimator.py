@@ -180,7 +180,7 @@ class KerasEstimator(EstimatorParams):
                 {"class_name": opt_conf[0], "config": opt_conf[1]})
             opt = hvd_keras.DistributedOptimizer(opt)
             # eager train step: this frontend stages gradients through
-            # host numpy (STATUS.md: eager-first TF binding), which a
+            # host numpy (the TF binding is eager-first), which a
             # compiled tf.function train_step cannot do
             model.compile(optimizer=opt, loss=est.loss,
                           metrics=list(est.metrics), run_eagerly=True)

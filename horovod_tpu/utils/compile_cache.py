@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache for the entry
-scripts of this checkout (``chip_smoke.py``, ``bench.py``,
+scripts of this checkout (``chip_smoke.py``, ``chipbench/run.py``,
 ``benchmarks/*.py``)."""
 
 import os

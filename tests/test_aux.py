@@ -421,10 +421,45 @@ def test_examples_and_benchmarks_compile():
         assert compileall.compile_dir(
             os.path.join(root, target), quiet=2, force=True), \
             f"{target}/ contains a script that does not compile"
-    for script in ("bench.py", "__graft_entry__.py"):
+    for script in ("chip_smoke.py", "__graft_entry__.py"):
         assert compileall.compile_file(
             os.path.join(root, script), quiet=2, force=True), \
             f"{script} does not compile"
+
+
+@pytest.mark.parametrize("measured,rebased,tag,failed", [
+    # a deterministic count drifting in EITHER direction; and, under a
+    # baseline someone re-recorded low, the absolute floor still holds
+    pytest.param({"wire_int8_reduction_vs_f32": 4.2,
+                  "pair_f32_int4_cross_bytes": 900_000.0,
+                  "moe_alltoall_int8_ratio": 3.7},
+                 {"moe_alltoall_int8_ratio": 3.7}, "perf",
+                 ["wire_int8_reduction_vs_f32",
+                  "pair_f32_int4_cross_bytes",
+                  "moe_alltoall_int8_ratio"], id="eq_drift_and_floor"),
+    pytest.param({"moe_steady_recompiles": 1.0}, {}, "perf",
+                 ["moe_steady_recompiles"], id="max_bound_zero"),
+    pytest.param({"ckpt_async_anchored_frac": None}, {}, "perf",
+                 ["ckpt_async_anchored_frac"], id="not_printed"),
+    # faults never change what the wire moves: the same exact band
+    pytest.param({"pair_bf16_int4_inner_bytes": 16_777_216.0,
+                  "overlap_bitwise_parity": 0.0}, {}, "fault",
+                 ["pair_bf16_int4_inner_bytes",
+                  "overlap_bitwise_parity"], id="faulted_leg"),
+])
+def test_perf_gate_rules(measured, rebased, tag, failed):
+    """``tools/perf_gate._gate`` against the checked-in baseline: the
+    baseline passes against itself, and each rule catches its case."""
+    from tools import perf_gate
+
+    with open(perf_gate.BASELINE_PATH) as f:
+        baseline = json.load(f)["metrics"]
+    assert set(baseline) == set(perf_gate.METRICS)
+    assert perf_gate._gate(baseline, baseline, tag=tag) == []
+    measured = {k: v for k, v in {**baseline, **measured}.items()
+                if v is not None}
+    assert perf_gate._gate(measured, {**baseline, **rebased},
+                           tag=tag) == failed
 
 
 def test_data_service_remote_worker_and_shipped_fn():

@@ -2,7 +2,7 @@
 
 * The TPU compiler is installed here and compiles for a chip that is
   described, not attached: every Pallas kernel of the main path at
-  lm436m / ResNet-50 widths must be taken by it as a Mosaic
+  lm436m and the cells' widths must be taken by it as a Mosaic
   ``tpu_custom_call``.  Interpret mode cannot show this — the int4
   codec passed every interpret-mode test while the compiler refused
   it.  Nothing runs, so these say nothing about results or times.
@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
+sys.path.insert(0, REPO)
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +54,7 @@ def chip(chips):
     return chips[0]
 
 
-# lm436m attention operands (benchmarks/lm_mfu_bench.py HEADLINE, B5)
+# lm436m attention operands (chip_smoke.py HEADLINE, B5)
 _QKV = [((5, 2048, 8, 128), jnp.bfloat16)] * 3
 # the long-context windowed shape: S8192, W1024
 _QKV_LONG = [((1, 8192, 8, 128), jnp.bfloat16)] * 3
@@ -62,9 +62,6 @@ _QKV_LONG = [((1, 8192, 8, 128), jnp.bfloat16)] * 3
 # 2 x 8192 tokens, 32 heads of 128): full, Mistral's window, Trinity's
 _QKV_S8K = [((2, 8192, 32, 128), jnp.bfloat16)] * 3
 _N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
-# ResNet-50 b128 stage-1 1x1 conv as a matmul: (B*H*W, Cin) @ (Cin, Cout)
-_CONV = [((128 * 56 * 56, 64), jnp.bfloat16), ((64, 256), jnp.bfloat16),
-         ((1, 64), jnp.float32), ((1, 64), jnp.float32)]
 
 
 def _flash(**kw):
@@ -78,13 +75,6 @@ def _flash(**kw):
 def _grad_of_sum(fn, n_args):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
                     argnums=tuple(range(n_args)))
-
-
-def _conv(x, w, a, b):
-    from horovod_tpu.ops.pallas_conv_bn import conv1x1_bn
-
-    y, s1, s2 = conv1x1_bn(x, w, (a, b), interpret=False)
-    return y.astype(jnp.float32).sum() + s1.sum() + s2.sum()
 
 
 def _kernel_cases():
@@ -121,9 +111,6 @@ def _kernel_cases():
             lambda x: pk.fused_scale_cast(x, 0.5, jnp.bfloat16,
                                           interpret=False),
             [((_N,), jnp.float32)], 1),
-        "conv1x1_bn_fwd": (_conv, _CONV, 1),
-        "conv1x1_bn_bwd": (jax.grad(_conv, argnums=(0, 1, 2, 3)),
-                           _CONV, 2),
     }
 
 
@@ -131,10 +118,9 @@ def _lm436m_step_case():
     """The framework's own one-chip lm436m step program
     (ops/compiled.py), handed the described device and eval_shape
     shapes — the stacked single-rank program chip_smoke.py runs."""
-    import argparse
     import functools
 
-    import lm_mfu_bench as mod
+    import chip_smoke
     import optax
 
     from horovod_tpu.models import TransformerLM
@@ -142,9 +128,9 @@ def _lm436m_step_case():
     from horovod_tpu.ops.pallas_kernels import flash_attention
     from horovod_tpu.ops.xla_ops import MeshExecutor
 
-    cfg, _ = mod.build(argparse.Namespace(batch=mod.HEADLINE_BATCH))
-    tokens = ((mod.HEADLINE_BATCH, cfg.max_seq_len), jnp.int32)
-    _, loss_fn = mod.model_and_loss(cfg, functools.partial(
+    cfg, _ = chip_smoke.build(chip_smoke.HEADLINE_BATCH)
+    tokens = ((chip_smoke.HEADLINE_BATCH, cfg.max_seq_len), jnp.int32)
+    _, loss_fn = chip_smoke.model_and_loss(cfg, functools.partial(
         flash_attention, interpret=False))
     optimizer = optax.adamw(1e-3)
     step = make_compiled_train_step(loss_fn, optimizer)
@@ -166,7 +152,7 @@ def _lm436m_step_case():
     "flash_fwd", "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
     "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
-    "fused_scale_cast", "conv1x1_bn_fwd", "conv1x1_bn_bwd",
+    "fused_scale_cast",
     pytest.param("lm436m_step", marks=pytest.mark.slow)])
 def test_chip_compiler_takes(chip, name):
     one_chip = SingleDeviceSharding(chip)
@@ -399,11 +385,11 @@ def test_compile_cache_placement(monkeypatch, from_env):
 
 
 def test_unknown_device_kind_has_no_peak():
-    import lm_mfu_bench as mod
+    from chipbench import flops
 
-    assert mod.published_peak_tflops("TPU v5 lite") == 197.0
-    with pytest.raises(ValueError, match="no published peak"):
-        mod.published_peak_tflops(jax.devices()[0].device_kind)
+    assert flops.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        flops.peaks(jax.devices()[0].device_kind)
 
 
 def test_importing_the_launcher_starts_no_backend():

@@ -699,17 +699,20 @@ def test_driver_suspend_resume_real_job(tmp_path):
             time.sleep(0.2)
         assert "batch 2" in log.read_text(), log.read_text()
 
+        # (the driver's monitor drops a drained worker from _procs as
+        # soon as it sees the exit: hold the processes themselves)
+        procs = dict(driver._procs)
+        assert len(procs) == 2, procs
         driver.suspend()
         assert driver.suspended
         # every worker must drain at its next commit and exit CLEANLY
         deadline = time.monotonic() + 90
         while time.monotonic() < deadline:
-            codes = {k: p.poll() for k, p in driver._procs.items()}
-            if codes and all(c is not None for c in codes.values()):
+            if all(p.poll() is not None for p in procs.values()):
                 break
             time.sleep(0.2)
-        codes = {k: p.poll() for k, p in driver._procs.items()}
-        assert codes and all(c == 0 for c in codes.values()), (
+        codes = {k: p.poll() for k, p in procs.items()}
+        assert all(c == 0 for c in codes.values()), (
             f"workers did not self-abort cleanly: {codes}")
         batches_at_suspend = log.read_text().count("batch")
         # suspension is a PAUSE: nothing runs while suspended

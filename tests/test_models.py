@@ -37,15 +37,21 @@ def test_resnet_train_mode_updates_batch_stats():
 
 
 def test_resnet50_param_count():
-    # ~25.6M params, matching torchvision resnet50 used by the
-    # reference benchmark (examples/pytorch/pytorch_synthetic_benchmark.py).
+    """torchvision's resnet50 (the reference benchmark's model,
+    examples/pytorch/pytorch_synthetic_benchmark.py) leaf for leaf:
+    the tree the benchmark's control cell trains."""
     model = ResNet50(num_classes=1000)
     x = jnp.zeros((1, 224, 224, 3))
     variables = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), x, train=False))
-    n = sum(int(np.prod(p.shape))
-            for p in jax.tree_util.tree_leaves(variables["params"]))
-    assert 25.4e6 < n < 25.8e6, n
+    params = jax.tree_util.tree_leaves_with_path(variables["params"])
+    assert sum(int(np.prod(p.shape)) for _, p in params) == 25_557_032
+    names = [path[-1].key for path, _ in params]
+    # 53 convolutions and the head; a scale and a bias per BatchNorm
+    assert len(params) == 161
+    assert names.count("kernel") == 54
+    assert names.count("scale") == 53 and names.count("bias") == 54
+    assert len(jax.tree_util.tree_leaves(variables["batch_stats"])) == 106
 
 
 def test_vgg16_param_count_and_forward():
@@ -419,62 +425,6 @@ def test_kv_cache_decode_sampling_reproducible():
         gen(params, prompt)
     with pytest.raises(ValueError, match="max_seq_len"):
         make_generate_fn(model, max_new_tokens=20)(params, prompt)
-
-
-def test_s2d_stem_matches_7x7_conv():
-    """The space-to-depth stem is function-space equivalent to the
-    7x7/s2 conv: remapping a 7x7x3 kernel into the 4x4x12 layout
-    (w4[KY,KX,(dy,dx,c)] = w7[2KY+dy-1, 2KX+dx-1, c], zero where out
-    of range) reproduces the original conv output exactly."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    rng = np.random.RandomState(0)
-    x = rng.randn(2, 32, 32, 3).astype(np.float32)
-    w7 = rng.randn(7, 7, 3, 8).astype(np.float32) * 0.1
-
-    ref = lax.conv_general_dilated(
-        jnp.asarray(x), jnp.asarray(w7), (2, 2),
-        [(3, 3), (3, 3)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-    # remap weights into the s2d layout
-    w4 = np.zeros((4, 4, 12, 8), np.float32)
-    for KY in range(4):
-        for KX in range(4):
-            for dy in range(2):
-                for dx in range(2):
-                    ky, kx = 2 * KY + dy - 1, 2 * KX + dx - 1
-                    if 0 <= ky < 7 and 0 <= kx < 7:
-                        w4[KY, KX, dy * 6 + dx * 3: dy * 6 + dx * 3 + 3] \
-                            = w7[ky, kx]
-    B, H, W, C = x.shape
-    xs = x.reshape(B, H // 2, 2, W // 2, 2, C) \
-          .transpose(0, 1, 3, 2, 4, 5).reshape(B, H // 2, W // 2, 12)
-    got = lax.conv_general_dilated(
-        jnp.asarray(xs), jnp.asarray(w4), (1, 1),
-        [(2, 1), (2, 1)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_resnet_s2d_stem_trains():
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.models.resnet import ResNet
-
-    model = ResNet(stage_sizes=[1, 1], num_classes=5, num_filters=8,
-                   s2d_stem=True)
-    rng = jax.random.PRNGKey(0)
-    x = jax.random.normal(rng, (2, 32, 32, 3), jnp.float32)
-    v = model.init(rng, x, train=False)
-    out, mut = model.apply(v, x, train=True, mutable=["batch_stats"])
-    assert out.shape == (2, 5)
-    # stem output grid matches the 7x7/s2 stem's
-    assert v["params"]["conv_init"]["kernel"].shape == (4, 4, 12, 8)
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])

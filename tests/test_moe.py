@@ -183,6 +183,31 @@ def test_transformer_moe_capacity_branch_runs_and_differs():
     assert np.all(np.isfinite(np.asarray(routed)))
 
 
+def test_capacity_routed_step_compiles_once():
+    """Fixed-capacity dispatch keeps every shape static: batches that
+    route (and drop) differently run the one program the first step
+    compiled — the count ``tools/perf_gate.py`` reads off lm_bench as
+    ``moe_steady_recompiles``."""
+    from horovod_tpu.models.transformer import TransformerConfig
+    from horovod_tpu.parallel import (
+        MeshSpec, build_mesh, make_lm_train_step)
+
+    mesh = build_mesh(MeshSpec(dp=1), jax.devices()[:1])
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+        max_seq_len=16, num_experts=4, expert_top_k=2,
+        moe_capacity_factor=1.25)
+    init, _, jit_step, tok_sharding = make_lm_train_step(mesh, cfg)
+    rng = np.random.default_rng(3)
+    batches = [jnp.asarray(rng.integers(0, 64, (2, 16)))
+               for _ in range(4)]
+    step, state = jit_step(init(jax.random.PRNGKey(0), batches[0]))
+    for tokens in batches:
+        state, loss = step(state, jax.device_put(tokens, tok_sharding))
+    assert np.isfinite(float(loss))
+    assert step._cache_size() == 1
+
+
 # ---------------------------------------------------------------------------
 # autotune: the tenth dimension
 

@@ -1136,23 +1136,23 @@ def test_wire_pair_matrix(two_host_topology, iw, ow, algo, path):
         assert eng.algo_runs.get(algo, 0) > runs0.get(algo, 0)
 
 
+def _hop_bytes(hop):
+    """Bytes the per-hop accounting has attributed to ``hop`` so far."""
+    from horovod_tpu import telemetry
+
+    fam = telemetry.metrics().get(telemetry.WIRE_HOP_BYTES_FAMILY, {})
+    return sum(s.get("value", 0.0) for s in fam.get("samples", [])
+               if s.get("labels", {}).get("hop") == hop)
+
+
 def test_per_hop_cross_bytes_split(two_host_topology):
     """The hop accounting must show the pair's whole point: with pair
     (bf16, int4) on a hierarchical reduction, the inner hop moves
     2x the payload at bf16 width while the cross hop moves only the
     quantized 1/local_size shard — and the cross family's int4 bytes
     undercut the same reduction's int8 bytes."""
-    from horovod_tpu import telemetry
-    eng = two_host_topology
-
-    def hop(h):
-        fam = telemetry.metrics().get(
-            telemetry.WIRE_HOP_BYTES_FAMILY, {})
-        return sum(s.get("value", 0.0) for s in fam.get("samples", [])
-                   if s.get("labels", {}).get("hop") == h)
-
     def run_one(wire, name):
-        i0, c0 = hop("inner"), hop("cross")
+        i0, c0 = _hop_bytes("inner"), _hop_bytes("cross")
 
         def fn():
             x = np.ones(1 << 14, np.float32)
@@ -1162,7 +1162,8 @@ def test_per_hop_cross_bytes_split(two_host_topology):
             return True
 
         assert all(run_ranks(fn))
-        return hop("inner") - i0, hop("cross") - c0
+        return (_hop_bytes("inner") - i0,
+                _hop_bytes("cross") - c0)
 
     n = 1 << 14
     di8, dc8 = run_one("int8", "m.hop.i8")
@@ -1174,6 +1175,29 @@ def test_per_hop_cross_bytes_split(two_host_topology):
     assert 0 < dc4 < dc8, (dc4, dc8)
     assert dc8 <= n * 2 + 256, dc8       # int16 partials + scales
     assert dc4 <= n * 1 + 256, dc4       # int8 partials + scales
+
+
+@pytest.mark.parametrize("inner,outer,hop,per_call", [
+    ("f32", "int8", "cross", 2_105_344),   # int16 partials + scales
+    ("f32", "int4", "cross", 1_056_768),   # int8 partials + scales
+    ("bf16", "int4", "inner", 8_388_608),  # 2 passes at bf16 width
+], ids=["f32:int8-cross", "f32:int4-cross", "bf16:int4-inner"])
+def test_per_hop_bytes_of_an_8mib_call(two_host_topology, inner, outer,
+                                       hop, per_call):
+    """What each hop of the 2 x 2 decomposition moves for one 8 MiB
+    call, to the byte: the counts ``tools/perf_gate.py`` holds the
+    collective_bench leg to, read from the library directly."""
+    before = _hop_bytes(hop)
+
+    def fn():
+        x = np.ones(1 << 21, np.float32)
+        hvd.allreduce(x, op=hvd.Sum, name=f"m.hop8.{inner}.{outer}",
+                      algorithm="torus", wire_dtype=outer,
+                      wire_inner=inner)
+        return True
+
+    assert all(run_ranks(fn))
+    assert _hop_bytes(hop) - before == per_call
 
 
 def test_wire_inner_mismatch_fails_loudly(live_engine):
@@ -1596,11 +1620,12 @@ def test_alltoall_wire_matrix(live_engine, path, wire, hint):
 
 
 @pytest.mark.parametrize("path", ["engine", "compiled"])
-@pytest.mark.parametrize("wire,floor", [("int8", 3.9), ("int4", 7.5)])
-def test_alltoall_quantized_accounting(live_engine, path, wire, floor):
+@pytest.mark.parametrize("wire,ratio", [("int8", 3.969), ("int4", 7.877)])
+def test_alltoall_quantized_accounting(live_engine, path, wire, ratio):
     """The alltoall byte families must show the codec's true wire
-    reduction — int8 ~3.97x, int4 ~7.88x — on both dispatch paths
-    (the exchange ships codes + scales, never dequantized f32)."""
+    reduction — 1024 bytes of f32 per 256-element block over 256 (int8)
+    or 128 (int4) code bytes and a 2-byte scale — on both dispatch
+    paths (the exchange ships codes + scales, never dequantized f32)."""
     from horovod_tpu import telemetry
     l0 = telemetry.counter_total(telemetry.ALLTOALL_LOGICAL_BYTES_FAMILY)
     a0 = telemetry.counter_total(telemetry.ALLTOALL_WIRE_BYTES_FAMILY)
@@ -1619,7 +1644,7 @@ def test_alltoall_quantized_accounting(live_engine, path, wire, floor):
         telemetry.ALLTOALL_LOGICAL_BYTES_FAMILY) - l0
     da = telemetry.counter_total(
         telemetry.ALLTOALL_WIRE_BYTES_FAMILY) - a0
-    assert dl > 0 and dl / da > floor, (dl, da, dl / da)
+    assert dl > 0 and round(dl / da, 3) == ratio, (dl, da, dl / da)
 
 
 def test_compiled_alltoall_single_program(live_engine):

@@ -9,7 +9,8 @@ scale).  The TPU equivalents that XLA does NOT already fuse well:
   two XLA ops with an HBM round-trip between them.
 * :func:`flash_attention` — blockwise causal attention that never
   materializes the (S, S) score matrix: streaming softmax in VMEM,
-  O(S) HBM traffic.  Used by the single-chip fast path; the
+  O(S) HBM traffic; two kernels, the forward and ONE backward.  Used
+  by the single-chip fast path; the
   sequence-parallel path composes the same math with ``ppermute``
   (parallel/ring_attention.py).
 * :func:`quantize_blockwise` / :func:`dequantize_blockwise` — the
@@ -306,61 +307,110 @@ fake_quantize_blockwise_int4.defvjp(_fq4_fwd, _fq4_bwd)
 # ---------------------------------------------------------------------------
 # flash attention (causal, forward)
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
-                  seq_len, scale, window=None):
-    # q_ref: (1, block_q, D); k_ref/v_ref: (1, S, D).  Matmuls run in
-    # the INPUT dtype with f32 accumulation: bf16 activations hit the
-    # MXU's fast path (f32 operands would halve+ its rate) while f32
-    # inputs keep exact reference numerics.  All softmax math is f32;
-    # the 1/sqrt(D) scale is applied to the f32 scores, not to q, so
-    # no precision is lost to a low-precision pre-multiply.
-    block_q = q_ref.shape[1]
-    D = q_ref.shape[2]
-    qi = pl.program_id(1)
-    q = q_ref[0]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+# what a masked score is set to: BELOW the _NEG_INF a query's running
+# maximum starts from, so exp(masked - maximum) is 0 in float32 even for
+# a query that has met no key yet (a window that ends inside a key
+# block leaves some queries' first block wholly masked), and `l` needs
+# no second mask after the exp
+_MASKED = 2 * _NEG_INF
 
-    def body(kb, carry):
-        o, m, l = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(                      # (bq, bk) f32
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * np.float32(scale)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = q_pos >= k_pos
-        if window is not None:
-            mask = mask & (q_pos - k_pos < window)
-        s = jnp.where(mask, s, np.float32(_NEG_INF))
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, np.float32(0.0))
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        o_new = o * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
 
-    # causal: key blocks covering positions up to the LAST row of this
-    # query block (block_q may exceed block_k); a sliding window also
-    # skips blocks entirely BEFORE the first row's window start
+def _band(qi, block_q, block_k, window):
+    """The key blocks ``[first_kb, num_kb)`` query block ``qi`` meets, in
+    both kernels.  Causal: up to the block that holds the LAST row's
+    position (block_q may exceed block_k); a sliding window also skips
+    blocks entirely BEFORE the first row's window start."""
     num_kb = ((qi + 1) * block_q - 1) // block_k + 1
     first_kb = 0
     if window is not None:
         # qi is a traced grid index — stay in jnp
         first_kb = jnp.maximum(0, qi * block_q - window + 1) // block_k
-    o0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(first_kb, num_kb, body, (o0, m0, l0))
+    return first_kb, num_kb
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
+                  scale, window=None):
+    """The forward for one query block: an online softmax over the key
+    blocks inside the band.
+
+    q_ref: (1, block_q, D); k_ref / v_ref: (1, S, D).  Matmuls run in
+    the INPUT dtype with f32 accumulation: bf16 activations hit the
+    MXU's fast path (f32 operands would halve+ its rate) while f32
+    inputs keep exact reference numerics.  All softmax math is f32; the
+    1/sqrt(D) scale is applied to the f32 scores, not to q, so no
+    precision is lost to a low-precision pre-multiply.
+
+    The scores are held TRANSPOSED, (block_k, block_q), as the backward
+    holds them: a query's maximum and sum run down the key axis
+    (elementwise across vregs, then one fold of 8 sublanes) and `m`,
+    `l`, `alpha` are (1, block_q) rows of a few vregs that broadcast
+    along sublanes as they lie; held (block_q, 1) each was 64 sparse
+    vregs, and dividing by `l`, its log and `lse`'s relayout cost a
+    grid step about as much as a block pair.  The accumulator is o^T,
+    (D, block_q), transposed ONCE at the store; PV takes v's block as
+    the transposed operand.
+
+    Two key blocks an iteration, BOTH QK^T products before either
+    softmax: inside one pair QK^T -> maximum -> exp -> PV is a chain
+    (every key's score is needed before the first exp), and the
+    compiler overlaps nothing across loop iterations, so the second
+    pair's product is what the MXU does while the VPU works through the
+    first pair's softmax.  The sums stay in key-block order."""
+    block_q = q_ref.shape[1]
+    D = q_ref.shape[2]
+    qi = pl.program_id(1)
+    q = q_ref[0]
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 1)
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+
+    def scores(kb):
+        cols = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        st = jax.lax.dot_general(                     # (bk, bq) f32
+            k_ref[0, cols, :], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * np.float32(scale)
+        return st, v_ref[0, cols, :]
+
+    def accumulate(kb, st, v, carry):
+        ot, m, l = carry
+        k_pos = kb * block_k + k_iota
+        mask = q_pos >= k_pos
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
+        st = jnp.where(mask, st, np.float32(_MASKED))
+        m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        pt = jnp.exp(st - m_new)
+        l_new = l * alpha + jnp.sum(pt, axis=0, keepdims=True)
+        ot_new = ot * alpha + jax.lax.dot_general(    # (D, bq) f32
+            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return ot_new, m_new, l_new
+
+    first_kb, num_kb = _band(qi, block_q, block_k, window)
+
+    def two(i, carry):
+        kb = first_kb + 2 * i
+        st_a, v_a = scores(kb)
+        st_b, v_b = scores(kb + 1)
+        carry = accumulate(kb, st_a, v_a, carry)
+        return accumulate(kb + 1, st_b, v_b, carry)
+
+    def one(kb, carry):
+        return accumulate(kb, *scores(kb), carry)
+
+    twos = (num_kb - first_kb) // 2
+    carry = (jnp.zeros((D, block_q), jnp.float32),
+             jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32))
+    carry = jax.lax.fori_loop(0, twos, two, carry)
+    # a band of an odd number of blocks: the last one alone
+    ot, m, l = jax.lax.fori_loop(first_kb + 2 * twos, num_kb, one, carry)
     l = jnp.maximum(l, np.float32(1e-30))
-    o_ref[0] = (o / l[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (ot / l).T.astype(o_ref.dtype)
     # logsumexp per row, consumed by the backward kernel; stored as
     # (BH, 1, S) so TPU block shapes satisfy the (8, 128) tiling rule
-    lse_ref[0, 0] = m + jnp.log(l)
+    lse_ref[0] = m + jnp.log(l)
 
 
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
@@ -420,12 +470,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # the forward's band: key blocks up to this query block's LAST row,
-    # from the first row's window start
-    num_kb = ((qi + 1) * block_q - 1) // block_k + 1
-    first_kb = 0
-    if window is not None:
-        first_kb = jnp.maximum(0, qi * block_q - window + 1) // block_k
+    first_kb, num_kb = _band(qi, block_q, block_k, window)
     dq = jax.lax.fori_loop(
         first_kb, num_kb, body, jnp.zeros((block_q, q_ref.shape[2]),
                                           jnp.float32))
@@ -468,8 +513,8 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, window,
     scale = 1.0 / np.sqrt(D)
     out, lse = _named_kernel(
         "flash_fwd",
-        functools.partial(_flash_kernel, block_k=block_k, seq_len=S,
-                          scale=scale, window=window),
+        functools.partial(_flash_kernel, block_k=block_k, scale=scale,
+                          window=window),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, 1, S), jnp.float32)),
         grid=(BH, S // block_q),
@@ -578,7 +623,14 @@ def flash_attention(q, k, v, *, block_q=512, block_k=512,
     """Causal attention (B, S, H, D) -> (B, S, H, D), flash-style.
 
     Memory: O(block_q * S) VMEM per program instead of O(S^2) HBM —
-    the long-context single-chip workhorse.  Differentiable: the
+    the long-context single-chip workhorse.  The forward (scope and
+    name ``flash_fwd``) runs one query block a grid step with k and v
+    of the (batch, head) whole in VMEM: an online softmax over the key
+    blocks inside the band, two an iteration so that one pair's QK^T
+    runs beside the other's softmax, the scores held transposed,
+    (block_k, block_q), the running maximum and sum as (1, block_q)
+    rows and the accumulator as o^T, all float32; it returns the
+    output and each row's logsumexp, (BH, 1, S).  Differentiable: the
     backward pass is ONE pallas kernel (scope and name ``flash_dkv``,
     kept from the dk/dv kernel it grew out of; it yields dq too) that
     recomputes each block pair's probabilities once from the saved

@@ -187,6 +187,70 @@ def test_flash_backward_one_kernel_matches_dense(dtype, B, H, bq, bk,
                                    err_msg=f"d{name}")
 
 
+# (dtype, BH, S, D, block_q, block_k, window): the forward kernel alone
+# (`_flash_fwd_call`) against the dense reference's output and a float32
+# logsumexp.  The band's key blocks go through the loop two an
+# iteration and what is left over one at a time, so bands of one, two
+# and three blocks and longer ones are here; a window of 5 / 17 / 63 ends
+# inside a block, and with several query blocks to a key block
+# (block_q 8, block_k 32) the later query blocks' first key block is
+# WHOLLY masked for some rows: there `p` must be 0 and `l` unchanged
+# with no second mask after the `exp`.
+_FWD_CASES = [
+    (jnp.float32, 1, 64, 16, 16, 16, None),
+    (jnp.bfloat16, 1, 64, 16, 16, 16, None),
+    (jnp.float32, 1, 64, 16, 64, 64, None),     # a band one block long
+    (jnp.float32, 1, 32, 16, 16, 16, None),     # bands of one and two
+    (jnp.float32, 1, 48, 16, 16, 16, None),     # ... and three
+    (jnp.float32, 1, 64, 16, 16, 16, 5),
+    (jnp.float32, 1, 64, 16, 16, 16, 17),
+    (jnp.float32, 1, 64, 16, 16, 16, 63),
+    (jnp.float32, 1, 64, 8, 8, 32, 5),          # rows wholly masked in
+    (jnp.float32, 1, 64, 8, 8, 32, 17),         # the band's first block
+    (jnp.float32, 1, 64, 8, 8, 32, 63),
+    (jnp.bfloat16, 3, 64, 8, 8, 32, 17),
+    (jnp.float32, 1, 64, 8, 32, 8, None),       # block_q > block_k
+    (jnp.float32, 2, 64, 8, 32, 8, 17),
+    (jnp.bfloat16, 4, 64, 16, 32, 8, 63),
+    (jnp.float32, 1, 64, 8, 8, 32, None),       # block_q < block_k
+    (jnp.float32, 6, 96, 16, 16, 16, 40),       # B*H > 1, odd band
+    (jnp.float32, 2, 256, 128, 128, 128, None),     # the cells' D
+    (jnp.bfloat16, 2, 256, 128, 128, 128, 130),
+]
+
+
+@pytest.mark.parametrize(
+    "dtype,BH,S,D,bq,bk,window", _FWD_CASES,
+    ids=[f"{np.dtype(c[0]).name}-BH{c[1]}-S{c[2]}D{c[3]}-{c[4]}x{c[5]}"
+         f"-W{c[6]}" for c in _FWD_CASES])
+def test_flash_forward_matches_dense(dtype, BH, S, D, bq, bk, window):
+    from horovod_tpu.ops.pallas_kernels import _flash_fwd_call
+
+    keys = jax.random.split(jax.random.PRNGKey(S + D + bq), 3)
+    q, k, v = (jax.random.normal(kk, (BH, S, D), jnp.float32)
+               .astype(dtype) for kk in keys)
+    out, lse = _flash_fwd_call(q, k, v, bq, bk, window, True)
+    assert out.shape == (BH, S, D) and out.dtype == dtype
+    assert lse.shape == (BH, 1, S) and lse.dtype == jnp.float32
+    # the references in float32 from the SAME (rounded) inputs
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    want = dense_causal_attention(q32[:, :, None], k32[:, :, None],
+                                  v32[:, :, None], window=window)[:, :, 0]
+    scores = jnp.einsum("bqd,bkd->bqk", q32, k32) / np.sqrt(D)
+    pos = jnp.arange(S)
+    mask = pos[:, None] >= pos[None, :]
+    if window is not None:
+        mask = mask & (pos[:, None] - pos[None, :] < window)
+    want_lse = jax.scipy.special.logsumexp(
+        jnp.where(mask, scores, -jnp.inf), axis=-1)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse[:, 0]), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # block-scaled int8 wire codec (quantized collectives)
 

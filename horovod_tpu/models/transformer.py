@@ -28,6 +28,10 @@ TPU-first choices:
   (``parallel/moe.routed_experts_apply``).  Depth still costs no
   compile time: the leading dense layers are one scan, the others one
   scan over the periods of their pattern of kinds.
+* A looped model (``total_ut_steps`` > 1, the key of Ouro's published
+  ``config.json``): that stack applied several times over the same
+  weights, an exit after every pass, and in ``make_fused_lm_loss`` the
+  expected loss under the gate's distribution over the exits.
 """
 
 from dataclasses import dataclass, field
@@ -117,6 +121,16 @@ class TransformerConfig:
     # them, and what the absent experts would add is left out
     num_experts_held: Optional[int] = None   # None => all
     first_expert_held: int = 0
+    # -- a looped model (a model with ``layer_types``): the whole stack
+    # of layers runs ``total_ut_steps`` times over the SAME weights, the
+    # final norm after every pass and its output carried into the next;
+    # a gate (``early_exit_gate``, d_model -> 1) on every pass's state
+    # gives a per-token distribution over the passes to exit at, and
+    # ``make_fused_lm_loss`` the expected loss under it less
+    # ``exit_entropy_coeff`` times its entropy (arXiv:2510.25741
+    # section 3).  The logits are the last pass's
+    total_ut_steps: int = 1
+    exit_entropy_coeff: float = 0.05
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -568,6 +582,112 @@ def _shortest_period(kinds):
             return kinds[:p], n // p
 
 
+def _stack(module, block, name, length, remat=False, hook=True,
+           **scan_axes):
+    """``block`` (under the remat policy with ``remat``) scanned
+    ``length`` times over a leading axis of its parameters inside
+    ``module``, each iteration's slice passing the step's gradient hook
+    where one is tracing and ``hook`` says this is the slice's only
+    use."""
+    if hook and grad_hook.reduces_in_backward() \
+            and not module.is_initializing():
+        # a data-parallel compiled step is tracing this call: each
+        # layer's parameter slice passes the hook inside the scan
+        # body (and inside the remat wrapper), so the layer's
+        # gradient is all-reduced in the backward loop's body,
+        # beside the backward's own work, and not after the loop.
+        # Everywhere else the module tree is the plain one
+        covered = module.path + (name,)
+        block = nn.map_variables(
+            block, "params",
+            trans_in_fn=lambda layer: grad_hook.reduce_in_backward(
+                layer, covered))
+    if remat:
+        block = _with_remat(block, module.cfg)
+    return nn.scan(
+        block,
+        variable_axes={"params": 0, **scan_axes},
+        split_rngs={"params": True},
+        in_axes=nn.broadcast,
+        length=length,
+        metadata_params={nn.PARTITION_NAME: "layers"})
+
+
+def _layered(module, x, angles):
+    """The stack of a model whose layers differ, built inside
+    ``module`` (which has ``cfg`` and ``attention_fn``): the leading
+    dense layers one scan, the others one scan over the periods of
+    their pattern of kinds, so depth costs no compile time.  Returns
+    ``(x, what the routed layers counted)``."""
+    cfg = module.cfg
+    kinds, lead = cfg.layer_types, cfg.num_dense_layers
+    if len(kinds) != cfg.n_layers or set(kinds) - set(LAYER_TYPES):
+        raise ValueError(
+            f"layer_types must name n_layers={cfg.n_layers} kinds "
+            f"of {LAYER_TYPES}, got {kinds}")
+    if "sliding_attention" in kinds and not cfg.sliding_window:
+        raise ValueError("sliding_attention layers need "
+                         "sliding_window")
+    if cfg.num_experts and (cfg.score_func != "sigmoid"
+                            or not cfg.route_norm):
+        raise ValueError(
+            "the routed layer scores with a sigmoid and renormalises "
+            f"the selected: score_func={cfg.score_func!r}, "
+            f"route_norm={cfg.route_norm}")
+    groups = [("dense_layers", kinds[:lead], False),
+              ("periods", kinds[lead:],
+               bool(cfg.num_experts))]
+    counts = 0
+    for name, group, routed in groups:
+        if not group:
+            continue
+        period, repeats = _shortest_period(group)
+        # a layer that runs total_ut_steps times has its gradient
+        # whole only when the backward of the FIRST pass leaves it:
+        # the step reduces it after the backward, once
+        # (docs/parallelism.md)
+        stack = _stack(module, LayerPeriod, name, repeats,
+                       hook=cfg.total_ut_steps == 1,
+                       **{ROUTER_STATE: 0})(
+            cfg, module.attention_fn, period, routed, repeats, name=name)
+        x, c = stack(x, angles)
+        counts = counts + jnp.sum(c, axis=0)
+    return x, counts
+
+
+class LoopPass(nn.Module):
+    """One pass of a looped model: the whole stack of layers, then the
+    final norm, whose output is both the next pass's input and this
+    pass's exit."""
+    cfg: TransformerConfig
+    attention_fn: Callable
+
+    @nn.compact
+    def __call__(self, x, angles):
+        x, _ = _layered(self, x, angles)
+        return RMSNorm(self.cfg.dtype, self.cfg.rms_norm_eps,
+                       name="ln_final")(x)
+
+
+#: what a looped model sums on the device, a step call
+#: (``ops/device_sums.py``): over the tokens the loss weighs, the
+#: weights and the mass of the exit distribution on each pass
+LOOP_TOKENS_SUM = "horovod_loop_tokens_total"
+#: what a step call of a looped model counts on the host: its layers
+#: times its passes
+LOOP_LAYER_APPLICATIONS = "horovod_loop_layer_applications_total"
+
+
+def loop_exit_mass_sum(step):
+    """The name of the sum of pass ``step``'s exit mass (from 1)."""
+    return f"horovod_loop_exit_mass_pass_{step}_total"
+
+
+def loop_device_sums(total_ut_steps):
+    return (LOOP_TOKENS_SUM,) + tuple(
+        loop_exit_mass_sum(t + 1) for t in range(total_ut_steps))
+
+
 class TransformerLM(nn.Module):
     """Token ids (B, S) -> logits (B, S, V)."""
     cfg: TransformerConfig
@@ -579,77 +699,31 @@ class TransformerLM(nn.Module):
         """The names of the sums this model makes on the device inside
         a compiled step (``ops/device_sums.py``)."""
         cfg = self.cfg
+        if cfg.total_ut_steps > 1:
+            return loop_device_sums(cfg.total_ut_steps)
         routed = cfg.layer_types is not None and cfg.num_experts \
             and cfg.num_dense_layers < cfg.n_layers
         return MOE_DEVICE_SUMS if routed else ()
 
-    def _stack(self, block, name, length, remat=False, **scan_axes):
-        """``block`` (under the remat policy with ``remat``) scanned
-        ``length`` times over a leading axis of its parameters, each
-        iteration's slice passing the step's gradient hook where one
-        is tracing."""
-        if grad_hook.reduces_in_backward() and not self.is_initializing():
-            # a data-parallel compiled step is tracing this call: each
-            # layer's parameter slice passes the hook inside the scan
-            # body (and inside the remat wrapper), so the layer's
-            # gradient is all-reduced in the backward loop's body,
-            # beside the backward's own work, and not after the loop.
-            # Everywhere else the module tree is the plain one
-            covered = self.path + (name,)
-            block = nn.map_variables(
-                block, "params",
-                trans_in_fn=lambda layer: grad_hook.reduce_in_backward(
-                    layer, covered))
-        if remat:
-            block = _with_remat(block, self.cfg)
-        return nn.scan(
-            block,
-            variable_axes={"params": 0, **scan_axes},
-            split_rngs={"params": True},
-            in_axes=nn.broadcast,
-            length=length,
-            metadata_params={nn.PARTITION_NAME: "layers"})
-
-    def _layered(self, x, angles, decode):
-        """The stack of a model whose layers differ: the leading dense
-        layers one scan, the others one scan over the periods of their
-        pattern of kinds, so depth costs no compile time."""
+    def _loop(self, x, angles):
+        """The stack of a looped model: ONE ``LoopPass`` applied
+        ``total_ut_steps`` times, so every pass reads the same
+        parameters and their gradient is the sum over the passes.
+        Returns the normed state after every pass, (R, B, S, M).  (A
+        scan over the passes with the parameters broadcast computes the
+        same in 4% more time on the chip, PERF.md section 6.)"""
         cfg = self.cfg
-        kinds, lead = cfg.layer_types, cfg.num_dense_layers
-        if decode:
+        if cfg.num_experts:
             raise ValueError(
-                "a model with layer_types has no KV-cache path: two "
-                "kinds of layer in one cache (serving/kvcache.py)")
-        if len(kinds) != cfg.n_layers or set(kinds) - set(LAYER_TYPES):
-            raise ValueError(
-                f"layer_types must name n_layers={cfg.n_layers} kinds "
-                f"of {LAYER_TYPES}, got {kinds}")
-        if "sliding_attention" in kinds and not cfg.sliding_window:
-            raise ValueError("sliding_attention layers need "
-                             "sliding_window")
-        if cfg.num_experts and (cfg.score_func != "sigmoid"
-                                or not cfg.route_norm):
-            raise ValueError(
-                "the routed layer scores with a sigmoid and renormalises "
-                f"the selected: score_func={cfg.score_func!r}, "
-                f"route_norm={cfg.route_norm}")
-        groups = [("dense_layers", kinds[:lead], False),
-                  ("periods", kinds[lead:],
-                   bool(cfg.num_experts))]
-        counts = 0
-        for name, group, routed in groups:
-            if not group:
-                continue
-            period, repeats = _shortest_period(group)
-            stack = self._stack(LayerPeriod, name, repeats,
-                                **{ROUTER_STATE: 0})(
-                cfg, self.attention_fn, period, routed, repeats, name=name)
-            x, c = stack(x, angles)
-            counts = counts + jnp.sum(c, axis=0)
-        if self.device_sums:
-            for name, value in zip(MOE_DEVICE_SUMS, counts):
-                device_sums.add(name, value)
-        return x
+                "a looped model (total_ut_steps > 1) has no routed "
+                "experts: expert_bias and the device's sums count one "
+                "pass")
+        one_pass = LoopPass(cfg, self.attention_fn, name="loop")
+        states = []
+        for _ in range(cfg.total_ut_steps):
+            x = one_pass(x, angles)
+            states.append(x)
+        return jnp.stack(states)
 
     @nn.compact
     def __call__(self, tokens, *, seq_offset=0, decode=False,
@@ -670,8 +744,33 @@ class TransformerLM(nn.Module):
         angles = jax.lax.dynamic_slice_in_dim(
             angles, seq_offset, tokens.shape[1], axis=0)
 
-        if cfg.layer_types is not None:
-            x = self._layered(x, angles, decode)
+        looped = cfg.total_ut_steps > 1
+        if looped and cfg.layer_types is None:
+            raise ValueError(
+                "total_ut_steps belongs to a model with layer_types")
+        if cfg.layer_types is not None and decode:
+            raise ValueError(
+                "a model with layer_types has no KV-cache path: two "
+                "kinds of layer in one cache, and a looped model one "
+                "set of keys and values a pass (serving/kvcache.py)")
+        if looped:
+            states = self._loop(x, angles)
+            with jax.named_scope("exit_gate"):
+                # float32, as a router's product: a pass's gate is one
+                # number a token
+                gates = nn.Dense(1, dtype=jnp.float32,
+                                 param_dtype=jnp.float32,
+                                 name="early_exit_gate")(states)[..., 0]
+            if pre_logits:
+                # the normed state (R, B, S, M) and the gate's logit
+                # (R, B, S) of EVERY pass
+                return (states, gates), head
+            x = states[-1]
+        elif cfg.layer_types is not None:
+            x, counts = _layered(self, x, angles)
+            if self.device_sums:
+                for name, value in zip(MOE_DEVICE_SUMS, counts):
+                    device_sums.add(name, value)
         else:
             if cfg.sandwich_norm or cfg.num_dense_layers \
                     or cfg.num_shared_experts or cfg.mup_enabled \
@@ -680,11 +779,12 @@ class TransformerLM(nn.Module):
                     "sandwich_norm, mup_enabled, num_dense_layers, "
                     "num_shared_experts and num_experts_held belong "
                     "to a model with layer_types")
-            stack = self._stack(DecoderBlock, "layers", cfg.n_layers,
-                                remat=True, cache=0)(
+            stack = _stack(self, DecoderBlock, "layers", cfg.n_layers,
+                           remat=True, cache=0)(
                 cfg, self.attention_fn, decode, name="layers")
             x, _ = stack(x, angles, seq_offset)
-        x = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="ln_final")(x)
+        if not looped:
+            x = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="ln_final")(x)
         if pre_logits:
             # hand the caller the final hidden states + the output
             # head (the tied embedding, or ``lm_head``) so the logits
@@ -768,6 +868,21 @@ def lm_loss(logits, targets):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return -jnp.mean(ll)
+
+
+def exit_distribution(gates):
+    """The distribution over the passes a looped model's token exits
+    at, from the gate's logits ``gates`` (R, ...) with the passes
+    leading: ``p_t = sigmoid(g_t) prod_{j<t} (1 - sigmoid(g_j))`` and
+    the last pass takes what is left, so every token's sum to 1.
+    Returns ``(p, entropy)``, float32, (R, ...) and (...)."""
+    gates = gates.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gates), axis=0)   # log prod(1 - l)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(gates[:-1]) + before[:-1], before[-1:]])
+    p = jnp.exp(log_p)
+    return p, -jnp.sum(p * log_p, axis=0)
 
 
 def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
@@ -860,11 +975,35 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
         def pre(params, tokens):
             return model(params, tokens, pre_logits=True)
 
+    cfg = getattr(model, "cfg", None)
+    looped = getattr(cfg, "total_ut_steps", 1) > 1
+
     def objective(x, emb, tokens):
         targets = jnp.roll(tokens, -1, axis=1)
         w = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
-        return chunked_lm_loss(x, emb, targets, n_chunks=n_chunks,
-                               weights=w)
+        if not looped:
+            return chunked_lm_loss(x, emb, targets, n_chunks=n_chunks,
+                                   weights=w)
+        # a looped model: the expectation of the passes' cross-entropies
+        # under each token's exit distribution, less the entropy bonus.
+        # The distribution sums to 1, so the exits stacked on the batch
+        # axis with the weights p * w go through the one chunked loss
+        # with the one denominator sum(w)
+        states, gates = x
+        passes = states.shape[0]
+        with jax.named_scope("exit_gate"):
+            p, entropy = exit_distribution(gates)
+            weighted = p * w
+            bonus = jnp.sum(entropy * w) / jnp.sum(w)
+            device_sums.add(LOOP_TOKENS_SUM,
+                            jnp.sum(w).astype(jnp.int32))
+            for t, mass in enumerate(jnp.sum(weighted, axis=(1, 2)), 1):
+                device_sums.add_fraction(loop_exit_mass_sum(t), mass)
+        expected = chunked_lm_loss(
+            states.reshape((-1,) + states.shape[2:]), emb,
+            jnp.tile(targets, (passes, 1)), n_chunks=n_chunks,
+            weights=weighted.reshape(-1, w.shape[-1]))
+        return expected - cfg.exit_entropy_coeff * bonus
 
     if with_state:
         def loss_fn(params, state, tokens):
@@ -875,6 +1014,10 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
     else:
         def loss_fn(params, tokens):
             return objective(*pre(params, tokens), tokens)
-    # what the model sums on the device, for the compiled step
+    # what the model sums on the device, for the compiled step, and
+    # what a step call of it counts on the host
     loss_fn.device_sums = tuple(getattr(model, "device_sums", ()))
+    if looped:
+        loss_fn.step_counts = {
+            LOOP_LAYER_APPLICATIONS: cfg.n_layers * cfg.total_ut_steps}
     return loss_fn

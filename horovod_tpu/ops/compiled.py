@@ -414,7 +414,7 @@ class _TimedFirstCall:
     shardings; no arrays) and answers ``report()`` from them, lazily."""
 
     __slots__ = ("_fn", "_scope", "_timed", "_args", "_report",
-                 "reduced_bytes", "sum_names")
+                 "reduced_bytes", "sum_names", "step_counts")
 
     def __init__(self, fn, scope=None):
         self._fn = fn
@@ -423,6 +423,8 @@ class _TimedFirstCall:
         self.reduced_bytes = getattr(fn, "reduced_bytes", (0, 0))
         # the sums a train step's model makes on the device
         self.sum_names = getattr(fn, "sum_names", ())
+        # {counter: what a call of a train step's program adds to it}
+        self.step_counts = getattr(fn, "step_counts", {})
         self._scope = scope     # a ``jax.named_scope`` the program has
         self._timed = False
         self._args = self._report = None
@@ -2311,6 +2313,10 @@ def _reduce_the_rest(grads, reduce_leaf, covered):
     return grads, [total, sum(found.values())]
 
 
+_STEP_COUNTS_HELP = ("Counted a call of the compiled train step's program, "
+                     "as its loss function asked (loss_fn.step_counts)")
+
+
 def _call_program(prog, state, tree):
     """One call of a step's program, under its span, and what the
     program's trace says it reduces added to the byte counters."""
@@ -2324,6 +2330,12 @@ def _call_program(prog, state, tree):
     if len(out) == 3:
         # the model sums on the device: note where the newest are
         device_sums.publish(prog, prog.sum_names, out[2])
+    if prog.step_counts:
+        from .. import telemetry
+
+        for name, amount in prog.step_counts.items():
+            telemetry.registry().counter(
+                name, _STEP_COUNTS_HELP).inc(amount)
     return out[:2]
 
 
@@ -2540,6 +2552,7 @@ class _CompiledTrainStep:
             compiler_options=dict(self._compiler_options(ex)) or None)
         jitted.reduced_bytes = reduced_bytes
         jitted.sum_names = sum_names
+        jitted.step_counts = dict(getattr(loss_fn, "step_counts", {}))
         return jitted
 
     def _compiler_options(self, ex):

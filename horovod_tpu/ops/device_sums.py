@@ -28,6 +28,12 @@ import numpy as np
 
 _TRACING = threading.local()
 STATE_KEY = "device_sums"
+#: a sum of fractions (a probability mass over tokens) is kept in steps
+#: of 2**-FRACTION_BITS: a step call may add up to 2**(31 -
+#: FRACTION_BITS) to it over all ranks, and rounds by at most half a
+#: step
+FRACTION_BITS = 8
+_FRACTIONS = set()     # the names ``add_fraction`` was handed
 
 
 def declared(loss_fn):
@@ -42,6 +48,14 @@ def add(name, value):
     found = getattr(_TRACING, "sums", None)
     if found is not None:
         found[name] = found.get(name, 0) + value
+
+
+def add_fraction(name, value):
+    """``add`` for a non-negative float scalar ``value``: the sum
+    ``name`` is kept in fixed point on the device and read as a float
+    counter."""
+    _FRACTIONS.add(name)
+    add(name, jnp.round(value * (1 << FRACTION_BITS)).astype(jnp.int32))
 
 
 @contextlib.contextmanager
@@ -93,7 +107,8 @@ def _fold(reg):
             record["seen"][name] = value
             # a state that started again from zero counts from there
             reg.counter(name, _HELP).labels().inc(
-                value - seen if value >= seen else value)
+                (value - seen if value >= seen else value)
+                / (1 << FRACTION_BITS if name in _FRACTIONS else 1))
 
 
 _HELP = ("Summed on the device inside the compiled train step "

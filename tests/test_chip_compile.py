@@ -156,6 +156,54 @@ def _lm436m_step_case():
     return program, state, ((1,) + tokens[0], tokens[1])
 
 
+@pytest.mark.slow
+@pytest.mark.parametrize("policy, fits", [("full", True),
+                                          ("dots_flash", False)])
+def test_looped_cell_fits_under_full_remat_only(chip, policy, fits,
+                                                monkeypatch):
+    """The benchmark's looped cell (Ouro-2.6B: 8 layers, 4 passes, 1 x
+    4096 tokens, 9.8 GB of state) as the chip's compiler sees its step:
+    it takes ``full`` remat and refuses ``dots_flash``, which every
+    other LM cell runs under, for HBM (PERF.md section 6, PR 32)."""
+    import json
+
+    from chipbench.adapters import looped_lm_train as adapter
+    from chipbench.run import with_rehearsal
+    from horovod_tpu.ops import device_sums, pallas_kernels
+    from horovod_tpu.ops.xla_ops import MeshExecutor
+
+    def load(*parts):
+        with open(os.path.join(REPO, "chipbench", *parts)) as f:
+            return with_rehearsal(json.load(f), False)
+
+    config = dict(load("configs", "ouro-2.6b-l8.json"), remat_policy=policy)
+    workload = load("workloads", "s4k-b1-1chip.json")
+    monkeypatch.setattr(pallas_kernels, "default_interpret", lambda: False)
+    step = adapter.make_step(config, workload, False)
+    params, _ = adapter.param_shapes(config, workload)
+    state = jax.eval_shape(lambda p: {
+        "params": p, "opt_state": step.optimizer.init(p),
+        device_sums.STATE_KEY: device_sums.zeros(
+            device_sums.declared(step.loss_fn))}, params)
+    one_chip = SingleDeviceSharding(chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    batch = jax.ShapeDtypeStruct((1, 1, workload["seq_len"]), jnp.int32)
+    with jax.enable_x64(False):
+        lowered = step._build(MeshExecutor([chip], 1)).lower(
+            shaped(state), shaped(batch))
+        if not fits:
+            with pytest.raises(Exception, match="Ran out of memory"):
+                lowered.compile()
+            return
+        text = lowered.compile().as_text()
+    # two flash kernels a layer body, the body once a pass
+    assert text.count("tpu_custom_call") >= 2
+
+
 @pytest.mark.parametrize("name", [
     "flash_fwd", "flash_fwd_s4k", "flash_fwd_s8k", "flash_fwd_s8k_w4096",
     "flash_fwd_s8k_w2048", "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",

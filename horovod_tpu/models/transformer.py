@@ -34,6 +34,7 @@ TPU-first choices:
   expected loss under the gate's distribution over the exits.
 """
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -885,25 +886,130 @@ def exit_distribution(gates):
     return p, -jnp.sum(p * log_p, axis=0)
 
 
+def _seq_chunks(n_chunks, *arrays):
+    """Each (B, S, ...) -> (n_chunks, B, S / n_chunks, ...)."""
+    def chunked(a):
+        b, s = a.shape[:2]
+        return jnp.moveaxis(
+            a.reshape(b, n_chunks, s // n_chunks, *a.shape[2:]), 1, 0)
+
+    return tuple(chunked(a) for a in arrays)
+
+
+def _seq_unchunk(a):
+    """(n_chunks, B, C, ...) -> (B, n_chunks * C, ...)."""
+    a = jnp.moveaxis(a, 0, 1)
+    return a.reshape(a.shape[0], -1, *a.shape[3:])
+
+
+def _chunk_ce(xc, tc, embd):
+    """One sequence chunk through the head: ``(e, sumexp, nll)`` with
+    ``e = exp(logits - rowmax)`` (B, C, V), its row sums and the
+    per-token ``logsumexp - target logit``, all float32."""
+    # (B, C, M) @ (M, V): f32 accumulation on bf16 operands, same
+    # numerics as the unfused logits einsum
+    logits = jnp.einsum("bcm,vm->bcv", xc, embd,
+                        preferred_element_type=jnp.float32)
+    top = jnp.max(logits, axis=-1)
+    e = jnp.exp(logits - top[..., None])
+    sumexp = jnp.sum(e, axis=-1)
+    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+    return e, sumexp, (jnp.log(sumexp) + top) - tgt
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _mean_ce(x, embd, targets, weights, denom, n_chunks):
+    """``sum(nll * weights) / denom`` over the sequence chunks."""
+    def body(total, inp):
+        xc, tc, wc = inp
+        return total + jnp.sum(_chunk_ce(xc, tc, embd)[2] * wc), None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                            _seq_chunks(n_chunks, x, targets, weights))
+    return total / denom
+
+
+def _mean_ce_fwd(x, embd, targets, weights, denom, n_chunks):
+    """The loss AND its gradients, each chunk's formed from the logits
+    the loss was read from: ``(softmax - onehot) * weight / denom``
+    against the head gives the chunk's dx, against the chunk of ``x``
+    its share of the head's gradient, which the scan carries."""
+    inv = 1.0 / denom
+
+    def body(demb, inp):
+        xc, tc, wc = inp
+        # the chunk as a value of its own, sliced once: fused into the
+        # two products that read it, the slice out of the stacked ``x``
+        # costs each a slower tiling on the chip (3.2 ms of the head's
+        # 85.5 at (4, 4096, 4096) x 32000; PERF.md section 6, PR 33)
+        xc = jax.lax.optimization_barrier(xc)
+        e, sumexp, nll = _chunk_ce(xc, tc, embd)
+        scale = inv * wc
+        onehot = jax.lax.broadcasted_iota(
+            jnp.int32, e.shape, e.ndim - 1) == tc[..., None]
+        dlogits = e * (scale / sumexp)[..., None] - jnp.where(
+            onehot, scale[..., None], 0.0)
+        # float32 dlogits against bf16 operands at the default
+        # precision, as autodiff's transposes of the logits product are
+        dxc = jax.lax.dot_general(
+            dlogits, embd, (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(xc.dtype)
+        dembc = jax.lax.dot_general(
+            dlogits, xc, (((0, 1), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32).astype(embd.dtype)
+        return demb + dembc, (dxc, nll * inv, jnp.sum(nll * wc))
+
+    # last chunk first: the order in which autodiff's backward scan
+    # summed the head's gradient in its (bf16) carry; the loss is
+    # summed first chunk first, as the plain call above sums it
+    demb, (dx, dweights, parts) = jax.lax.scan(
+        body, jnp.zeros_like(embd),
+        _seq_chunks(n_chunks, x, targets, weights), reverse=True)
+    total = functools.reduce(jnp.add, parts)
+    return total / denom, (
+        _seq_unchunk(dx), demb, _seq_unchunk(dweights),
+        -total * jax.lax.integer_pow(denom, -2))
+
+
+def _mean_ce_bwd(n_chunks, res, g):
+    # the step's cotangent is the literal 1, which the compiler drops:
+    # no sweep over the head's gradient is spent on a scale
+    dx, demb, dweights, ddenom = res
+    return (dx * g.astype(dx.dtype), demb * g.astype(demb.dtype), None,
+            dweights * g, ddenom * g)
+
+
+_mean_ce.defvjp(_mean_ce_fwd, _mean_ce_bwd)
+
+
 def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
     """Cross-entropy fused with the logits projection, chunked over the
     sequence so the full (B, S, V) logits tensor is never materialized.
 
     ``lm_loss(model.apply(...), targets)`` stores the f32 logits plus a
-    f32 log-softmax — 2 * B*S*V*4 bytes of HBM (2.6 GB at B=5, S=2048,
+    f32 log-softmax — 2 * B*S*V*4 bytes of HBM (4.2 GB at B=4, S=4096,
     V=32k) that caps the trainable batch and adds two full HBM sweeps.
     Here each ``lax.scan`` step projects one sequence chunk, reduces it
     to per-token (logsumexp − target-logit) contributions, and drops
-    the chunk logits; ``jax.checkpoint`` re-runs the chunk projection
-    in the backward instead of saving it (the logits matmul is ~7% of
-    the model's FLOPs, so the recompute costs ~2%).
+    the chunk logits.
+
+    Under ``jax.grad`` the gradient is formed in the forward pass
+    (a ``jax.custom_vjp``): the scan step that holds a chunk's logits
+    also forms ``(softmax − onehot) * weight`` from them, the chunk's
+    ``dx`` and its share of the head's gradient, so the head runs three
+    vocabulary-sized products a step (logits, dx, d head) and no chunk
+    is projected a second time; the backward pass only scales what the
+    forward kept by the incoming cotangent.  The price: forward-mode
+    differentiation (``jax.jvp`` / ``jacfwd``) and second derivatives
+    of this loss are not offered (nothing in ``horovod_tpu`` takes
+    either).  ``x``, ``emb`` and ``weights`` are differentiable.
 
     Exactly equals ``lm_loss`` in f32 (tests/test_models.py).
 
     Args:
       x: final hidden states (B, S, M) in the activation dtype
          (``model.apply(..., pre_logits=True)``).
-      emb: tied embedding (V, M) f32.
+      emb: the head (V, M) f32: the tied embedding or ``lm_head``.
       targets: (B, S) int32 target ids (already shifted).
       n_chunks: sequence chunks; S % n_chunks must be 0.
       weights: optional (B, S) f32 per-token weights — pass 0 for
@@ -915,35 +1021,13 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
     if s % n_chunks:
         raise ValueError(f"seq len {s} not divisible by n_chunks "
                          f"{n_chunks}")
-    c = s // n_chunks
     if weights is None:
         weights = jnp.ones((b, s), jnp.float32)
-
-    def chunk_nll(xc, tc, wc):
-        # (B, C, M) @ (M, V): f32 accumulation on bf16 operands, same
-        # numerics as the unfused logits einsum
-        logits = jnp.einsum("bcm,vm->bcv", xc, embd,
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, tc[..., None],
-                                  axis=-1)[..., 0]
-        return jnp.sum((lse - tgt) * wc)
-
-    def body(total, inp):
-        return total + jax.checkpoint(chunk_nll)(*inp), None
-
-    def chunked(a):
-        return jnp.moveaxis(a.reshape(b, n_chunks, c, *a.shape[2:]),
-                            1, 0)
-
     with jax.named_scope("lm_head_ce"):
-        embd = emb.astype(x.dtype)
-        total, _ = jax.lax.scan(
-            body, jnp.zeros((), jnp.float32),
-            (chunked(x), chunked(targets), chunked(weights)))
         denom = jnp.sum(weights)
         # all-padding batches (weight sum 0) yield loss 0, not 0/0 = NaN
-        return total / jnp.where(denom > 0, denom, 1.0)
+        return _mean_ce(x, emb.astype(x.dtype), targets, weights,
+                        jnp.where(denom > 0, denom, 1.0), n_chunks)
 
 
 def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
@@ -952,6 +1036,10 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
     ``lm_loss(model.apply(...)[:, :-1], tokens[:, 1:])`` via
     :func:`chunked_lm_loss` — targets rolled (not sliced, so S stays
     chunkable and sp-shard-aligned) with the final position weighted 0.
+    The head's gradient is formed in the forward pass, by that
+    function's own rule: ``jax.grad`` / ``value_and_grad`` of
+    ``loss_fn`` are what it offers, forward-mode and second
+    derivatives are not.
 
     The single definition of the fused objective, shared by
     ``parallel.make_lm_train_step(fused_ce=True)``, the pipelined step,

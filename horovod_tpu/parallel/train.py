@@ -52,7 +52,9 @@ def make_lm_train_step(mesh: Mesh, cfg: TransformerConfig,
 
     ``fused_ce=True`` fuses the logits projection into a
     sequence-chunked cross-entropy (``ce_chunks`` chunks) so the
-    (B, S, V) logits tensor never hits HBM.
+    (B, S, V) logits tensor never hits HBM; each chunk's gradient is
+    formed with its logits, in the forward pass (``models.
+    chunked_lm_loss``: reverse-mode first derivatives only).
 
     ``pipeline`` opts the step into the MPMD pipeline runtime
     (runtime.py; docs/parallelism.md): a :class:`~.runtime.

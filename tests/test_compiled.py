@@ -485,6 +485,13 @@ def test_step_scopes_in_lowered_text(hvd_shutdown, model):
         assert scope in text, scope
     # the flash backward is ONE kernel, under the old dkv scope
     assert "flash_dq" not in text
+    if model == "lm":
+        # the head forms its gradient where it forms its logits: its
+        # projection (the one einsum of that spelling, which the
+        # lowered text names relative to the function that holds it)
+        # is not computed a second time in the backward
+        assert "bcm,vm->bcv/dot_general" in text
+        assert "rematted_computation/bcm,vm->bcv" not in text
 
 
 @pytest.mark.parametrize("sharded", [False, True],
@@ -509,12 +516,17 @@ def test_step_scopes_in_program_table(hvd_shutdown, model, sharded):
                  any("jvp(" in p and "transpose(" not in p
                      for p in in_grad),
                  any("rematted_computation" in p for p in in_grad))
-        return missing, kinds
+        head_remat = [p for p in paths if "lm_head_ce" in p
+                      and "rematted_computation" in p]
+        return missing, kinds, head_remat
 
-    for missing, (backward, forward, remat) in run_ranks(fn, 2):
+    for missing, (backward, forward, remat), head_remat in run_ranks(
+            fn, 2):
         assert not missing, missing
         assert backward and forward
+        # the layers' recomputation; none of it is the loss head's
         assert remat == (model == "lm")
+        assert not head_remat, head_remat
 
 
 def test_step_report_table_memory_and_laziness(hvd_shutdown):

@@ -149,6 +149,147 @@ def test_chunked_lm_loss_matches_unfused(tiny_lm):
         chunked_lm_loss(x, emb, tokens, n_chunks=7)
 
 
+_HEAD_V, _HEAD_M, _HEAD_S = 24, 8, 12      # vocabulary unlike any other
+
+
+def _unfused_ce(x, head, targets, w):
+    """The weighted mean cross-entropy with every logit held, float32."""
+    logp = jax.nn.log_softmax(jnp.einsum("bsm,vm->bsv", x, head))
+    nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+    if w is None:
+        return jnp.mean(nll)
+    total = jnp.sum(w)
+    return jnp.sum(nll * w) / jnp.where(total > 0, total, 1.0)
+
+
+def _head_problem(tied, weights):
+    """A head's whole setting in a dozen lines: ``(fused, unfused,
+    params, tokens)``, the two losses of one small model whose head is
+    the embedding (``tied``) or a matrix of its own, with per-token
+    weights that are absent, constant, or a function of another
+    parameter (the looped model's ``p * w``)."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+
+    def normal(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32) * 0.5
+
+    params = {"embed": normal(keys[0], _HEAD_V, _HEAD_M),
+              "mix": normal(keys[1], _HEAD_M, _HEAD_M),
+              "gate": normal(keys[2], _HEAD_M)}
+    if not tied:
+        params["lm_head"] = normal(keys[3], _HEAD_V, _HEAD_M)
+    tokens = jax.random.randint(keys[4], (2, _HEAD_S), 0, _HEAD_V)
+
+    def parts(p, tokens):
+        x = jnp.tanh(p["embed"][tokens] @ p["mix"])
+        w = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
+        if weights == "none":
+            w = None
+        elif weights == "differentiable":
+            w = jax.nn.sigmoid(x @ p["gate"]) * w
+        return (x, p["embed"] if tied else p["lm_head"],
+                jnp.roll(tokens, -1, axis=1), w)
+
+    def fused(p, tokens):
+        x, head, targets, w = parts(p, tokens)
+        return chunked_lm_loss(x, head, targets, n_chunks=3, weights=w)
+
+    def unfused(p, tokens):
+        return _unfused_ce(*parts(p, tokens))
+
+    return fused, unfused, params, tokens
+
+
+@pytest.mark.parametrize("ranks", [None, 2], ids=["plain", "vmap"])
+@pytest.mark.parametrize("weights",
+                         ["none", "constant", "differentiable"])
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_chunked_lm_loss_gradient_formed_in_forward(tied, weights, ranks):
+    """The chunked loss's own rule (its gradient is formed in the scan
+    that forms the logits) against plain autodiff of the unfused
+    float32 loss: value and every parameter's gradient, the weights'
+    own included, for both heads, and under ``jax.vmap`` over a
+    leading rank axis as the one-rank step body runs it."""
+    fused, unfused, params, tokens = _head_problem(tied, weights)
+    grad = jax.value_and_grad
+    if ranks:
+        params = jax.tree.map(
+            lambda a: jnp.stack([a * (1 + 0.1 * r) for r in range(ranks)]),
+            params)
+        tokens = jnp.stack([jnp.roll(tokens, r) for r in range(ranks)])
+        grad = lambda f: jax.vmap(jax.value_and_grad(f))    # noqa: E731
+    la, ga = grad(unfused)(params, tokens)
+    lb, gb = grad(fused)(params, tokens)
+    np.testing.assert_allclose(la, lb, atol=1e-6)
+    assert set(ga) == set(gb)
+    for name in ga:
+        np.testing.assert_allclose(ga[name], gb[name], atol=1e-6,
+                                   err_msg=name)
+    gate = np.abs(np.asarray(gb["gate"])).max()
+    assert (gate > 1e-4) == (weights == "differentiable")
+
+
+def test_chunked_lm_loss_weights_cotangent_and_zero_sum():
+    """The weights as an argument: their gradient is each token's own
+    ``(lse - tgt)`` over the sum, less the loss over the sum; and a
+    weight sum of zero gives loss 0 and zero, finite gradients."""
+    _, _, params, tokens = _head_problem(True, "constant")
+    x = params["embed"][tokens]
+    targets = jnp.roll(tokens, -1, axis=1)
+    w = jnp.linspace(0.0, 1.0, tokens.size, dtype=jnp.float32).reshape(
+        tokens.shape)
+
+    def unfused(x, head, w):
+        return _unfused_ce(x, head, targets, w)
+
+    def loss(x, head, w):
+        return chunked_lm_loss(x, head, targets, n_chunks=4, weights=w)
+
+    for weights in (w, jnp.zeros_like(w)):
+        la, ga = jax.value_and_grad(unfused, (0, 1, 2))(
+            x, params["embed"], weights)
+        lb, gb = jax.value_and_grad(loss, (0, 1, 2))(
+            x, params["embed"], weights)
+        np.testing.assert_allclose(la, lb, atol=1e-6)
+        for a, b in zip(ga, gb):
+            assert np.isfinite(b).all()
+            np.testing.assert_allclose(a, b, atol=1e-6)
+    assert float(lb) == 0.0
+    assert not np.asarray(gb[0]).any() and not np.asarray(gb[1]).any()
+
+
+def _equations(jaxpr, in_scan=False):
+    """Every equation of a jaxpr and of the jaxprs inside it, each with
+    whether a ``scan`` body holds it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_scan
+        inner = in_scan or eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, inner)
+
+
+def test_chunked_lm_loss_projects_each_chunk_once():
+    """What a CPU can decide of the program: the gradient of the
+    chunked loss holds no ``checkpoint`` and, in its scan bodies,
+    exactly the three products of the vocabulary's width that the
+    mathematics needs (logits, dx, d head), where recomputing the
+    logits in the backward made four."""
+    fused, _, params, tokens = _head_problem(False, "differentiable")
+    jaxpr = jax.make_jaxpr(jax.grad(fused))(params, tokens).jaxpr
+    equations = list(_equations(jaxpr))
+    names = {eqn.primitive.name for eqn, _ in equations}
+    assert "scan" in names and "custom_vjp_call" not in names
+    assert not {n for n in names if "remat" in n or "checkpoint" in n}
+    wide = [eqn for eqn, in_scan in equations
+            if in_scan and eqn.primitive.name == "dot_general"
+            and any(_HEAD_V in v.aval.shape
+                    for v in eqn.invars + eqn.outvars)]
+    assert len(wide) == 3
+    # forward-mode differentiation is the price (the docstring says so)
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(lambda p: fused(p, tokens), (params,), (params,))
+
+
 def test_remat_dots_flash_matches_dots():
     """remat_policy='dots_flash' (save the checkpoint-named flash
     kernel outputs so the backward replay skips the pallas forward)

@@ -25,7 +25,10 @@ TPU-first choices:
   published ``config.json`` files such as Trinity-Mini's ``afmoe``):
   sliding and full attention in one model, leading dense layers, then
   routed experts of which this model may hold a share
-  (``parallel/moe.routed_experts_apply``).  Depth still costs no
+  (``parallel/moe.route`` + ``routed_experts_apply``): a sigmoid router
+  balanced by a bias, or a softmax router balanced by an auxiliary
+  loss, which may read the layer's input before attention
+  (SmallThinker's ``config.json``).  Depth still costs no
   compile time: the leading dense layers are one scan, the others one
   scan over the periods of their pattern of kinds.
 * A looped model (``total_ut_steps`` > 1, the key of Ouro's published
@@ -107,10 +110,23 @@ class TransformerConfig:
     moe_intermediate_size: Optional[int] = None   # None => d_ff
     num_shared_experts: int = 0   # a dense SwiGLU of that many expert
     # widths beside the routed ones
-    score_func: str = "sigmoid"   # over all num_experts; the one kind
-    route_norm: bool = True       # the routed layer has: sigmoid scores,
-    route_scale: float = 1.0      # the selected renormalised, then scaled
-    load_balance_coeff: float = 0.0   # > 0: each routed layer keeps an
+    score_func: str = "sigmoid"   # over all num_experts: "sigmoid"
+    # scores, or "softmax" (logits; the weights are the softmax over the
+    # selected).  Either way the selected are renormalised
+    route_norm: bool = True       # (``route_norm``, the only kind built)
+    route_scale: float = 1.0      # and then scaled
+    router_before_attention: bool = False   # the router reads the
+    # layer's INPUT, before its first norm and attention; the experts
+    # still take the normed state after attention, with that routing
+    expert_activation: str = "silu"   # the routed experts' gate: "silu"
+    # (SwiGLU) | "relu" (ReGLU)
+    router_aux_loss_coef: float = 0.0   # > 0 (a softmax router): each
+    # routed layer's load-balancing loss E * sum_e f_e P_e
+    # (``parallel/moe.load_balance_loss``) leaves the stack beside the
+    # counts, and ``make_fused_lm_loss`` adds this much times its mean
+    # over the routed layers to the cross-entropy
+    load_balance_coeff: float = 0.0   # > 0 (a sigmoid router): each
+    # routed layer keeps an
     # ``expert_bias`` (collection ``router_state``) that enters its
     # top-k selection only and moves by this much a step toward the
     # experts that got fewer tokens than the mean, where the caller
@@ -435,19 +451,52 @@ ROUTER_STATE = "router_state"
 MOE_DEVICE_SUMS = ("horovod_moe_assignments_total",
                    "horovod_moe_held_assignments_total",
                    "horovod_moe_dropped_assignments_total")
+#: and where the router is balanced by an auxiliary loss
+#: (``router_aux_loss_coef`` > 0): the sum over the routed layers of
+#: that loss (a fraction: 1.0 a layer under a balanced router) and of
+#: the tokens the busiest of ALL the router's experts got
+MOE_AUX_LOSS_SUM = "horovod_moe_aux_loss_total"
+MOE_MAX_EXPERT_TOKENS_SUM = "horovod_moe_max_expert_tokens_total"
+
+
+def _layer_sums(cfg, counts=0, aux_loss=0, max_expert_tokens=0):
+    """What a layer hands up the stack beside its output, summed over
+    the layers on the way: the routed layer's counts
+    (``MOE_DEVICE_SUMS``) and, where the router has an auxiliary loss,
+    that loss and the busiest expert's tokens.  The defaults are the
+    sums' zero."""
+    sums = {"counts": counts}
+    if cfg.router_aux_loss_coef:
+        sums.update(aux_loss=aux_loss, max_expert_tokens=max_expert_tokens)
+    return sums
+
+
+def _expert_bias(module):
+    """A routed layer's ``expert_bias`` variable, or None where the
+    model keeps none or the caller applied it without the collection."""
+    if not module.cfg.load_balance_coeff or not (
+            module.is_initializing()
+            or module.has_variable(ROUTER_STATE, "expert_bias")):
+        return None
+    return module.variable(ROUTER_STATE, "expert_bias", jnp.zeros,
+                           (module.cfg.num_experts,), jnp.float32)
 
 
 class RoutedExperts(nn.Module):
     """The feed-forward of an expert layer: a router over all
-    ``num_experts``, the routed experts this model holds (the dropless
-    grouped product of ``parallel/moe.routed_experts_apply``) and the
-    shared expert beside them.  Returns ``(y, counts)``; ``counts`` is
-    int32 (3,): assignments, those on held experts, those of them not
-    computed (``MOE_DEVICE_SUMS``)."""
+    ``num_experts`` (``parallel/moe.route``: ``cfg.score_func``), the
+    routed experts this model holds (the dropless grouped product of
+    ``parallel/moe.routed_experts_apply``) and the shared experts
+    beside them.  ``route_only`` stops after the routing of ``x`` and
+    returns it; handed back as ``routing`` with the tensor the experts
+    take, the layer goes on from there (a router that reads the layer's
+    input before attention).  Returns ``(y, sums)``, ``sums`` as
+    ``_layer_sums``: ``counts`` int32 (3,) are the assignments, those
+    on held experts, those of them not computed."""
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routing=None, route_only=False):
         # call-time import: parallel imports models, not the reverse
         from ..parallel import moe as moe_mod
 
@@ -457,40 +506,58 @@ class RoutedExperts(nn.Module):
         held = cfg.num_experts_held or E
         F = cfg.moe_intermediate_size or cfg.d_ff
         init = nn.initializers.lecun_normal()
-        router = self.param("router", init, (M, E), jnp.float32)
-        wi_gate = self.param("wi_gate", init, (held, M, F), jnp.float32)
-        wi_up = self.param("wi_up", init, (held, M, F), jnp.float32)
-        wo = self.param("wo", init, (held, F, M), jnp.float32)
+        flat = x.reshape(B * S, M)
+        if routing is None:
+            router = self.param("router", init, (M, E), jnp.float32)
+        if not route_only:
+            experts = [
+                self.param(name, init, shape, jnp.float32).astype(cfg.dtype)
+                for name, shape in (("wi_gate", (held, M, F)),
+                                    ("wi_up", (held, M, F)),
+                                    ("wo", (held, F, M)))]
         # expert_bias enters the selection only.  Its update is a rule
         # of the training loop: the layer reads it from ``router_state``
-        # and, where the caller made that collection mutable, leaves
-        # the updated one there; without the collection it is zero
-        bias, state = jnp.zeros((E,), jnp.float32), None
-        if cfg.load_balance_coeff and (
-                self.is_initializing()
-                or self.has_variable(ROUTER_STATE, "expert_bias")):
-            state = self.variable(ROUTER_STATE, "expert_bias", jnp.zeros,
-                                  (E,), jnp.float32)
-            bias = state.value
-        y, counts, tokens_per_expert = moe_mod.routed_experts_apply(
-            x.reshape(B * S, M), router, bias,
-            wi_gate.astype(cfg.dtype), wi_up.astype(cfg.dtype),
-            wo.astype(cfg.dtype), first_expert=cfg.first_expert_held,
-            topk=cfg.expert_top_k, route_scale=cfg.route_scale)
+        # and, where the caller made that collection mutable, leaves the
+        # updated one there; without the collection it is zero
+        state = _expert_bias(self)
+        if routing is None:
+            bias = jnp.zeros((E,), jnp.float32) \
+                if cfg.score_func == "sigmoid" else None
+            if state is not None:
+                bias = state.value
+            weights, idx, tokens_per_expert, mean_probs = moe_mod.route(
+                flat, router, cfg.expert_top_k,
+                score_func=cfg.score_func, expert_bias=bias,
+                route_scale=cfg.route_scale)
+            balance = {}
+            if cfg.router_aux_loss_coef:
+                balance = dict(
+                    aux_loss=moe_mod.load_balance_loss(tokens_per_expert,
+                                                       mean_probs),
+                    max_expert_tokens=jnp.max(tokens_per_expert))
+            routing = (weights, idx, tokens_per_expert, balance)
+        if route_only:
+            return routing
+        weights, idx, tokens_per_expert, balance = routing
+        y, counts = moe_mod.routed_experts_apply(
+            flat, weights, idx, *experts, num_experts=E,
+            first_expert=cfg.first_expert_held,
+            activation=cfg.expert_activation)
         if state is not None and not self.is_initializing() \
                 and self.is_mutable_collection(ROUTER_STATE):
             state.value = moe_mod.updated_expert_bias(
-                bias, tokens_per_expert, cfg.load_balance_coeff)
+                state.value, tokens_per_expert, cfg.load_balance_coeff)
         y = y.reshape(B, S, M).astype(cfg.dtype)
         if cfg.num_shared_experts:
             y = y + SwiGLU(cfg, F * cfg.num_shared_experts,
                            name="shared")(x)
-        return y, counts
+        return y, _layer_sums(cfg, counts, **balance)
 
 
 class LayeredBlock(nn.Module):
     """One layer of a model whose layers differ: attention of this
-    layer's kind, then the dense SwiGLU or the routed experts."""
+    layer's kind, then the dense SwiGLU or the routed experts (routed,
+    under ``router_before_attention``, from the layer's input)."""
     cfg: TransformerConfig
     attention_fn: Callable
     layer_type: str
@@ -503,6 +570,13 @@ class LayeredBlock(nn.Module):
         def norm(name):
             return RMSNorm(cfg.dtype, cfg.rms_norm_eps, name=name)
 
+        routing = None
+        if self.routed:
+            moe = RoutedExperts(cfg, name="moe")
+            if cfg.router_before_attention:
+                # issued ahead of attention, under the routed layer's
+                # own scope ``moe/route`` all the same
+                routing = moe(x, route_only=True)
         # the device trace's path carries the layer's published kind
         with jax.named_scope(self.layer_type):
             h = Attention(cfg, self.attention_fn,
@@ -513,13 +587,14 @@ class LayeredBlock(nn.Module):
         x = x + h
         m = norm("ln_mlp")(x)
         if self.routed:
-            f, counts = RoutedExperts(cfg, name="moe")(m)
+            f, sums = moe(m, routing)
         else:
-            f, counts = SwiGLU(cfg, name="mlp")(m), \
-                jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32)
+            f, sums = SwiGLU(cfg, name="mlp")(m), _layer_sums(
+                cfg, jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32),
+                jnp.float32(0), jnp.int32(0))
         if cfg.sandwich_norm:
             f = norm("ln_post_mlp")(f)
-        return x + f, counts
+        return x + f, sums
 
 
 class LayerPeriod(nn.Module):
@@ -538,12 +613,12 @@ class LayerPeriod(nn.Module):
         # pass and keep every activation after all
         block = _with_remat(LayeredBlock, self.cfg,
                             prevent_cse=self.repeats == 1)
-        counts = 0
+        sums = _layer_sums(self.cfg)
         for i, kind in enumerate(self.layer_types):
-            x, c = block(self.cfg, self.attention_fn, kind, self.routed,
-                         name=f"layer_{i}")(x, angles)
-            counts = counts + c
-        return x, counts
+            x, layer = block(self.cfg, self.attention_fn, kind, self.routed,
+                             name=f"layer_{i}")(x, angles)
+            sums = jax.tree.map(jnp.add, sums, layer)
+        return x, sums
 
 
 def _with_remat(block, cfg, prevent_cse=False):
@@ -614,12 +689,37 @@ def _stack(module, block, name, length, remat=False, hook=True,
         metadata_params={nn.PARTITION_NAME: "layers"})
 
 
+def _check_router(cfg):
+    """Refuse a routed layer this model does not build."""
+    from ..parallel import moe as moe_mod
+
+    if cfg.score_func not in moe_mod.SCORE_FUNCS or not cfg.route_norm:
+        raise ValueError(
+            f"the routed layer scores with one of {moe_mod.SCORE_FUNCS} "
+            "and renormalises the selected: "
+            f"score_func={cfg.score_func!r}, route_norm={cfg.route_norm}")
+    if cfg.expert_activation not in moe_mod.ACTIVATIONS:
+        raise ValueError(
+            f"expert_activation must be one of "
+            f"{tuple(moe_mod.ACTIVATIONS)}, got {cfg.expert_activation!r}")
+    sigmoid = cfg.score_func == "sigmoid"
+    if (cfg.load_balance_coeff and not sigmoid) or (
+            cfg.router_aux_loss_coef and sigmoid):
+        raise ValueError(
+            "a sigmoid router is balanced by its expert_bias "
+            "(load_balance_coeff), a softmax router by its auxiliary "
+            f"loss (router_aux_loss_coef): score_func={cfg.score_func!r}, "
+            f"load_balance_coeff={cfg.load_balance_coeff}, "
+            f"router_aux_loss_coef={cfg.router_aux_loss_coef}")
+
+
 def _layered(module, x, angles):
     """The stack of a model whose layers differ, built inside
     ``module`` (which has ``cfg`` and ``attention_fn``): the leading
     dense layers one scan, the others one scan over the periods of
     their pattern of kinds, so depth costs no compile time.  Returns
-    ``(x, what the routed layers counted)``."""
+    ``(x, the layers' sums)`` (``_layer_sums``: what the routed layers
+    counted, and their auxiliary losses where the router has one)."""
     cfg = module.cfg
     kinds, lead = cfg.layer_types, cfg.num_dense_layers
     if len(kinds) != cfg.n_layers or set(kinds) - set(LAYER_TYPES):
@@ -629,16 +729,12 @@ def _layered(module, x, angles):
     if "sliding_attention" in kinds and not cfg.sliding_window:
         raise ValueError("sliding_attention layers need "
                          "sliding_window")
-    if cfg.num_experts and (cfg.score_func != "sigmoid"
-                            or not cfg.route_norm):
-        raise ValueError(
-            "the routed layer scores with a sigmoid and renormalises "
-            f"the selected: score_func={cfg.score_func!r}, "
-            f"route_norm={cfg.route_norm}")
+    if cfg.num_experts:
+        _check_router(cfg)
     groups = [("dense_layers", kinds[:lead], False),
               ("periods", kinds[lead:],
                bool(cfg.num_experts))]
-    counts = 0
+    sums = _layer_sums(cfg)
     for name, group, routed in groups:
         if not group:
             continue
@@ -651,9 +747,10 @@ def _layered(module, x, angles):
                        hook=cfg.total_ut_steps == 1,
                        **{ROUTER_STATE: 0})(
             cfg, module.attention_fn, period, routed, repeats, name=name)
-        x, c = stack(x, angles)
-        counts = counts + jnp.sum(c, axis=0)
-    return x, counts
+        x, periods = stack(x, angles)
+        sums = jax.tree.map(lambda total, each: total + jnp.sum(each, axis=0),
+                            sums, periods)
+    return x, sums
 
 
 class LoopPass(nn.Module):
@@ -702,9 +799,18 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         if cfg.total_ut_steps > 1:
             return loop_device_sums(cfg.total_ut_steps)
-        routed = cfg.layer_types is not None and cfg.num_experts \
-            and cfg.num_dense_layers < cfg.n_layers
-        return MOE_DEVICE_SUMS if routed else ()
+        if cfg.layer_types is None or not self.routed_layers:
+            return ()
+        return MOE_DEVICE_SUMS + (
+            (MOE_AUX_LOSS_SUM, MOE_MAX_EXPERT_TOKENS_SUM)
+            if cfg.router_aux_loss_coef else ())
+
+    @property
+    def routed_layers(self):
+        """How many of the layers have routed experts."""
+        cfg = self.cfg
+        return max(cfg.n_layers - cfg.num_dense_layers, 0) \
+            if cfg.num_experts else 0
 
     def _loop(self, x, angles):
         """The stack of a looped model: ONE ``LoopPass`` applied
@@ -745,7 +851,7 @@ class TransformerLM(nn.Module):
         angles = jax.lax.dynamic_slice_in_dim(
             angles, seq_offset, tokens.shape[1], axis=0)
 
-        looped = cfg.total_ut_steps > 1
+        looped, aux_loss = cfg.total_ut_steps > 1, None
         if looped and cfg.layer_types is None:
             raise ValueError(
                 "total_ut_steps belongs to a model with layer_types")
@@ -768,10 +874,15 @@ class TransformerLM(nn.Module):
                 return (states, gates), head
             x = states[-1]
         elif cfg.layer_types is not None:
-            x, counts = _layered(self, x, angles)
+            x, sums = _layered(self, x, angles)
             if self.device_sums:
-                for name, value in zip(MOE_DEVICE_SUMS, counts):
+                for name, value in zip(MOE_DEVICE_SUMS, sums["counts"]):
                     device_sums.add(name, value)
+            if cfg.router_aux_loss_coef and self.routed_layers:
+                device_sums.add_fraction(MOE_AUX_LOSS_SUM, sums["aux_loss"])
+                device_sums.add(MOE_MAX_EXPERT_TOKENS_SUM,
+                                sums["max_expert_tokens"])
+                aux_loss = sums["aux_loss"] / self.routed_layers
         else:
             if cfg.sandwich_norm or cfg.num_dense_layers \
                     or cfg.num_shared_experts or cfg.mup_enabled \
@@ -790,8 +901,10 @@ class TransformerLM(nn.Module):
             # hand the caller the final hidden states + the output
             # head (the tied embedding, or ``lm_head``) so the logits
             # projection can fuse into a chunked loss
-            # (chunked_lm_loss) instead of materializing (B, S, V)
-            return x, head
+            # (chunked_lm_loss) instead of materializing (B, S, V);
+            # beside the states, where the router has one, the mean over
+            # the routed layers of its auxiliary loss
+            return (x if aux_loss is None else (x, aux_loss)), head
         # logits matmul in the activation dtype with f32 accumulation:
         # a (B*S, M) @ (M, V) f32 matmul would run at a fraction of the
         # MXU's bf16 rate and dominate the step at large vocab
@@ -1049,6 +1162,11 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
     ``apply(params, tokens, pre_logits=True) -> (x, emb)`` callable
     (e.g. ``make_pipelined_lm_apply``'s).
 
+    A model whose router is balanced by an auxiliary loss
+    (``router_aux_loss_coef`` > 0) hands that loss's mean over its
+    routed layers back beside the states, and the objective is the
+    cross-entropy plus the coefficient times it.
+
     ``with_state`` (a flax model): ``loss_fn(params, state, tokens) ->
     (loss, new_state)`` for ``make_compiled_train_step(...,
     has_aux=True)``, where ``state`` is the model's other collections
@@ -1066,12 +1184,24 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
     cfg = getattr(model, "cfg", None)
     looped = getattr(cfg, "total_ut_steps", 1) > 1
 
+    aux_coef = getattr(cfg, "router_aux_loss_coef", 0.0) \
+        if getattr(model, "routed_layers", 0) else 0.0
+
     def objective(x, emb, tokens):
         targets = jnp.roll(tokens, -1, axis=1)
         w = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
-        if not looped:
+        def cross_entropy(x):
             return chunked_lm_loss(x, emb, targets, n_chunks=n_chunks,
                                    weights=w)
+
+        if aux_coef:
+            # a loss term born inside the scanned, rematerialised layers
+            # (the routed layers' balance loss, their mean) came out
+            # beside the states: it joins the cross-entropy here
+            x, aux_loss = x
+            return cross_entropy(x) + aux_coef * aux_loss
+        if not looped:
+            return cross_entropy(x)
         # a looped model: the expectation of the passes' cross-entropies
         # under each token's exit distribution, less the entropy bonus.
         # The distribution sums to 1, so the exits stacked on the batch

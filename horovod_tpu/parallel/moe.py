@@ -41,8 +41,9 @@ __all__ = [
     "parse_moe_label", "snap_ep", "expert_capacity", "top_k_gating",
     "make_dispatch_plan", "straight_through", "moe_dispatch",
     "moe_combine", "capacity_moe_apply", "quantized_all_to_all",
-    "dense_flop_matched_ff", "score_top_k_routing", "updated_expert_bias",
-    "held_buffer_rows", "routed_experts_apply",
+    "dense_flop_matched_ff", "score_top_k_routing",
+    "softmax_top_k_routing", "route", "load_balance_loss",
+    "updated_expert_bias", "held_buffer_rows", "routed_experts_apply",
 ]
 
 #: expert-parallel degrees the autotuner sweeps (snapped at latch
@@ -249,6 +250,80 @@ def score_top_k_routing(x, router_w, expert_bias, topk, *, route_scale=1.0):
     return weights * route_scale, idx
 
 
+def softmax_top_k_routing(x, router_w, topk, *, route_scale=1.0):
+    """Logits over ALL experts in float32, the ``topk`` largest
+    selected, and the softmax over the SELECTED logits (which is the
+    softmax over all of them with the selected renormalised), scaled:
+    ``(weights, idx, probs)``, the first two (T, topk), ``probs``
+    (T, experts) the softmax over all, which the balance loss reads.
+    The gradient reaches the router through the weights and ``probs``."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    picked, idx = lax.top_k(logits, topk)
+    weights = jax.nn.softmax(picked, axis=-1)
+    return weights * route_scale, idx, jax.nn.softmax(logits, axis=-1)
+
+
+#: the routers :func:`route` knows, by the ``score_func`` of published
+#: configurations
+SCORE_FUNCS = ("sigmoid", "softmax")
+
+
+def route(x, router_w, topk, *, score_func="sigmoid", expert_bias=None,
+          route_scale=1.0):
+    """The routing of one layer as a step of its own, from whatever
+    tensor the model routes on (the feed-forward's input, or the
+    layer's input before attention): every token's ``topk`` of the
+    ``router_w.shape[-1]`` experts and their weights, which
+    :func:`routed_experts_apply` takes.
+
+    ``score_func`` ``"sigmoid"``: :func:`score_top_k_routing` (with
+    ``expert_bias``, zero without one); ``"softmax"``:
+    :func:`softmax_top_k_routing`.  Returns ``(weights (T, topk)
+    float32, idx (T, topk), tokens_per_expert int32 (experts,),
+    mean_probs)``: ``tokens_per_expert`` is what
+    :func:`updated_expert_bias` and :func:`load_balance_loss` read,
+    ``mean_probs`` float32 (experts,) the mean over the tokens of the
+    softmax over all experts (``None`` from the sigmoid router, which
+    is balanced by its bias)."""
+    num_experts = router_w.shape[-1]
+    with jax.named_scope("route"):
+        if score_func == "sigmoid":
+            if expert_bias is None:
+                expert_bias = jnp.zeros((num_experts,), jnp.float32)
+            weights, idx = score_top_k_routing(
+                x, router_w, expert_bias, topk, route_scale=route_scale)
+            mean_probs = None
+        elif score_func == "softmax":
+            if expert_bias is not None:
+                raise ValueError("the softmax router takes no expert_bias")
+            weights, idx, probs = softmax_top_k_routing(
+                x, router_w, topk, route_scale=route_scale)
+            mean_probs = jnp.mean(probs, axis=0)
+        else:
+            raise ValueError(f"score_func must be one of {SCORE_FUNCS}, "
+                             f"got {score_func!r}")
+        tokens_per_expert = jnp.sum(
+            idx[:, :, None] == jnp.arange(num_experts), axis=(0, 1),
+            dtype=jnp.int32)
+    return weights, idx, tokens_per_expert, mean_probs
+
+
+def load_balance_loss(tokens_per_expert, mean_probs):
+    """The auxiliary loss that balances a softmax router
+    (arXiv:2101.03961 eq. 4-6): ``E * sum_e f_e P_e`` with ``f_e`` the
+    share of the assignments that fell on expert e (counted, so no
+    gradient passes) and ``P_e`` = ``mean_probs``; 1.0 exactly under a
+    balanced router, up to ``E`` under one that sends everything to one
+    expert.  Both vectors are over the tokens THIS caller routed: a
+    data- or expert-parallel group that wants them over all its tokens
+    reduces them first (docs/parallelism.md)."""
+    with jax.named_scope("aux_loss"):
+        load = lax.stop_gradient(tokens_per_expert.astype(jnp.float32))
+        share = load / jnp.maximum(jnp.sum(load), 1.0)
+        return mean_probs.shape[-1] * jnp.sum(share * mean_probs)
+
+
 def updated_expert_bias(expert_bias, tokens_per_expert, coeff):
     """``expert_bias`` after a step in which the experts got
     ``tokens_per_expert`` (E,): up by ``coeff`` for an expert that got
@@ -329,13 +404,17 @@ def _from_rows_bwd(res, ct):
 _from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
 
 
-def _pass_of_experts(rows_held, topk, start, y, x, order, slot, sizes,
-                     weights, wi_gate, wi_up, wo):
+#: the gate's activation of a gated expert: SwiGLU's and ReGLU's
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _pass_of_experts(rows_held, topk, activation, start, y, x, order, slot,
+                     sizes, weights, wi_gate, wi_up, wo):
     """``y`` plus the held experts' part for the assignments ``start``
     .. ``start + rows_held`` of ``order`` (held ones first, by expert;
-    ``slot`` is its inverse): SwiGLU as grouped products over the
-    ragged groups, weighted and summed back by token; and how many rows
-    some group owned."""
+    ``slot`` is its inverse): the gated unit (``activation`` on the
+    gate) as grouped products over the ragged groups, weighted and
+    summed back by token; and how many rows some group owned."""
     with jax.named_scope("dispatch"):
         rows = lax.dynamic_slice(order, (start,), (rows_held,))
         ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows_held)
@@ -346,7 +425,7 @@ def _pass_of_experts(rows_held, topk, start, y, x, order, slot, sizes,
         valid = (local >= 0) & (local < ends[-1])
         xs = _to_rows(x, token, owned, local, valid)
     with jax.named_scope("experts"):
-        gate = jax.nn.silu(lax.ragged_dot(xs, wi_gate, groups))
+        gate = ACTIVATIONS[activation](lax.ragged_dot(xs, wi_gate, groups))
         up = lax.ragged_dot(xs, wi_up, groups)
         out = lax.ragged_dot(gate * up, wo, groups)
     with jax.named_scope("combine"):
@@ -358,7 +437,7 @@ def _pass_of_experts(rows_held, topk, start, y, x, order, slot, sizes,
 
 
 @lru_cache(maxsize=None)
-def _held_experts(rows_held, topk):
+def _held_experts(rows_held, topk, activation):
     """``(x, order, slot, sizes, weights, wi_gate, wi_up, wo) -> (y
     (T, M) float32, rows computed)``: as many passes through a buffer of
     ``rows_held`` rows as the held assignments need, one where they
@@ -381,8 +460,8 @@ def _held_experts(rows_held, topk):
 
         def one(i, carry):
             y, computed = carry
-            y, owned = _pass_of_experts(rows_held, topk, i * rows_held,
-                                        y, *args)
+            y, owned = _pass_of_experts(rows_held, topk, activation,
+                                        i * rows_held, y, *args)
             return y, computed + owned
 
         first = one(0, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
@@ -393,8 +472,8 @@ def _held_experts(rows_held, topk):
         def gradients(start):
             _, vjp = jax.vjp(
                 lambda x, *rest: _pass_of_experts(
-                    rows_held, topk, start, jnp.zeros_like(ct), x,
-                    order, slot, sizes, *rest)[0],
+                    rows_held, topk, activation, start, jnp.zeros_like(ct),
+                    x, order, slot, sizes, *rest)[0],
                 x, weights, wi_gate, wi_up, wo)
             return vjp(ct)
 
@@ -419,17 +498,20 @@ def _held_experts(rows_held, topk):
     return held_experts
 
 
-def routed_experts_apply(x, router_w, expert_bias, wi_gate, wi_up, wo,
-                         *, first_expert=0, topk, route_scale=1.0):
+def routed_experts_apply(x, weights, idx, wi_gate, wi_up, wo, *, num_experts,
+                         first_expert=0, activation="silu"):
     """The routed experts of one layer as ONE member of an
     expert-parallel group computes them: it is told which experts it
     holds (``wi_gate`` / ``wi_up`` (H, M, F) and ``wo`` (H, F, M) are
     experts ``first_expert`` .. ``first_expert + H`` of the
-    ``router_w.shape[-1]`` the router scores), routes every token over
-    all of them, and returns the part of ``sum_j w_j
-    SwiGLU_{idx_j}(x)`` that its own experts give.  Assignments to
-    absent experts contribute nothing; nothing stands in for the
-    members that hold them or for the exchange with them.
+    ``num_experts`` the router scores), TAKES the routing of every
+    token over all of them (``weights``, ``idx`` (T, topk): the step
+    :func:`route` makes, from ``x`` or from an earlier tensor), and
+    returns the part of ``sum_j w_j FF_{idx_j}(x)`` that its own experts
+    give, ``FF`` the gated unit ``(act(x Wg) * x Wu) Wo`` with
+    ``activation`` ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).
+    Assignments to absent experts contribute nothing; nothing stands in
+    for the members that hold them or for the exchange with them.
 
     No assignment to a held expert is dropped, whatever the routing:
     the assignments are sorted by expert, held ones first, and the
@@ -437,22 +519,13 @@ def routed_experts_apply(x, router_w, expert_bias, wi_gate, wi_up, wo,
     buffer of ``held_buffer_rows`` rows, as many times as it takes
     (once under a router near balance).
 
-    ``x`` (T, M) in the products' dtype; the router's product, scores
-    and top-k are float32.  Returns ``(y (T, M) float32, counts,
-    tokens_per_expert)``; ``counts`` is int32 (3,): the assignments
-    (T * topk), those that fell on held experts, and those of them that
-    were not computed (0, or the layer is not dropless);
-    ``tokens_per_expert`` int32 (num_experts,) is what
-    :func:`updated_expert_bias` reads."""
-    T = x.shape[0]
-    held, num_experts = wi_gate.shape[0], router_w.shape[-1]
+    ``x`` (T, M) in the products' dtype.  Returns ``(y (T, M) float32,
+    counts)``; ``counts`` is int32 (3,): the assignments (T * topk),
+    those that fell on held experts, and those of them that were not
+    computed (0, or the layer is not dropless)."""
+    T, topk = idx.shape
+    held = wi_gate.shape[0]
     n = T * topk
-    with jax.named_scope("route"):
-        weights, idx = score_top_k_routing(
-            x, router_w, expert_bias, topk, route_scale=route_scale)
-        tokens_per_expert = jnp.sum(
-            idx[:, :, None] == jnp.arange(num_experts), axis=(0, 1),
-            dtype=jnp.int32)
     with jax.named_scope("dispatch"):
         local = idx.reshape(n) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)
@@ -469,11 +542,10 @@ def routed_experts_apply(x, router_w, expert_bias, wi_gate, wi_up, wo,
             + (jnp.cumsum(sizes) - sizes)[None]
         slot = jnp.where(key < held, jnp.sum(
             jnp.where(mine, earlier, 0), axis=1), n).reshape(T, topk)
-    y, computed = _held_experts(rows_held, topk)(
+    y, computed = _held_experts(rows_held, topk, activation)(
         x, order, slot, sizes, weights, wi_gate, wi_up, wo)
     n_held = jnp.sum(sizes)
-    return y, jnp.stack([jnp.int32(n), n_held, n_held - computed]), \
-        tokens_per_expert
+    return y, jnp.stack([jnp.int32(n), n_held, n_held - computed])
 
 
 # ---------------------------------------------------------------------------

@@ -50,10 +50,14 @@ def _layer(key, held, first=0, router=None):
 
 
 def _apply(x, p, first=0, bias=None):
-    return moe.routed_experts_apply(
-        x, p["router"], jnp.zeros((E,)) if bias is None else bias,
-        p["wi_gate"], p["wi_up"], p["wo"], first_expert=first, topk=K,
-        route_scale=2.826)
+    """The routing step and the experts that take it, in a row, as
+    Trinity's layer calls them: ``(y, counts, tokens_per_expert)``."""
+    w, idx, by_expert, _ = moe.route(
+        x, p["router"], K, expert_bias=bias, route_scale=2.826)
+    y, counts = moe.routed_experts_apply(
+        x, w, idx, p["wi_gate"], p["wi_up"], p["wo"], num_experts=E,
+        first_expert=first)
+    return y, counts, by_expert
 
 
 def _one_hot_form(x, p, first=0, bias=None):

@@ -62,6 +62,9 @@ _QKV_LONG = [((1, 8192, 8, 128), jnp.bfloat16)] * 3
 # the benchmark's s8k cells (chipbench/: Mistral-7B and Trinity-Mini at
 # 2 x 8192 tokens, 32 heads of 128): full, Mistral's window, Trinity's
 _QKV_S8K = [((2, 8192, 32, 128), jnp.bfloat16)] * 3
+# ... SmallThinker's 28 query heads (7 to each of 4 kv heads, expanded
+# as the model hands them over: 56 rows of heads, no power of two)
+_QKV_S8K_H28 = [((2, 8192, 28, 128), jnp.bfloat16)] * 3
 # ... and its s4k cells (4 x 4096 tokens; the 4096 window does not bind)
 _QKV_S4K = [((4, 4096, 32, 128), jnp.bfloat16)] * 3
 _N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
@@ -91,6 +94,8 @@ def _kernel_cases():
         "flash_fwd_s8k": (_flash(), _QKV_S8K, 1),
         "flash_fwd_s8k_w4096": (_flash(window=4096), _QKV_S8K, 1),
         "flash_fwd_s8k_w2048": (_flash(window=2048), _QKV_S8K, 1),
+        "flash_fwd_s8k_h28": (_flash(), _QKV_S8K_H28, 1),
+        "flash_fwd_s8k_h28_w4096": (_flash(window=4096), _QKV_S8K_H28, 1),
         "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 2),
         "flash_window_bwd": (
             _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 2),
@@ -101,6 +106,9 @@ def _kernel_cases():
             _grad_of_sum(_flash(window=4096), 3), _QKV_S8K, 2),
         "flash_bwd_s8k_w2048": (
             _grad_of_sum(_flash(window=2048), 3), _QKV_S8K, 2),
+        "flash_bwd_s8k_h28": (_grad_of_sum(_flash(), 3), _QKV_S8K_H28, 2),
+        "flash_bwd_s8k_h28_w4096": (
+            _grad_of_sum(_flash(window=4096), 3), _QKV_S8K_H28, 2),
         "quantize_int8": (
             lambda x: pk.quantize_blockwise(x, interpret=False),
             [((_N,), jnp.float32)], 1),
@@ -206,8 +214,10 @@ def test_looped_cell_fits_under_full_remat_only(chip, policy, fits,
 
 @pytest.mark.parametrize("name", [
     "flash_fwd", "flash_fwd_s4k", "flash_fwd_s8k", "flash_fwd_s8k_w4096",
-    "flash_fwd_s8k_w2048", "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
-    "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "quantize_int8",
+    "flash_fwd_s8k_w2048", "flash_fwd_s8k_h28", "flash_fwd_s8k_h28_w4096",
+    "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
+    "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "flash_bwd_s8k_h28",
+    "flash_bwd_s8k_h28_w4096", "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
     "fused_scale_cast",
     pytest.param("lm436m_step", marks=pytest.mark.slow)])

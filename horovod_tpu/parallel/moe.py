@@ -349,12 +349,58 @@ def held_buffer_rows(n_assignments, held, num_experts):
     return min(rows, n_assignments)
 
 
+#: a gathered table of more bytes than this is gathered in column pieces.
+#: A v5e's compiler keeps a gather's table in the on-chip memory (``S(1)``
+#: in the compiled program) while it fits, and the gather then runs at
+#: 6 ns a row; a larger table stays in HBM and the gather takes 46 ns a
+#: row.  bf16 (20,480, 2,560) = 105 MB fits, (30,720, 2,048) = 126 MB
+#: does not (alone, the line is between 117 and 120 MB).
+_TABLE_BYTES = 100 * 2 ** 20
+#: ... of whole lane tiles
+_LANES = 128
+#: the sublanes of a tile: a slot table of K columns cuts one unless
+#: K is a multiple
+_SUBLANES = 8
+
+
+def _column_pieces(n_rows, width, itemsize):
+    """``[(first, last), ...]``: the columns of an ``(n_rows, width)``
+    table in as few pieces of whole lane tiles as bring each under
+    ``_TABLE_BYTES``; one piece for a table that is."""
+    lanes = -(-width // _LANES)
+    fit = max(_TABLE_BYTES // (n_rows * _LANES * itemsize), 1)
+    pieces = -(-lanes // fit)           # lane tiles that fit, a piece
+    step = -(-lanes // pieces) * _LANES     # ... spread evenly
+    return [(first, min(first + step, width))
+            for first in range(0, width, step)]
+
+
 def _sum_by_owner(rows, slot, valid):
     """(N, M) from ``rows`` (R, M): owner n's sum over its K slots of
-    the rows ``slot[n, k]``, where ``valid``; float32."""
-    picked = rows[jnp.where(valid, slot, 0)]
-    return jnp.sum(jnp.where(valid[..., None], picked, 0), axis=1,
-                   dtype=jnp.float32)
+    the rows ``slot[n, k]``, where ``valid``; float32.
+
+    In the form the chip runs fast at any shape (PERF.md, PR 35): the
+    slots lead the gathered ``(K, N, M)`` where K columns would cut a
+    tile (a ``(N, 6, M)`` is laid out again, 1.8 ms for 503 MB), and a
+    table over ``_TABLE_BYTES`` is gathered and summed by column
+    pieces.  For K a multiple of 8 and a table under the budget nothing
+    is added to the one gather and the one sum."""
+    n_slots = slot.shape[1]
+    axis = 0 if n_slots > 1 and n_slots % _SUBLANES else 1
+    index = jnp.where(valid, slot, 0)
+    if axis == 0:
+        index, valid = index.T, valid.T
+
+    def piece(rows):
+        picked = rows[index]
+        return jnp.sum(jnp.where(valid[..., None], picked, 0), axis=axis,
+                       dtype=jnp.float32)
+
+    pieces = _column_pieces(*rows.shape, rows.dtype.itemsize)
+    if len(pieces) == 1:
+        return piece(rows)
+    return jnp.concatenate([piece(rows[:, first:last])
+                            for first, last in pieces], axis=1)
 
 
 # Rows move between the owners' order (tokens) and the buffer's order
@@ -363,7 +409,11 @@ def _sum_by_owner(rows, slot, valid):
 # (``slot``, the inverse), so the transpose of a gather is the other
 # gather and never the scatter-add autodiff would write (on a v5e a
 # scatter-add of 20,480 rows of 2,048 takes 2.8 ms, the gather of those
-# rows 0.3 ms, the gather over all 131,072 slots 2.0 ms).
+# rows 0.13 ms, the gather over all 131,072 slots 0.83 ms and the
+# masked sum over the slots 0.85-1.0).  Two rules keep the gather over
+# the slots that fast, and ``_sum_by_owner`` follows both from the
+# shapes it is given: the table it reads fits the on-chip memory, and
+# the slot table does not cut a tile.
 
 @jax.custom_vjp
 def _to_rows(x, owner, owned, slot, valid):

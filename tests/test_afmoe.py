@@ -29,6 +29,7 @@ sys.path.insert(0, REPO)
 from chipbench import weights  # noqa: E402
 from chipbench.references import afmoe_train as reference  # noqa: E402
 from chipbench.references import precision  # noqa: E402
+from tools.sum_by_owner_probe import stood as _sum_as_it_stood  # noqa: E402
 
 T, M, F, E, K = 48, 16, 8, 16, 4
 
@@ -186,6 +187,93 @@ def test_expert_bias_moves_toward_the_experts_that_got_fewer():
 
     assert int(loads[0][5]) > 3 * mean and int(loads[-1][5]) < 2 * mean
     assert off_balance(loads[-1]) < off_balance(loads[0]) / 2
+
+
+# ---------------------------------------------------------------------------
+# the way back to the tokens: each owner's sum of its buffer rows
+
+def _owners_and_rows(topk, seed=0, owners=40, width=384):
+    """A table of rows, and owners that each hold some of their
+    ``topk`` slots (a valid slot names a row of its own, the others an
+    index out of range, as the layer makes them)."""
+    rng = np.random.default_rng(seed)
+    valid = rng.random((owners, topk)) < 0.4
+    n_rows = int(valid.sum()) + 16          # and rows nobody holds
+    rows = rng.standard_normal((n_rows, width)).astype(np.float32)
+    slot = np.full((owners, topk), owners * topk, np.int32)
+    slot[valid] = rng.permutation(n_rows)[:int(valid.sum())]
+    return rows, slot, valid
+
+
+def _count(jaxpr, primitive):
+    return sum(eqn.primitive.name == primitive for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("mapped", [False, True])
+@pytest.mark.parametrize("over_budget", [False, True])
+@pytest.mark.parametrize("topk", [1, 2, 6, 8, 9])
+def test_sum_by_owner_is_each_owners_sum(topk, over_budget, mapped,
+                                         monkeypatch):
+    """Against a loop over the owners, whatever form the shapes ask
+    for: a slot table that would cut a tile, a table over the budget
+    (three column pieces of one lane tile here), under ``vmap``."""
+    rows, slot, valid = _owners_and_rows(topk)
+    if over_budget:
+        monkeypatch.setattr(moe, "_TABLE_BYTES", rows.shape[0] * 128 * 4)
+    want = np.zeros((slot.shape[0], rows.shape[1]), np.float64)
+    for owner, (slots, held) in enumerate(zip(slot, valid)):
+        for row in slots[held]:
+            want[owner] += rows[row]
+    def fn(*args):       # traced anew under this case's budget
+        return moe._sum_by_owner(*args)
+
+    pieces = 3 if over_budget else 1
+    jaxpr = jax.make_jaxpr(fn)(rows, slot, valid).jaxpr
+    assert _count(jaxpr, "gather") == pieces
+    assert _count(jaxpr, "concatenate") == (pieces > 1)
+    if mapped:
+        got = jax.jit(jax.vmap(fn, in_axes=(0, None, None)))(
+            jnp.stack([rows, 2 * rows]), slot, valid)
+        assert got.dtype == jnp.float32
+        _close(got[0], want, 1e-6)
+        _close(got[1], 2 * want, 1e-6)
+    else:
+        got = jax.jit(fn)(rows, slot, valid)
+        assert got.dtype == jnp.float32 and got.shape == want.shape
+        _close(got, want, 1e-6)
+
+
+@pytest.mark.parametrize("topk, width", [(8, 384), (16, 384), (1, 1)])
+def test_whole_tiles_under_the_budget_keep_the_form_they_had(topk, width):
+    """Trinity's shapes (top-8, a table under the budget) and the
+    ``(n, 1)`` use for the routing weights: the jaxpr is the one it
+    was, one gather and one sum, nothing padded, turned or joined."""
+    rows, slot, valid = _owners_and_rows(topk, width=width)
+    now = jax.make_jaxpr(moe._sum_by_owner)(rows, slot, valid)
+    assert str(now) == str(jax.make_jaxpr(_sum_as_it_stood)(
+        rows, slot, valid))
+    for primitive in ("pad", "concatenate", "transpose"):
+        assert _count(now.jaxpr, primitive) == 0
+    assert _count(now.jaxpr, "gather") == 1
+
+
+@pytest.mark.parametrize("n_rows, width, itemsize, want", [
+    (20480, 2048, 2, [(0, 2048)]),                      # Trinity: 84 MB
+    (20480, 2560, 2, [(0, 2560)]),                      # 105 MB fits
+    (30720, 2048, 2, [(0, 1024), (1024, 2048)]),        # 126 MB does not
+    (30720, 2560, 2, [(0, 1280), (1280, 2560)]),        # SmallThinker
+    (30720, 2560, 4, [(0, 640), (640, 1280), (1280, 1920), (1920, 2560)]),
+    (20480, 1, 4, [(0, 1)]),                            # the weights
+    (10 ** 7, 200, 4, [(0, 128), (128, 200)]),          # a tile at least
+])
+def test_column_pieces_are_whole_lane_tiles_under_the_budget(
+        n_rows, width, itemsize, want):
+    got = moe._column_pieces(n_rows, width, itemsize)
+    assert got == want
+    assert all(first % 128 == 0 for first, _ in got)
+    if n_rows < 10 ** 7:
+        assert all(n_rows * (last - first) * itemsize <= moe._TABLE_BYTES
+                   for first, last in got)
 
 
 # ---------------------------------------------------------------------------

@@ -258,6 +258,65 @@ def test_chip_compiler_takes(chip, name):
         assert mem.temp_size_in_bytes < 12.5e9
 
 
+# the routed layer's way back to the tokens (parallel/moe._sum_by_owner)
+# at the benchmark's two routed shapes: (tokens, experts a token, width,
+# buffer rows)
+_WAY_BACK = {"smallthinker": (16384, 6, 2560, 30720),    # 157 MB, top-6
+             "trinity": (16384, 8, 2048, 20480)}         # 84 MB, top-8
+
+
+def _way_back_text(chip, fn, shape, out_dtype=jnp.bfloat16):
+    """The chip's compiler's program for ``fn`` behind a producer inside
+    the program (the table is ``out * w``, as the combine makes it)."""
+    T, K, M, R = shape
+    one_chip = SingleDeviceSharding(chip)
+
+    def way_back(out, w, slot, valid):
+        return fn((out * w).astype(jnp.bfloat16), slot, valid).astype(
+            out_dtype)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((R, M), jnp.bfloat16), ((R, 1), jnp.float32),
+        ((T, K), jnp.int32), ((T, K), jnp.bool_))]
+    with jax.enable_x64(False):
+        return jax.jit(way_back).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("shape", sorted(_WAY_BACK))
+def test_way_back_gathers_from_the_on_chip_memory(chip, shape, out_dtype):
+    """Every gather over the slots reads a table the compiler keeps in
+    the on-chip memory (``S(1)``) and takes the strategy that goes with
+    it (6 ns a row on the chip against 46 from HBM), and the gathered
+    rows are summed as they lie: no ``reshape bf16[16384,6,2560]`` copy
+    (PERF.md, PR 35).  Both directions' result types."""
+    from horovod_tpu.parallel import moe
+    from tools import described_step
+
+    text = _way_back_text(chip, moe._sum_by_owner, _WAY_BACK[shape],
+                          out_dtype)
+    found = described_step.gathers(text)
+    assert len(found) == (2 if shape == "smallthinker" else 1)
+    for _, _, table, strategy, _ in found:
+        assert "S(1)" in table and strategy == "0", found
+    assert not re.search(r" = bf16\[\d+,\d+,\d+\]\S* reshape\(", text)
+
+
+def test_way_back_at_trinitys_shape_compiles_as_it_did(chip):
+    """Top-8 and a table under the budget: the program is the one the
+    function compiled to before it learned SmallThinker's shape."""
+    from horovod_tpu.parallel import moe
+    from tools.sum_by_owner_probe import stood
+
+    def bare(text):     # the instructions, without the source lines
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines() if " = " in line]
+
+    now = bare(_way_back_text(chip, moe._sum_by_owner, _WAY_BACK["trinity"]))
+    assert len(now) > 20
+    assert now == bare(_way_back_text(chip, stood, _WAY_BACK["trinity"]))
+
+
 def test_flash_backward_that_cannot_fit_vmem_names_the_bytes():
     """The backward kernel asks for the VMEM its shapes need; a
     sequence whose k, v, dk, dv and accumulators pass what a chip has

@@ -188,6 +188,103 @@ def test_dropless_under_a_router_skewed_onto_one_expert(monkeypatch):
     jax.tree.map(lambda a, b: _close(a, b, 1e-4), grads, want)
 
 
+# the way back to the tokens (``moe._sum_by_owner``) in every form the
+# shapes can ask for: 64 tokens of width 256 (two lane tiles), 9 of 32
+# experts held
+_WAY_BACK = dict(tokens=64, width=256, ffn=8, experts=32, held=9)
+
+
+def _taken_routing(topk, skewed, seed):
+    """``(x, weights, idx, wi_gate, wi_up, wo)``: a routing the layer
+    TAKES.  ``skewed``: every choice of every token on a held expert,
+    so the held assignments are all there are and pass three times
+    through a buffer sized for a balanced router; else uniform over
+    the 32 experts (one pass)."""
+    d = _WAY_BACK
+    rng = np.random.default_rng(seed)
+    among = d["held"] if skewed else d["experts"]
+    idx = np.argsort(rng.random((d["tokens"], among)), axis=1)[:, :topk]
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    return (f32(d["tokens"], d["width"]),
+            rng.random((d["tokens"], topk)).astype(np.float32) + 0.1,
+            idx.astype(np.int32),
+            f32(d["held"], d["width"], d["ffn"]) / math.sqrt(d["width"]),
+            f32(d["held"], d["width"], d["ffn"]) / math.sqrt(d["width"]),
+            f32(d["held"], d["ffn"], d["width"]) / math.sqrt(d["ffn"]))
+
+
+def _per_token_form(x, weights, idx, wi_gate, wi_up, wo):
+    """Every held expert on every token, times the weight the taken
+    routing gives it or zero."""
+    weight = jnp.sum(jnp.where(
+        idx[:, :, None] == jnp.arange(wi_gate.shape[0]),
+        weights[:, :, None], 0.0), 1)
+    hidden = jax.nn.relu(jnp.einsum("tm,emf->etf", x, wi_gate)) \
+        * jnp.einsum("tm,emf->etf", x, wi_up)
+    return jnp.einsum("etf,efm,te->tm", hidden, wo, weight)
+
+
+@pytest.mark.parametrize("mapped", [False, True])
+@pytest.mark.parametrize("skewed", [False, True])
+@pytest.mark.parametrize("over_budget", [False, True])
+@pytest.mark.parametrize("topk", [1, 2, 6, 8, 9])
+def test_the_way_back_holds_its_values_and_gradients_in_every_form(
+        topk, over_budget, skewed, mapped, monkeypatch):
+    """``routed_experts_apply`` against the per-token form: the values
+    and the gradients to ``x``, the routing weights and the three
+    expert matrices, for slot tables that fill a tile (8), cut one
+    (2, 6, 9) or have one column, a buffer under and over the budget of
+    one gather (two column pieces), one pass and three, alone and under
+    the ``vmap`` the one-device compiled step wraps the loss in."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    args = _taken_routing(topk, skewed, seed=topk)
+    n = _WAY_BACK["tokens"] * topk
+    buffer_rows = moe.held_buffer_rows(n, _WAY_BACK["held"],
+                                       _WAY_BACK["experts"])
+
+    def held_part(*args):       # traced anew under this case's budget
+        return moe.routed_experts_apply(
+            *args, num_experts=_WAY_BACK["experts"], activation="relu")
+
+    def gathers():
+        return str(jax.make_jaxpr(lambda *a: held_part(*a))(*args)).count(
+            "gather[")
+
+    if over_budget:
+        whole = gathers()
+        monkeypatch.setattr(moe, "_TABLE_BYTES", buffer_rows * 128 * 4)
+        assert len(moe._column_pieces(buffer_rows, 256, 4)) == 2
+        # one more in the first pass and in the loop of further passes
+        assert gathers() == whole + 2
+    y, counts = jax.jit(held_part)(*args)
+    n_held = int(np.sum(args[2] < _WAY_BACK["held"]))
+    assert [int(c) for c in counts] == [n, n_held, 0]
+    assert -(-n_held // buffer_rows) == (3 if skewed else 1)
+
+    def loss(fn):
+        def scalar(x, weights, wi_gate, wi_up, wo):
+            return jnp.sum(fn(x, weights, args[2], wi_gate, wi_up, wo) ** 2)
+        return scalar
+
+    inexact = (args[0], args[1]) + args[3:]
+    got_fn = jax.value_and_grad(
+        loss(lambda *a: held_part(*a)[0]), argnums=tuple(range(5)))
+    want_fn = jax.value_and_grad(loss(_per_token_form),
+                                 argnums=tuple(range(5)))
+    if mapped:      # over x and the weights, as over a rank axis
+        axes = (0, 0, None, None, None)
+        inexact = (jnp.stack([inexact[0], 0.5 * inexact[0]]),
+                   jnp.stack([inexact[1], inexact[1][::-1]])) + inexact[2:]
+        got_fn, want_fn = (jax.vmap(f, in_axes=axes)
+                           for f in (got_fn, want_fn))
+    else:
+        _close(y, _per_token_form(*args), 1e-4)
+    got, want = jax.jit(got_fn)(*inexact), jax.jit(want_fn)(*inexact)
+    jax.tree.map(lambda a, b: _close(
+        a, b, 2e-4 * float(jnp.max(jnp.abs(b)))), got, want)
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in got[1])
+
+
 def _parents_routed_layer(x, router_w, expert_bias, wi_gate, wi_up, wo, *,
                           first_expert=0, topk, route_scale=1.0):
     """``routed_experts_apply`` as the parent commit had it: routing and

@@ -11,6 +11,7 @@ devices rather than one OS process per accelerator.
 
 import os
 import threading
+import time
 from contextlib import contextmanager as _contextmanager
 
 from . import env as env_mod
@@ -195,7 +196,24 @@ def init(comm=None, process_sets=None, num_ranks=None, devices=None):
     ``jax.distributed`` so compiled collectives span processes, and a
     :class:`StoreController` for negotiation (reference
     GlooContext::Initialize, gloo/gloo_context.cc:150-216).
+
+    The call is one host span, ``hvd: init``, and its seconds land in
+    ``horovod_init_seconds_total`` of the registry it installs.
     """
+    from .. import telemetry
+    from ..utils import profiler
+
+    t0 = time.perf_counter()
+    with profiler.annotate("hvd: init"):
+        _init(comm, process_sets, num_ranks, devices)
+    # the engine has just installed this round's registry: the span's
+    # counter is written here and not through ``annotate``
+    telemetry.registry().counter(
+        telemetry.INIT_SECONDS_FAMILY, telemetry.INIT_SECONDS_HELP).inc(
+        time.perf_counter() - t0)
+
+
+def _init(comm, process_sets, num_ranks, devices):
     global _engine, _topology, _timeline
     with _state_lock:
         if _engine is not None:

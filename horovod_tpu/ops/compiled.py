@@ -451,25 +451,31 @@ class _TimedFirstCall:
     def report(self):
         """What the compiled program says about itself, or ``None``
         before its first call: ``module`` (the HLO module's name, as a
-        device trace prints it before the fingerprint), ``scopes``
-        (``telemetry.programs.instruction_scopes`` of the optimized
-        module: the join
-        between a trace's events and the ``jax.named_scope`` names),
-        ``memory`` (bytes of ``memory_analysis()``: argument, output,
-        temp, alias, generated_code) and ``cost`` (``flops``,
-        ``bytes_accessed`` of ``cost_analysis()``).  Computed at the
-        first request, from the program lowered and compiled for the
-        first call's abstract arguments (jax hands back the lowering
-        and the executable it holds for them; where it has dropped
-        them, a retrace and a read of the persistent compile cache),
-        then kept."""
+        device trace prints it before the fingerprint); the three
+        tables of ``telemetry.programs.program_tables`` over the
+        optimized module: ``scopes`` ({instruction: ``op_name`` path},
+        the join between a trace's events and the ``jax.named_scope``
+        names), ``renamed`` ({instruction: the compiler's own name} of
+        the kernels whose path in ``scopes`` is recovered by dataflow
+        and not stated by the compiler) and ``collectives`` (the
+        program's collectives, synchronous, asynchronous halves with
+        their pair, and the compute fusions that carry one, with
+        their bytes); ``memory`` (bytes of ``memory_analysis()``:
+        argument, output, temp, alias, generated_code) and ``cost``
+        (``flops``, ``bytes_accessed`` of ``cost_analysis()``).
+        Computed at the first request, from the program lowered and
+        compiled for the first call's abstract arguments (jax hands
+        back the lowering and the executable it holds for them; where
+        it has dropped them, a retrace and a read of the persistent
+        compile cache), then kept."""
         if self._report is None and self._args is not None:
             lowered = self._fn.lower(*self._args)
             compiled = lowered.compile()
             text = compiled.as_text()
-            scopes = programs.instruction_scopes(text)
+            tables = programs.program_tables(text)
             if self._scope is not None and not any(
-                    self._scope in path for path in scopes.values()):
+                    self._scope in path
+                    for path in tables["scopes"].values()):
                 # a scope the program is known to name is missing:
                 # jax's persistent compile cache leaves metadata out of
                 # its key, so it hands back an executable that another
@@ -487,9 +493,9 @@ class _TimedFirstCall:
                 finally:
                     jax.config.update(floor, kept)
                 text = compiled.as_text()
-                scopes = programs.instruction_scopes(text)
+                tables = programs.program_tables(text)
             self._report = programs.executable_report(
-                compiled, text, scopes)
+                compiled, text, tables)
         return self._report
 
 
@@ -2942,18 +2948,27 @@ class _CompiledTrainStep:
         ``params`` and every rank receives that object — as every rank
         receives the one new state a step returns.  (Each thread
         placing its own copy of a GB-scale state holds one per rank on
-        every device.)"""
-        eng, ps = _ps_state(self.process_set)
-        n_local = len(ps.executor.local_positions)
-        pos = _caller_pos(eng, ps) if n_local > 1 else None
-        if pos is None:
-            return self._build_state(params, aux)
-        rdv = _rendezvous_for(
-            ps, ("init_state",) + self._step_tag(
-                ps, basics.context().rank), n_local)
-        return rdv.run(
-            pos, (params, aux),
-            lambda slots: self._build_state(*slots[min(slots)]))
+        every device.)
+
+        The call is one host span, ``hvd: init state``; its seconds
+        land in ``horovod_init_state_seconds_total``, a rank thread's
+        wait for the one build included."""
+        from .. import telemetry
+
+        with _span("init state", telemetry.registry().counter(
+                telemetry.INIT_STATE_SECONDS_FAMILY,
+                telemetry.INIT_STATE_SECONDS_HELP)):
+            eng, ps = _ps_state(self.process_set)
+            n_local = len(ps.executor.local_positions)
+            pos = _caller_pos(eng, ps) if n_local > 1 else None
+            if pos is None:
+                return self._build_state(params, aux)
+            rdv = _rendezvous_for(
+                ps, ("init_state",) + self._step_tag(
+                    ps, basics.context().rank), n_local)
+            return rdv.run(
+                pos, (params, aux),
+                lambda slots: self._build_state(*slots[min(slots)]))
 
     def _build_state(self, params, aux):
         if self.sharded:

@@ -253,6 +253,15 @@ COMPILE_CACHE_HITS_HELP = ("Executables read from jax's persistent "
 COMPILE_CACHE_WRITES_FAMILY = "horovod_compile_cache_writes_total"
 COMPILE_CACHE_WRITES_HELP = ("Executables written to jax's persistent "
                              "compilation cache during first calls")
+INIT_SECONDS_FAMILY = "horovod_init_seconds_total"
+INIT_SECONDS_HELP = ("Seconds inside hvd.init() (the span 'hvd: init'; "
+                     "one call a process: hvd.run calls it before the "
+                     "rank threads start)")
+INIT_STATE_SECONDS_FAMILY = "horovod_init_state_seconds_total"
+INIT_STATE_SECONDS_HELP = (
+    "Seconds inside a compiled train step's init_state() (the span "
+    "'hvd: init state'), summed over the calling ranks: rank threads "
+    "of one process each wait there for the one state built for all")
 
 # -- per-hop wire accounting (docs/concepts.md "Per-hop wire"): the
 #    engine's reduction dispatch and collective_bench both consume

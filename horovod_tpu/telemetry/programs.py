@@ -149,25 +149,112 @@ _INSTRUCTION = re.compile(
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
-_OPERATION = re.compile(r" ([a-z][\w\-]*)\((?:[^%]*%([\w.\-]+))?")
+_OPERATION = re.compile(r" ([a-z][\w\-]*)\(")
+_REFERENCE = re.compile(r"%([\w.\-]+)")
 # operations that only move another instruction's result
 _MOVES = frozenset(("copy", "copy-start", "copy-done", "bitcast",
                     "get-tuple-element"))
+# ... and those a walk along the dataflow passes through besides
+_PASSED = _MOVES | {"tuple"}
+_COLLECTIVE = re.compile(
+    r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-(start|done))?$")
+# what the TPU compiler's fusions around an asynchronous collective hold
+_ASYNC_HALF = re.compile(r'custom_call_target="AsyncCollective(Start|Done)"')
+_ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)\w*)\[([\d,]*)\]")
 
 
-def instruction_scopes(hlo_text):
-    """{instruction name: ``op_name`` path} for every instruction of an
-    HLO module's text, fused ones included.  An instruction that
+def _operands(line, at):
+    """The names of an instruction's operands; its operation's ``(`` is
+    at ``at - 1`` of ``line``."""
+    end = line.find(")", at)
+    if line.find("(", at, end) >= 0:    # operands with their types
+        depth = 1
+        for end in range(at, len(line)):
+            depth += (line[end] == "(") - (line[end] == ")")
+            if not depth:
+                break
+    return _REFERENCE.findall(line, at, end)
+
+
+def _bytes(result):
+    """Bytes of a result type (``f32[4096,8,128]{...}``, or a tuple's
+    elements together)."""
+    total = 0
+    for kind, bits, dims in _ARRAY.findall(result):
+        count = 1
+        for dim in filter(None, dims.split(",")):
+            count *= int(dim)
+        total += (count * (int(bits) if bits else 8) + 7) // 8
+    return total
+
+
+def _first_reached(start, edges, wanted, passed):
+    """Breadth first from ``start`` along ``edges``: the first
+    instruction that is ``wanted``, walking on only through those that
+    are ``passed``; ``None`` where the walk ends without one."""
+    seen, frontier = {start}, [start]
+    while frontier:
+        reached = []
+        for name in frontier:
+            for other in edges.get(name, ()):
+                if other in seen:
+                    continue
+                seen.add(other)
+                if wanted(other):
+                    return other
+                if passed(other):
+                    reached.append(other)
+        frontier = reached
+    return None
+
+
+def program_tables(hlo_text):
+    """What an optimized HLO module's text says of its instructions:
+    ``{"scopes", "renamed", "collectives"}``, the three tables of a
+    program's report (``ops/compiled._TimedFirstCall.report``).
+
+    ``scopes``: {instruction name: ``op_name`` path} for every
+    instruction, fused ones included.  An instruction that
     carries no ``op_name`` of its own is booked, where it calls a
     computation (a fusion the compiler made), to that computation's
     root instruction and, where the root has none either, to the first
     instruction in it that has one; and where it only moves another's
     result (the copies and their asynchronous halves that the compiler
     inserts, a bitcast, an element of a tuple), to the instruction that
-    made the result.  What is left reads ``""``."""
-    scopes, calls, moves = {}, {}, []
+    made the result.  What is left reads ``""``.
+
+    ``renamed``: {instruction name: the compiler's own ``op_name``} of
+    the kernels the compiler put in an operation's place and named
+    after themselves (a ``custom-call`` whose ``op_name`` holds no scope
+    of the program, no ``/``: ``ragged-dot-none``), for which ``scopes``
+    holds a path recovered by dataflow: that of the first instruction
+    with a stated program path among the kernel's users (breadth first,
+    through moves, tuples and other such kernels), less its last two
+    components (the primitive and the user's own innermost scope, which
+    need not be the kernel's), then the compiler's name.  A kernel with
+    no such user keeps its own string and is not listed.
+
+    ``collectives``: one entry for every instruction of a computation
+    that runs (not a fused one) that is a collective or calls a
+    computation that holds one: ``{"instruction", "kind", "bytes",
+    "mode", "pair", "path"}``.  ``kind`` is the collective's opcode and
+    ``bytes`` those of the collective's own result; ``mode`` is
+    ``"sync"`` for a bare collective, ``"start"`` / ``"done"`` for the
+    halves of an asynchronous one (an ``all-reduce-start``, or a
+    fusion of the TPU compiler's around an ``AsyncCollectiveStart`` /
+    ``AsyncCollectiveDone`` call: ``async-collective-start.N`` in a
+    trace), ``"carried"`` for a compute fusion that holds a step of an
+    asynchronous collective; ``pair`` is the other half's name
+    (the ``done`` that the start's state reaches through the carried
+    fusions), else ``None``; ``path`` is the instruction's entry of
+    ``scopes``."""
+    lines = hlo_text.splitlines()
+    scopes, unnamed_callers, moves = {}, [], []
     roots, firsts, computation = {}, {}, None
-    for line in hlo_text.splitlines():
+    opcodes, operands_at, called = {}, {}, {}
+    kernels, collective, halves = [], {}, {}
+    for number, line in enumerate(lines):
         found = _INSTRUCTION.match(line)
         if not found:
             opened = _COMPUTATION.match(line)
@@ -178,32 +265,117 @@ def instruction_scopes(hlo_text):
         op_name = _OP_NAME.search(line)
         op_name = op_name.group(1) if op_name else ""
         scopes[name] = op_name
+        op = _OPERATION.search(line, found.end())
+        opcode = opcodes[name] = op.group(1) if op else ""
+        if op:
+            operands_at[name] = (number, op.end())
         if is_root:
             roots[computation] = op_name
+        calls = _CALLS.search(line)
+        if calls:
+            called[name] = calls.group(1)
         if op_name:
             firsts.setdefault(computation, op_name)
-        elif (called := _CALLS.search(line)):
-            calls[name] = called.group(1)
-        elif (op := _OPERATION.search(line, found.end())) \
-                and op.group(1) in _MOVES and op.group(2):
-            moves.append((name, op.group(2)))
-    for name, called in calls.items():
-        scopes[name] = roots.get(called) or firsts.get(called, "")
+        elif calls:
+            unnamed_callers.append(name)
+        elif opcode in _MOVES:
+            made_by = _REFERENCE.search(line, op.end())
+            if made_by:
+                moves.append((name, made_by.group(1)))
+        if opcode == "custom-call":
+            if op_name and "/" not in op_name:
+                # a kernel the compiler put in an operation's place
+                # and named after itself
+                kernels.append(name)
+            elif (half := _ASYNC_HALF.search(line, op.end())):
+                halves[computation] = half.group(1).lower()
+        elif _COLLECTIVE.match(opcode):
+            collective[name] = (computation, line[found.end():op.start()])
+    for name in unnamed_callers:
+        scopes[name] = roots.get(called[name]) \
+            or firsts.get(called[name], "")
+
+    users = {}      # the dataflow, read only where something walks it
+    if kernels or collective:
+        for name, (number, at) in operands_at.items():
+            for made_by in _operands(lines[number], at):
+                users.setdefault(made_by, []).append(name)
+
+    renamed = {}
+    compiler_named = frozenset(kernels)
+    for name in kernels:
+        user = _first_reached(
+            name, users,
+            lambda n: "/" in scopes.get(n, "") and n not in compiler_named,
+            lambda n: opcodes.get(n) in _PASSED or n in compiler_named)
+        enclosing = user and scopes[user].split(";")[0].split("/")[:-2]
+        if enclosing:
+            renamed[name] = scopes[name]
+            scopes[name] = "/".join(enclosing + [scopes[name]])
     for name, made_by in moves:     # in the text's order: operands first
         scopes[name] = scopes.get(made_by, "")
-    return scopes
+    return {"scopes": scopes, "renamed": renamed,
+            "collectives": _collectives(
+                collective, halves, called, opcodes, users, scopes)}
 
-def executable_report(compiled, text, scopes):
+
+def _collectives(collective, halves, called, opcodes, users, scopes):
+    """The ``collectives`` table of ``program_tables``, from
+    ``collective`` ({collective instruction: (its computation, its
+    result type)}), ``halves`` ({computation around an asynchronous
+    collective's call: ``"start"`` or ``"done"``}) and ``called``
+    ({instruction: the computation it calls})."""
+    fused = frozenset(called.values())
+    held = {}       # fused computation: the first collective in it
+    for name, (computation, _) in collective.items():
+        if computation in fused:
+            held.setdefault(computation, name)
+    entries, mode = [], {}
+    for name in opcodes if collective else ():
+        if name in collective and collective[name][0] not in fused:
+            inside = name
+        else:
+            inside = held.get(called.get(name))
+            if inside is None:
+                continue
+        kind, half = _COLLECTIVE.match(opcodes[inside]).groups()
+        mode[name] = (half or "sync") if inside is name \
+            else halves.get(called[name], "carried")
+        entries.append({
+            "instruction": name, "kind": kind,
+            "bytes": _bytes(collective[inside][1]), "mode": mode[name],
+            "pair": None, "path": scopes[name]})
+    pair = {}
+    for name, its in mode.items():
+        if its == "start":
+            done = _first_reached(
+                name, users, lambda n: mode.get(n) == "done",
+                lambda n: opcodes.get(n) in _PASSED
+                or mode.get(n) == "carried")
+            if done is not None:
+                pair[name], pair[done] = done, name
+    for entry in entries:
+        entry["pair"] = pair.get(entry["instruction"])
+    return entries
+
+
+def instruction_scopes(hlo_text):
+    """``program_tables(hlo_text)["scopes"]``."""
+    return program_tables(hlo_text)["scopes"]
+
+
+def executable_report(compiled, text, tables):
     """The report of one compiled program (``jax.stages.Compiled``)
-    whose optimized text is ``text`` and whose table is ``scopes``:
-    see ``ops/compiled._TimedFirstCall.report``."""
+    whose optimized text is ``text`` and whose tables
+    (``program_tables(text)``) are ``tables``: see
+    ``ops/compiled._TimedFirstCall.report``."""
     memory = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     return {
         "module": re.match(r"HloModule\s+([\w.\-]+)", text).group(1),
-        "scopes": scopes,
+        **tables,
         "memory": {kind: int(getattr(memory, kind + "_size_in_bytes", 0)
                              or 0)
                    for kind in ("argument", "output", "temp", "alias",

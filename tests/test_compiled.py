@@ -557,6 +557,8 @@ def test_step_report_table_memory_and_laziness(hvd_shutdown):
     # a fusion the compiler made is booked to an op of the program
     fusions = [n for n in report["scopes"] if "fusion" in n]
     assert fusions and all(report["scopes"][n] for n in fusions)
+    # one rank on the CPU: no compiler's kernel, no collective
+    assert report["renamed"] == {} and report["collectives"] == []
 
     memory = report["memory"]
     assert all(v >= 0 for v in memory.values())

@@ -7,6 +7,11 @@ shows the memory space of its table and the strategy it took.
     python tools/described_step.py <cell> [--compile] [--out <file>]
                                                   (cwd = a checkout)
 
+A four-chip cell's step is built as ``hvd.run`` builds it on the host
+with four chips: ``MeshExecutor`` over the described 2x2, the state
+replicated, the batch's leading axis (a rank's rows each) over ``hvd``.
+Its compiled text names the collectives as the chip's executable does.
+
 A gather of rows is a ``kind=kCustom`` fusion with ``gather`` in its
 ``op_name``.  Its first operand is the table: ``S(1)`` in that
 operand's layout (``bf16[30720,1280]{1,0:T(8,128)(2,1)S(1)}``) is the
@@ -30,9 +35,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 def lowered_step(cell):
     sys.path.insert(0, os.getcwd())
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
 
     from chipbench.run import with_rehearsal
     from horovod_tpu.ops import device_sums, pallas_kernels
@@ -45,8 +50,6 @@ def lowered_step(cell):
     with open("BENCHMARK.json") as f:
         bench = json.load(f)
     entry = next(w for w in bench["workloads"] if w["name"] == cell)
-    if entry["chips"] != 1:
-        raise SystemExit("one-chip cells only")
     config = load("configs", entry["config"] + ".json")
     workload = load("workloads", entry["traffic"] + ".json")
     adapter = importlib.import_module(
@@ -54,8 +57,9 @@ def lowered_step(cell):
 
     jax.config.update("jax_traceback_in_locations_limit", 0)
     pallas_kernels.default_interpret = lambda: False
-    chip = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0]
+    chips = list(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices)[:entry["chips"]]
+    ex = MeshExecutor(chips, len(chips))
     step = adapter.make_step(config, workload, False)
     params, aux = adapter.param_shapes(config, workload)
 
@@ -68,14 +72,30 @@ def lowered_step(cell):
             state[device_sums.STATE_KEY] = device_sums.zeros(names)
         return state
 
-    one_chip = SingleDeviceSharding(chip)
-    shaped = lambda tree: jax.tree.map(lambda s: jax.ShapeDtypeStruct(  # noqa: E731
-        s.shape, s.dtype, sharding=one_chip), tree)
-    batch = jax.ShapeDtypeStruct(
-        (1, workload["batch"], workload["seq_len"]), jnp.int32)
+    if len(chips) == 1:
+        replicated = by_rank = SingleDeviceSharding(chips[0])
+    else:
+        replicated = NamedSharding(ex.mesh, P())
+        by_rank = NamedSharding(ex.mesh, P("hvd"))
+
+    def shaped(tree, sharding):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    make_input = importlib.import_module(
+        f"chipbench.inputs.{workload['input']['kind']}").make
+    ranks = len(chips)
     with jax.enable_x64(False):
-        return step._build(MeshExecutor([chip], 1)).lower(
-            shaped(jax.eval_shape(state_of, params)), shaped(batch))
+        # every rank's rows, as the step stages them: a leading rank axis
+        batch = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                (ranks, a.shape[0] // ranks) + a.shape[1:], a.dtype),
+            jax.eval_shape(lambda key: make_input(
+                key, config, workload, ranks * workload["batch"]),
+                jax.random.key(0)))
+        return step._build(ex).lower(
+            shaped(jax.eval_shape(state_of, params), replicated),
+            shaped(batch, by_rank))
 
 
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ")

@@ -6,11 +6,16 @@ step, summed by instruction).  Reads the yardstick, changes none of it.
     this one or a parent's unpacked beside it; on the chip)
 
 Each row: [instruction, ms a step, events, op_name path].  ``OPS_OUT`` names
-another directory than ``<cwd>/chiprun_out``.
+another directory than ``<cwd>/chiprun_out``.  Beside it,
+``tables_<tag>.json`` holds the step program's ``renamed`` and
+``collectives`` (empty for a checkout whose report has none) and the
+seconds the program took to report itself (a line
+``{"program_reports_seconds": ...}`` too).
 """
 import json
 import os
 import sys
+import time
 
 tag, cell, seed = sys.argv[1:4]
 OUT = os.environ.get("OPS_OUT", os.path.join(os.getcwd(), "chiprun_out"))
@@ -37,6 +42,24 @@ def split(ops, scopes, trace_steps):
 
 
 scope_join.split = split
+find_report = scope_join._find_report
+
+
+def timed_report(ops):
+    t0 = time.perf_counter()
+    report = find_report(ops)
+    seconds = time.perf_counter() - t0
+    print(json.dumps({"program_reports_seconds": seconds}), flush=True)
+    os.makedirs(OUT, exist_ok=True)
+    with open(os.path.join(OUT, f"tables_{tag}.json"), "w") as f:
+        json.dump({"program_reports_seconds": seconds,
+                   "renamed": (report or {}).get("renamed", {}),
+                   "collectives": (report or {}).get("collectives", [])},
+                  f, indent=0)
+    return report
+
+
+scope_join._find_report = timed_report
 args = ["--workload", cell, "--seed", seed, "--seconds", "20", "--trace", "1"]
 if os.environ.get("REHEARSE"):
     args += ["--rehearse", "1"]

@@ -45,6 +45,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import device_sums, grad_hook
 
@@ -79,11 +80,11 @@ class TransformerConfig:
     remat: bool = False       # jax.checkpoint each block (HBM <-> FLOPs)
     remat_policy: str = "full"  # "full" recomputes everything;
     # "dots" saves matmul outputs (jax dots_with_no_batch_dims_saveable)
-    # so the backward pass skips re-running the MXU work — worth ~400MB
-    # * n_layers of HBM at (B=8, S=2048, d=1024) in exchange for the
-    # ~33% remat recompute FLOPs; "dots_flash" additionally saves the
-    # flash-attention kernel outputs (out + lse, checkpoint-named) so
-    # the backward replay skips the pallas forward too
+    # so the backward pass skips re-running the MXU work — ~400MB *
+    # n_layers of HBM at (B=8, S=2048, d=1024) for the ~33% remat
+    # recompute FLOPs; "dots_flash" also saves the flash kernels'
+    # outputs (out + lse, checkpoint-named); both keep a routed layer's
+    # named products (``_with_remat``), which are no dots either
     head_dim: Optional[int] = None     # None => d_model // n_heads
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = True   # False => an output head of its
@@ -450,7 +451,12 @@ ROUTER_STATE = "router_state"
 #: (``ops/device_sums.py``; docs/observability.md "The compiled step")
 MOE_DEVICE_SUMS = ("horovod_moe_assignments_total",
                    "horovod_moe_held_assignments_total",
-                   "horovod_moe_dropped_assignments_total")
+                   "horovod_moe_dropped_assignments_total",
+                   # the passes the held assignments took through their
+                   # buffer, and those beyond a layer's first, whose
+                   # forward the backward pass runs again
+                   "horovod_moe_passes_total",
+                   "horovod_moe_recomputed_passes_total")
 #: and where the router is balanced by an auxiliary loss
 #: (``router_aux_loss_coef`` > 0): the sum over the routed layers of
 #: that loss (a fraction: 1.0 a layer under a balanced router) and of
@@ -491,8 +497,9 @@ class RoutedExperts(nn.Module):
     returns it; handed back as ``routing`` with the tensor the experts
     take, the layer goes on from there (a router that reads the layer's
     input before attention).  Returns ``(y, sums)``, ``sums`` as
-    ``_layer_sums``: ``counts`` int32 (3,) are the assignments, those
-    on held experts, those of them not computed."""
+    ``_layer_sums``: ``counts`` int32 (5,) are the assignments, those
+    on held experts, those of them not computed, the passes through the
+    buffer and those of them beyond the first (``MOE_DEVICE_SUMS``)."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -547,7 +554,8 @@ class RoutedExperts(nn.Module):
                 and self.is_mutable_collection(ROUTER_STATE):
             state.value = moe_mod.updated_expert_bias(
                 state.value, tokens_per_expert, cfg.load_balance_coeff)
-        y = y.reshape(B, S, M).astype(cfg.dtype)
+        y = checkpoint_name(y.reshape(B, S, M).astype(cfg.dtype),
+                            moe_mod.KEPT_OUTPUT)
         if cfg.num_shared_experts:
             y = y + SwiGLU(cfg, F * cfg.num_shared_experts,
                            name="shared")(x)
@@ -627,20 +635,22 @@ def _with_remat(block, cfg, prevent_cse=False):
     forward pass, and ``prevent_cse`` can stay off)."""
     if not cfg.remat:
         return block
+    # call-time import: parallel imports models, not the reverse
+    from ..parallel.moe import KEPT_OUTPUT, KEPT_PRODUCTS
+
+    # what a policy keeps beside the dense products, by the names the
+    # kernels' outputs are checkpointed under: neither a pallas call
+    # (ops/pallas_kernels.py) nor a grouped product (parallel/moe.py)
+    # is a dot, so without its name the backward replay runs it again
+    routed = KEPT_PRODUCTS + (KEPT_OUTPUT,)
+    kept = {"dots": routed,
+            "dots_flash": ("flash_out", "flash_lse") + routed}
     policy = None
-    if cfg.remat_policy == "dots":
-        policy = jax.checkpoint_policies.\
-            dots_with_no_batch_dims_saveable
-    elif cfg.remat_policy == "dots_flash":
-        # "dots" + the flash-attention kernel outputs
-        # (checkpoint-named in ops/pallas_kernels.py): a
-        # pallas call is not a dot, so without the names the
-        # backward replay re-runs every flash forward
+    if cfg.remat_policy in kept:
         policy = jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.
-            dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                "flash_out", "flash_lse"))
+                *kept[cfg.remat_policy]))
     elif cfg.remat_policy != "full":
         raise ValueError(
             f"remat_policy must be 'full', 'dots', or "

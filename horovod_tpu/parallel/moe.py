@@ -35,6 +35,7 @@ import jax
 import jax.custom_batching
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = [
     "MOE_EP_CHOICES", "MOE_CF_CHOICES", "MOE_CHOICES", "moe_label",
@@ -458,32 +459,120 @@ _from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what the first
+#: pass of the held experts hands its backward pass: the outputs of the
+#: gate's product (before the activation) and of the up product.  A
+#: remat policy that keeps the dense products keeps these
+#: (``models/transformer._with_remat``): a grouped product is no
+#: ``dot_general``, and under ``dots`` alone the replay would run it again
+KEPT_PRODUCTS = ("moe_gate_out", "moe_up_out")
+#: ... and of the routed layer's output in the model's dtype, named by
+#: the model (``models/transformer.RoutedExperts``).  The rule's forward
+#: is one loop under ``vmap``, so a replay that needs the output (a norm
+#: after the layer) would run all three products and the way back to
+#: the tokens again; kept, it runs none.  Nothing is kept where the
+#: backward pass does not read it
+KEPT_OUTPUT = "moe_out"
+
+
+def _pass_tables(rows_held, topk, start, order, slot, sizes):
+    """Who owns what in the pass over the assignments ``start`` ..
+    ``start + rows_held`` of ``order`` (held ones first, by expert;
+    ``slot`` is its inverse): ``(rows`` the assignment of each buffer
+    row, ``groups`` each expert's rows, ``owned`` (rows_held, 1) whether
+    some group owns the row, ``token`` the row's owner, ``local`` the
+    slots in this buffer, ``valid`` where one is an owned row, and how
+    many rows are owned)``."""
+    rows = lax.dynamic_slice(order, (start,), (rows_held,))
+    ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows_held)
+    groups = jnp.diff(ends, prepend=0)
+    owned = (jnp.arange(rows_held) < ends[-1])[:, None]
+    local = slot - start
+    valid = (local >= 0) & (local < ends[-1])
+    return rows, groups, owned, rows // topk, local, valid, ends[-1]
+
+
+def _row_weights(weights, rows, owned, local, valid):
+    """(rows_held, 1): each buffer row's routing weight."""
+    return _to_rows(weights.reshape(-1, 1), rows, owned,
+                    local.reshape(-1, 1), valid.reshape(-1, 1))
+
+
 def _pass_of_experts(rows_held, topk, activation, start, y, x, order, slot,
                      sizes, weights, wi_gate, wi_up, wo):
     """``y`` plus the held experts' part for the assignments ``start``
-    .. ``start + rows_held`` of ``order`` (held ones first, by expert;
-    ``slot`` is its inverse): the gated unit (``activation`` on the
-    gate) as grouped products over the ragged groups, weighted and
-    summed back by token; and how many rows some group owned."""
+    .. ``start + rows_held`` of ``order``: the gated unit (``activation``
+    on the gate) as grouped products over the ragged groups, weighted
+    and summed back by token; how many rows some group owned; and the
+    first two products ``(rows_held, F)``, the gate's before its
+    activation and the up product."""
     with jax.named_scope("dispatch"):
-        rows = lax.dynamic_slice(order, (start,), (rows_held,))
-        ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows_held)
-        groups = jnp.diff(ends, prepend=0)
-        owned = (jnp.arange(rows_held) < ends[-1])[:, None]
-        token = rows // topk
-        local = slot - start
-        valid = (local >= 0) & (local < ends[-1])
+        rows, groups, owned, token, local, valid, n_owned = _pass_tables(
+            rows_held, topk, start, order, slot, sizes)
         xs = _to_rows(x, token, owned, local, valid)
     with jax.named_scope("experts"):
-        gate = ACTIVATIONS[activation](lax.ragged_dot(xs, wi_gate, groups))
+        gate = lax.ragged_dot(xs, wi_gate, groups)
         up = lax.ragged_dot(xs, wi_up, groups)
-        out = lax.ragged_dot(gate * up, wo, groups)
+        out = lax.ragged_dot(ACTIVATIONS[activation](gate) * up, wo, groups)
     with jax.named_scope("combine"):
-        w = _to_rows(weights.reshape(-1, 1), rows, owned,
-                     local.reshape(-1, 1), valid.reshape(-1, 1))
+        w = _row_weights(weights, rows, owned, local, valid)
         # a row no group owns holds whatever the product left there
         out = (jnp.where(owned, out, 0) * w).astype(x.dtype)
-        return y + _from_rows(out, token, owned, local, valid), ends[-1]
+        return y + _from_rows(out, token, owned, local, valid), n_owned, \
+            (gate, up)
+
+
+def _product_gradients(lhs, rhs, groups, ct):
+    """``ct``'s gradients to the two operands of ``ragged_dot(lhs, rhs,
+    groups)``: two grouped products, and the product itself does not
+    run."""
+    return (jax.linear_transpose(
+                lambda lhs: lax.ragged_dot(lhs, rhs, groups), lhs)(ct)[0],
+            jax.linear_transpose(
+                lambda rhs: lax.ragged_dot(lhs, rhs, groups), rhs)(ct)[0])
+
+
+def _first_pass_gradients(rows_held, topk, activation, gate, up, x, order,
+                          slot, sizes, weights, wi_gate, wi_up, wo, ct):
+    """The gradients of pass 0 to ``(x, weights, wi_gate, wi_up, wo)``
+    from the two products it kept, ``ct`` the cotangent of ``y``: the
+    gated unit is element-wise over them, and the grouped products that
+    run are the six gradients alone.  The down product's output is not
+    formed again: it enters the routing weights' gradient only, ``dw =
+    <ct_row, w-less out_row>``, and with ``g = ct_rows Wo^T`` that is
+    ``<g, hidden>`` while the gated unit's cotangent is ``w g``: the
+    scalar ``w`` moved across a linear map."""
+    with jax.named_scope("dispatch"):
+        rows, groups, owned, token, local, valid, _ = _pass_tables(
+            rows_held, topk, 0, order, slot, sizes)
+        xs = _to_rows(x, token, owned, local, valid)
+    with jax.named_scope("combine"):
+        w = _row_weights(weights, rows, owned, local, valid)
+        ct_rows = _from_rows_bwd((token, owned, gate[:0]), ct)[0]
+    with jax.named_scope("experts"):
+        hidden, unit_vjp = jax.vjp(
+            lambda gate, up: ACTIVATIONS[activation](gate) * up, gate, up)
+        g, d_wo = _product_gradients((hidden * w).astype(hidden.dtype), wo,
+                                     groups, ct_rows)
+        d_gate, d_up = unit_vjp((g * w).astype(hidden.dtype))
+        d_xs_gate, d_wi_gate = _product_gradients(xs, wi_gate, groups, d_gate)
+        d_xs_up, d_wi_up = _product_gradients(xs, wi_up, groups, d_up)
+    with jax.named_scope("combine"):
+        # a row no group owns holds whatever the products left there
+        d_w = jnp.sum(jnp.where(owned, g.astype(jnp.float32)
+                                * hidden.astype(jnp.float32), 0),
+                      axis=1, keepdims=True).astype(weights.dtype)
+        d_weights = _to_rows_bwd(
+            (local.reshape(-1, 1), valid.reshape(-1, 1)), d_w)[0]
+    with jax.named_scope("dispatch"):
+        d_x = _to_rows_bwd((local, valid), d_xs_gate + d_xs_up)[0]
+    return d_x, d_weights.reshape(weights.shape), d_wi_gate, d_wi_up, d_wo
+
+
+def _held_passes(n_held, rows_held):
+    """The passes that ``n_held`` held assignments take through a buffer
+    of ``rows_held`` rows; the first always runs."""
+    return jnp.maximum(-(-n_held // rows_held), 1)
 
 
 @lru_cache(maxsize=None)
@@ -493,16 +582,23 @@ def _held_experts(rows_held, topk, activation):
     ``rows_held`` rows as the held assignments need, one where they
     fit.
 
-    The number of passes is known only on the device, so both
-    directions are loops of their own (a loop of a data-dependent
-    length has no transpose): the backward pass recomputes each pass
-    and takes its gradients (``jax.vjp``), summing further passes'
-    gradients in the cotangents' own dtype.  Both are loops under
-    ``vmap`` too (the one-device compiled step maps the loss over its
-    rank axis, and a grouped product has no batching rule for that)."""
+    The first pass always runs, so it stands outside the loop over the
+    others: its gate and up products are residuals of the rule (named
+    ``KEPT_PRODUCTS`` where the rule's forward hands them out, so that a
+    remat policy can keep them) and its gradients are taken from them
+    (``_first_pass_gradients``): six grouped products where recomputing
+    the pass takes nine.  The number of FURTHER passes is
+    known only on the device, so both directions loop over them on their
+    own (a loop of a data-dependent length has no transpose): the
+    backward pass recomputes each of those and takes its gradients
+    (``jax.vjp``), summing them in the cotangents' own dtype.  All of it
+    loops under ``vmap`` too (the one-device compiled step maps the loss
+    over its rank axis, and a grouped product has no batching rule for
+    that)."""
+    one_pass = partial(_pass_of_experts, rows_held, topk, activation)
 
     def passes(sizes):
-        return -(-jnp.sum(sizes) // rows_held)
+        return _held_passes(jnp.sum(sizes), rows_held)
 
     @jax.custom_batching.sequential_vmap
     def forward(x, order, slot, sizes, weights, wi_gate, wi_up, wo):
@@ -510,20 +606,20 @@ def _held_experts(rows_held, topk, activation):
 
         def one(i, carry):
             y, computed = carry
-            y, owned = _pass_of_experts(rows_held, topk, activation,
-                                        i * rows_held, y, *args)
-            return y, computed + owned
+            y, owned, products = one_pass(i * rows_held, y, *args)
+            return (y, computed + owned), products
 
-        first = one(0, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
-        return lax.fori_loop(1, passes(sizes), one, first)
+        first, kept = one(0, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+        return lax.fori_loop(1, passes(sizes),
+                             lambda i, carry: one(i, carry)[0], first), kept
 
     @jax.custom_batching.sequential_vmap
-    def backward(x, order, slot, sizes, weights, wi_gate, wi_up, wo, ct):
+    def backward(gate, up, x, order, slot, sizes, weights, wi_gate, wi_up,
+                 wo, ct):
         def gradients(start):
             _, vjp = jax.vjp(
-                lambda x, *rest: _pass_of_experts(
-                    rows_held, topk, activation, start, jnp.zeros_like(ct),
-                    x, order, slot, sizes, *rest)[0],
+                lambda x, *rest: one_pass(start, jnp.zeros_like(ct), x,
+                                          order, slot, sizes, *rest)[0],
                 x, weights, wi_gate, wi_up, wo)
             return vjp(ct)
 
@@ -531,17 +627,23 @@ def _held_experts(rows_held, topk, activation):
             1, passes(sizes),
             lambda i, total: jax.tree.map(
                 jnp.add, total, gradients(i * rows_held)),
-            gradients(0))
+            _first_pass_gradients(rows_held, topk, activation, gate, up, x,
+                                  order, slot, sizes, weights, wi_gate,
+                                  wi_up, wo, ct))
+
+    def fwd(*args):
+        out, kept = forward(*args)
+        with jax.named_scope("experts"):    # whose cost the keeping is
+            kept = tuple(map(checkpoint_name, kept, KEPT_PRODUCTS))
+        return out, (kept, args)
 
     @jax.custom_vjp
     def held_experts(*args):
-        return forward(*args)
+        return fwd(*args)[0]
 
-    def fwd(*args):
-        return forward(*args), args
-
-    def bwd(args, cts):
-        dx, dweights, dgate, dup, dwo = backward(*args, cts[0])
+    def bwd(residuals, cts):
+        kept, args = residuals
+        dx, dweights, dgate, dup, dwo = backward(*kept, *args, cts[0])
         return dx, None, None, None, dweights, dgate, dup, dwo
 
     held_experts.defvjp(fwd, bwd)
@@ -567,12 +669,17 @@ def routed_experts_apply(x, weights, idx, wi_gate, wi_up, wo, *, num_experts,
     the assignments are sorted by expert, held ones first, and the
     products run over ragged groups (``lax.ragged_dot``) through a
     buffer of ``held_buffer_rows`` rows, as many times as it takes
-    (once under a router near balance).
+    (once under a router near balance).  The first pass keeps its gate
+    and up products for the backward pass (``KEPT_PRODUCTS``), which
+    then runs that pass's six gradient products and none of its forward
+    again; every further pass is recomputed there (``_held_experts``).
 
     ``x`` (T, M) in the products' dtype.  Returns ``(y (T, M) float32,
-    counts)``; ``counts`` is int32 (3,): the assignments (T * topk),
-    those that fell on held experts, and those of them that were not
-    computed (0, or the layer is not dropless)."""
+    counts)``; ``counts`` is int32 (5,): the assignments (T * topk),
+    those that fell on held experts, those of them that were not
+    computed (0, or the layer is not dropless), the passes through the
+    buffer, and those of them beyond the first (0, or the backward pass
+    ran that many passes' forward again)."""
     T, topk = idx.shape
     held = wi_gate.shape[0]
     n = T * topk
@@ -595,7 +702,9 @@ def routed_experts_apply(x, weights, idx, wi_gate, wi_up, wo, *, num_experts,
     y, computed = _held_experts(rows_held, topk, activation)(
         x, order, slot, sizes, weights, wi_gate, wi_up, wo)
     n_held = jnp.sum(sizes)
-    return y, jnp.stack([jnp.int32(n), n_held, n_held - computed])
+    passes = _held_passes(n_held, rows_held)
+    return y, jnp.stack([jnp.int32(n), n_held, n_held - computed, passes,
+                         passes - 1])
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,8 @@ from chipbench.references import afmoe_train as reference  # noqa: E402
 from chipbench.references import precision  # noqa: E402
 from tools.sum_by_owner_probe import stood as _sum_as_it_stood  # noqa: E402
 
+from test_smallthinker import _grouped_products  # noqa: E402
+
 T, M, F, E, K = 48, 16, 8, 16, 4
 
 
@@ -141,9 +143,11 @@ def test_dropless_under_the_worst_routing(case, monkeypatch):
     x = jnp.abs(x) + 0.1                 # so x . direction > 0
     y, counts, _ = jax.jit(_apply)(x, p)
     want_held = T * K if case == "all_choices_held" else T
-    assert [int(c) for c in counts] == [T * K, want_held, 0]
     rows = moe.held_buffer_rows(T * K, held, E)
-    assert want_held > rows              # more than one pass
+    passes = -(-want_held // rows)
+    assert passes > 1       # the first from what it kept, the rest again
+    assert [int(c) for c in counts] == [T * K, want_held, 0, passes,
+                                        passes - 1]
     _close(y, _one_hot_form(x, p))
     grads = jax.grad(lambda x, p: jnp.sum(_apply(x, p)[0] ** 2),
                      argnums=(0, 1))(x, p)
@@ -359,7 +363,37 @@ def test_model_trains_through_the_compiled_step_as_the_reference(
     assert delta[MOE_DEVICE_SUMS[0]] == 2 * 64 * 4 * 4
     assert 0.15 < delta[MOE_DEVICE_SUMS[1]] / delta[MOE_DEVICE_SUMS[0]] < 0.35
     assert delta[MOE_DEVICE_SUMS[2]] == 0
+    # one pass a routed layer a step, none whose forward ran again
+    assert delta[MOE_DEVICE_SUMS[3]] == 2 * 4 and delta[MOE_DEVICE_SUMS[4]] == 0
     assert MOE_DEVICE_SUMS[1] in hvd.metrics()
+
+
+@pytest.mark.parametrize("policy, a_layer", [("dots_flash", 9), ("dots", 9),
+                                             ("full", 12)])
+def test_replay_of_a_normed_routed_layer_runs_no_grouped_product(
+        policy, a_layer):
+    """This model norms the routed layer's output, so its backward pass
+    reads that output again.  Wherever a remat policy keeps the dense
+    products it keeps the routed layer's by name (the first pass's gate
+    and up products, the layer's output): the gradient's program holds
+    the forward's three grouped products a layer and the six gradients,
+    none run again (15 a layer before the names: 3 + 3 replayed + 9);
+    ``full`` keeps nothing and replays the three."""
+    model = TransformerLM(_program_config(remat=True, remat_policy=policy))
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    variables = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t), tokens)
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params):
+        return jnp.sum(model.apply({"params": params, **state}, tokens))
+
+    def products(fn):       # outside the loops over further passes
+        return _grouped_products(
+            jax.make_jaxpr(fn)(variables["params"]).jaxpr)[0]
+
+    layers = products(loss) // 3
+    assert layers == 4 and products(jax.grad(loss)) == a_layer * layers
 
 
 def test_fp8_control_is_far_from_the_reference():
@@ -549,6 +583,8 @@ def test_device_sums_add_up_across_ranks_and_hook_the_stacks():
         assert np.isfinite(loss) and 0 < moved <= 0.003001
         assert sums[MOE_DEVICE_SUMS[0]].tolist() == [0, want]
         assert sums[MOE_DEVICE_SUMS[2]].tolist() == [0, 0]
+        assert sums[MOE_DEVICE_SUMS[3]].tolist() == [0, 3 * 4 * 4]
+        assert sums[MOE_DEVICE_SUMS[4]].tolist() == [0, 0]
         assert 0.15 * want < sums[MOE_DEVICE_SUMS[1]][1] < 0.35 * want
         assert reduced[1] == 3 * stacked and reduced[0] > reduced[1]
     # whoever read last saw all of it, once

@@ -164,6 +164,24 @@ def _lm436m_step_case():
     return program, state, ((1,) + tokens[0], tokens[1])
 
 
+def _lowered_cell_step(monkeypatch, cell, **config_changes):
+    """A benchmark cell's step program lowered for the described chip by
+    ``tools/described_step.py``, a command: it reads the checkout it
+    stands in and sets its process up for the chip's compiler, which a
+    test puts back."""
+    from horovod_tpu.ops import pallas_kernels
+    from tools import described_step
+
+    monkeypatch.chdir(REPO)
+    monkeypatch.setattr(pallas_kernels, "default_interpret",
+                        pallas_kernels.default_interpret)
+    limit = jax.config.jax_traceback_in_locations_limit
+    try:
+        return described_step.lowered_step(cell, **config_changes)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("policy, fits", [("full", True),
                                           ("dots_flash", False)])
@@ -173,36 +191,9 @@ def test_looped_cell_fits_under_full_remat_only(chip, policy, fits,
     4096 tokens, 9.8 GB of state) as the chip's compiler sees its step:
     it takes ``full`` remat and refuses ``dots_flash``, which every
     other LM cell runs under, for HBM (PERF.md section 6, PR 32)."""
-    import json
-
-    from chipbench.adapters import looped_lm_train as adapter
-    from chipbench.run import with_rehearsal
-    from horovod_tpu.ops import device_sums, pallas_kernels
-    from horovod_tpu.ops.xla_ops import MeshExecutor
-
-    def load(*parts):
-        with open(os.path.join(REPO, "chipbench", *parts)) as f:
-            return with_rehearsal(json.load(f), False)
-
-    config = dict(load("configs", "ouro-2.6b-l8.json"), remat_policy=policy)
-    workload = load("workloads", "s4k-b1-1chip.json")
-    monkeypatch.setattr(pallas_kernels, "default_interpret", lambda: False)
-    step = adapter.make_step(config, workload, False)
-    params, _ = adapter.param_shapes(config, workload)
-    state = jax.eval_shape(lambda p: {
-        "params": p, "opt_state": step.optimizer.init(p),
-        device_sums.STATE_KEY: device_sums.zeros(
-            device_sums.declared(step.loss_fn))}, params)
-    one_chip = SingleDeviceSharding(chip)
-
-    def shaped(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    batch = jax.ShapeDtypeStruct((1, 1, workload["seq_len"]), jnp.int32)
+    lowered = _lowered_cell_step(monkeypatch, "ouro-2.6b-s4k-1chip",
+                                 remat_policy=policy)
     with jax.enable_x64(False):
-        lowered = step._build(MeshExecutor([chip], 1)).lower(
-            shaped(state), shaped(batch))
         if not fits:
             with pytest.raises(Exception, match="Ran out of memory"):
                 lowered.compile()
@@ -210,6 +201,46 @@ def test_looped_cell_fits_under_full_remat_only(chip, policy, fits,
         text = lowered.compile().as_text()
     # two flash kernels a layer body, the body once a pass
     assert text.count("tpu_custom_call") >= 2
+
+
+# HBM a v5e's runtime lets a program use (the compiler's own limit is
+# 15.75 GiB = 16.9 GB)
+_CHIP_HBM_BYTES = 16.9e9
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell", ["smallthinker-21b-s8k-1chip",
+                                  "trinity-mini-s8k-1chip"])
+def test_routed_cells_run_no_grouped_product_twice_and_fit(chip, cell,
+                                                           monkeypatch):
+    """The two routed cells' steps as the chip's compiler sees them:
+    they compile (a step over the chip's memory is refused), the
+    program's own account of its memory stays under the chip's, and
+    outside the loops over further passes each of the 4 routed layers
+    holds the forward's three grouped products and the backward's six
+    gradients: the first pass keeps its gate and up products, and
+    Trinity's remat replay, which needs the layer's output for the norm
+    after it, finds that kept too.  Before: 3 + 9 a layer, Trinity's
+    three replayed among the 9."""
+    from horovod_tpu.telemetry.programs import program_tables
+
+    lowered = _lowered_cell_step(monkeypatch, cell)
+    with jax.enable_x64(False):
+        compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.generated_code_size_in_bytes < _CHIP_HBM_BYTES
+    # a layer's passes are a loop in a loop: ``sequential_vmap``'s over
+    # the one rank, and inside it the loop over FURTHER passes
+    further = re.compile(r"/moe/while/body/closed_call/while(/|$)")
+    paths = [path for name, path in program_tables(
+        compiled.as_text())["scopes"].items()
+        if name.startswith("ragged-dot-none")]
+    ran = [path for path in paths if not further.search(path)]
+    assert len(ran) == 4 * (3 + 6)
+    assert not any("rematted_computation" in path for path in paths)
+    # each further pass: 3 forward, 3 again + 6 backward
+    assert len(paths) - len(ran) == 4 * (3 + 9)
 
 
 @pytest.mark.parametrize("name", [

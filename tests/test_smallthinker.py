@@ -10,6 +10,7 @@ import math
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +22,7 @@ from horovod_tpu import telemetry
 from horovod_tpu.models import TransformerLM, make_fused_lm_loss
 from horovod_tpu.models.transformer import (
     MOE_AUX_LOSS_SUM, MOE_DEVICE_SUMS, MOE_MAX_EXPERT_TOKENS_SUM,
-    dense_causal_attention)
+    _with_remat, dense_causal_attention)
 from horovod_tpu.ops.pallas_kernels import flash_attention
 from horovod_tpu.parallel import moe
 
@@ -178,7 +179,7 @@ def test_dropless_under_a_router_skewed_onto_one_expert(monkeypatch):
         return (hidden @ p["wo"][0]) * weight[:, None]
 
     y, counts, _, _ = jax.jit(_apply)(x, p)
-    assert [int(c) for c in counts] == [T * K, T, 0]
+    assert [int(c) for c in counts[:3]] == [T * K, T, 0]
     assert T > moe.held_buffer_rows(T * K, 1, E)     # a second pass
     _close(y, dense(x, p))
     grads = jax.grad(lambda x, p: jnp.sum(_apply(x, p)[0] ** 2),
@@ -213,13 +214,14 @@ def _taken_routing(topk, skewed, seed):
             f32(d["held"], d["ffn"], d["width"]) / math.sqrt(d["ffn"]))
 
 
-def _per_token_form(x, weights, idx, wi_gate, wi_up, wo):
+def _per_token_form(x, weights, idx, wi_gate, wi_up, wo, activation="relu"):
     """Every held expert on every token, times the weight the taken
     routing gives it or zero."""
     weight = jnp.sum(jnp.where(
         idx[:, :, None] == jnp.arange(wi_gate.shape[0]),
         weights[:, :, None], 0.0), 1)
-    hidden = jax.nn.relu(jnp.einsum("tm,emf->etf", x, wi_gate)) \
+    hidden = moe.ACTIVATIONS[activation](
+        jnp.einsum("tm,emf->etf", x, wi_gate)) \
         * jnp.einsum("tm,emf->etf", x, wi_up)
     return jnp.einsum("etf,efm,te->tm", hidden, wo, weight)
 
@@ -258,8 +260,9 @@ def test_the_way_back_holds_its_values_and_gradients_in_every_form(
         assert gathers() == whole + 2
     y, counts = jax.jit(held_part)(*args)
     n_held = int(np.sum(args[2] < _WAY_BACK["held"]))
-    assert [int(c) for c in counts] == [n, n_held, 0]
-    assert -(-n_held // buffer_rows) == (3 if skewed else 1)
+    passes = 3 if skewed else 1      # and those the backward runs again
+    assert [int(c) for c in counts] == [n, n_held, 0, passes, passes - 1]
+    assert -(-n_held // buffer_rows) == passes
 
     def loss(fn):
         def scalar(x, weights, wi_gate, wi_up, wo):
@@ -283,6 +286,166 @@ def test_the_way_back_holds_its_values_and_gradients_in_every_form(
     jax.tree.map(lambda a, b: _close(
         a, b, 2e-4 * float(jnp.max(jnp.abs(b)))), got, want)
     assert all(float(jnp.max(jnp.abs(g))) > 0 for g in got[1])
+
+
+# the first pass hands its backward pass what it computed
+# (``moe._first_pass_gradients``); further passes are recomputed there
+
+def _held_part(activation, dtype=jnp.float32):
+    """``(x, weights, idx, wi_gate, wi_up, wo) -> y``: the layer on
+    ``_WAY_BACK``'s sizes with its products in ``dtype``."""
+    def held_part(x, weights, idx, *experts):
+        cast = lambda a: a.astype(dtype)    # noqa: E731
+        return moe.routed_experts_apply(
+            cast(x), weights, idx, *map(cast, experts),
+            num_experts=_WAY_BACK["experts"], activation=activation)[0]
+    return held_part
+
+
+def _gradients(fn, args):
+    """Of ``sum(fn(...) ** 2)`` to ``x``, the routing weights and the
+    three expert matrices."""
+    x, weights, idx, *experts = args
+    return jax.jit(jax.grad(
+        lambda x, weights, *experts: jnp.sum(
+            fn(x, weights, idx, *experts).astype(jnp.float32) ** 2),
+        argnums=tuple(range(5))))(x, weights, *experts)
+
+
+@pytest.fixture
+def first_pass_recomputed(monkeypatch):
+    """Inside: the layer as it stood before its first pass kept
+    anything, that pass differentiated as a whole like every other."""
+    def recomputed(rows_held, topk, activation, gate, up, x, order, slot,
+                   sizes, weights, wi_gate, wi_up, wo, ct):
+        _, vjp = jax.vjp(
+            lambda x, *rest: moe._pass_of_experts(
+                rows_held, topk, activation, 0, jnp.zeros_like(ct), x,
+                order, slot, sizes, *rest)[0],
+            x, weights, wi_gate, wi_up, wo)
+        return vjp(ct)
+
+    def switch():
+        monkeypatch.setattr(moe, "_first_pass_gradients", recomputed)
+        moe._held_experts.cache_clear()     # rules that close over it
+
+    yield switch
+    monkeypatch.undo()
+    moe._held_experts.cache_clear()
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gradients_from_the_kept_products_are_the_oracles(
+        dtype, activation, skewed, monkeypatch, first_pass_recomputed):
+    """The gradients of ``routed_experts_apply`` to ``x``, the routing
+    weights and the three expert matrices against the per-token form in
+    float32, for a routing that fits one pass (all of it from what the
+    pass kept) and one that needs three (the first from what it kept,
+    two recomputed): float32 to 1e-5 of the largest entry; bf16 products
+    within the 2e-2 that the layer differentiated pass by pass, as it
+    stood, keeps too."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    args = _taken_routing(6, skewed, seed=11)
+    want = _gradients(
+        lambda *a: _per_token_form(*a, activation=activation), args)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for as_it_stood in [False, True][:1 + (dtype == "bfloat16")]:
+        if as_it_stood:
+            first_pass_recomputed()
+        got = _gradients(_held_part(activation, jnp.dtype(dtype)), args)
+        for g, w in zip(got, want, strict=True):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            scale = float(jnp.max(jnp.abs(w)))
+            assert scale > 0
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=tol, atol=tol * scale)
+
+
+def _grouped_products(jaxpr, looped=False):
+    """``[outside, inside]``: the ``ragged_dot``s of a jaxpr and of
+    whatever it calls, outside and inside the ``while`` loops (the loop
+    over further passes, whose length only the device knows)."""
+    found = [0, 0]
+    for eqn in jaxpr.eqns:
+        found[looped] += eqn.primitive.name == "ragged_dot_general"
+        inner = looped or eqn.primitive.name == "while"
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found = [a + b for a, b in zip(
+                        found, _grouped_products(sub, inner))]
+    return found
+
+
+def test_a_one_pass_backward_runs_the_six_gradients_alone(
+        first_pass_recomputed):
+    """Counted in the jaxpr: the forward's three grouped products (and
+    three in the loop over further passes); in the backward pass the six
+    gradients where the pass recomputed takes nine, as each further pass
+    in its loop still does and as the first did before it kept its gate
+    and up products."""
+    args = _taken_routing(6, False, seed=12)
+    inexact = args[:2] + args[3:]
+
+    def counts():
+        fn = lambda x, w, *experts: _held_part("relu")(  # noqa: E731
+            x, w, args[2], *experts)
+        y, vjp = jax.vjp(fn, *inexact)
+        return (_grouped_products(jax.make_jaxpr(fn)(*inexact).jaxpr),
+                _grouped_products(jax.make_jaxpr(vjp)(y).jaxpr))
+
+    assert counts() == ([3, 3], [6, 9])
+    first_pass_recomputed()
+    assert counts() == ([3, 3], [9, 9])
+
+
+class _HeldPart(nn.Module):
+    """The layer as a module, for ``_with_remat``."""
+    @nn.compact
+    def __call__(self, *args):
+        return _held_part("silu")(*args)
+
+
+@pytest.mark.parametrize("ranks", [1, 2])
+@pytest.mark.parametrize("skewed", [False, True])
+@pytest.mark.parametrize("policy, products", [
+    ("dots_flash", 9), ("dots", 9), ("full", 12)])
+def test_remat_keeps_the_first_pass_products_where_it_keeps_the_dense(
+        policy, products, skewed, ranks, monkeypatch):
+    """Under ``nn.remat`` with each policy of ``_with_remat`` and under
+    the ``vmap`` over a rank axis of 1 and 2 that the one-device
+    compiled step wraps the loss in, one pass and three: the gradients
+    are the un-rematted layer's, and outside the loop over further
+    passes the program holds the forward's three products and the six
+    gradients wherever a policy keeps the dense products; ``full`` keeps
+    nothing and runs the rule's forward again, three products more."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    args = _taken_routing(6, skewed, seed=13)
+    idx = args[2]
+    inexact = [jnp.stack([a * (1 + r) for r in range(ranks)])
+               for a in args[:2]] + list(args[3:])
+    cfg = _program_config(remat=True, remat_policy=policy)
+    rematted = _with_remat(_HeldPart, cfg)()
+
+    def grads(fn):
+        return jax.vmap(jax.value_and_grad(
+            lambda x, w, *experts: jnp.sum(
+                fn(x, w, idx, *experts) ** 2), argnums=tuple(range(5))),
+            in_axes=(0, 0, None, None, None))
+
+    got_fn = grads(lambda *a: rematted.apply({}, *a))
+    want_fn = grads(_held_part("silu"))
+    got, want = jax.jit(got_fn)(*inexact), jax.jit(want_fn)(*inexact)
+    jax.tree.map(lambda a, b: _close(
+        a, b, 1e-6 * float(jnp.max(jnp.abs(b)))), got, want)
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in got[1])
+    assert _grouped_products(
+        jax.make_jaxpr(got_fn)(*inexact).jaxpr)[0] == products
+    assert _grouped_products(
+        jax.make_jaxpr(want_fn)(*inexact).jaxpr)[0] == 9
 
 
 def _parents_routed_layer(x, router_w, expert_bias, wi_gate, wi_up, wo, *,
@@ -329,7 +492,7 @@ def test_trinitys_layer_is_bit_equal_after_the_split():
         y, counts = moe.routed_experts_apply(
             x, w, idx, p["wi_gate"], p["wi_up"], p["wo"], num_experts=E,
             first_expert=8)
-        return y, counts, by_expert
+        return y, counts[:3], by_expert     # the passes came later
 
     def parent(x, p):
         return _parents_routed_layer(
@@ -476,6 +639,7 @@ def test_model_trains_through_the_compiled_step_as_the_reference(
     assert delta[MOE_DEVICE_SUMS[0]] == 2 * 64 * 6 * 4
     assert 0.1 < delta[MOE_DEVICE_SUMS[1]] / delta[MOE_DEVICE_SUMS[0]] < 0.4
     assert delta[MOE_DEVICE_SUMS[2]] == 0
+    assert delta[MOE_DEVICE_SUMS[3]] == 2 * 4 and delta[MOE_DEVICE_SUMS[4]] == 0
     want_aux = sum(float(v[0]) for seen in found["seen"]
                    for v in seen["aux_loss"].values())
     assert delta[MOE_AUX_LOSS_SUM] == pytest.approx(want_aux, abs=2 / 256)

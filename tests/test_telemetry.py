@@ -608,7 +608,8 @@ def test_recorded_routed_layer_gets_its_phases_back():
     """One routed layer of SmallThinker's step as the chip's compiler
     left it (tests/data): every kernel lands under ``moe`` in the phase
     it runs in, 3 in the forward and 9 in the backward, and none claims
-    a part of the layer it did not state."""
+    a part of the layer it did not state.  (The recording is the
+    reader's fixture, PR 36's program: today's backward runs 6.)"""
     from chipbench import scope_join, scope_time
     from horovod_tpu.telemetry.programs import program_tables
 

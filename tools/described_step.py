@@ -32,7 +32,7 @@ import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
-def lowered_step(cell):
+def lowered_step(cell, **config_changes):
     sys.path.insert(0, os.getcwd())
     import jax
     from jax.experimental import topologies
@@ -50,7 +50,8 @@ def lowered_step(cell):
     with open("BENCHMARK.json") as f:
         bench = json.load(f)
     entry = next(w for w in bench["workloads"] if w["name"] == cell)
-    config = load("configs", entry["config"] + ".json")
+    config = dict(load("configs", entry["config"] + ".json"),
+                  **config_changes)
     workload = load("workloads", entry["traffic"] + ".json")
     adapter = importlib.import_module(
         f"chipbench.adapters.{config['adapter']}")

@@ -31,6 +31,13 @@ TPU-first choices:
   (SmallThinker's ``config.json``).  Depth still costs no
   compile time: the leading dense layers are one scan, the others one
   scan over the periods of their pattern of kinds.
+* A third kind of layer, ``"mamba"`` (Granite-4.0-H's
+  ``granitemoehybrid``): a Mamba-2 state-space mixer where the other
+  kinds have attention (``models/mamba.py``: in-projection, a causal
+  depthwise convolution, the selective scan in its chunked form, a gated
+  norm, out-projection), in one period with attention layers; and four
+  muP-style multipliers on the embedding, the residual branches, the
+  attention scores and the logits.
 * A looped model (``total_ut_steps`` > 1, the key of Ouro's published
   ``config.json``): that stack applied several times over the same
   weights, an exit after every pass, and in ``make_fused_lm_loss`` the
@@ -48,6 +55,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import device_sums, grad_hook
+from .mamba import SSM_DEVICE_SUMS, Mamba2Mixer
+from .mamba import KEPT_OUTPUT as SSD_KEPT_OUTPUT
 
 
 @dataclass(frozen=True)
@@ -78,7 +87,8 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
     remat: bool = False       # jax.checkpoint each block (HBM <-> FLOPs)
-    remat_policy: str = "full"  # "full" recomputes everything;
+    remat_policy: str = "full"  # "full" recomputes everything (but a
+    # mamba layer's scan output, which every policy keeps by name);
     # "dots" saves matmul outputs (jax dots_with_no_batch_dims_saveable)
     # so the backward pass skips re-running the MXU work — ~400MB *
     # n_layers of HBM at (B=8, S=2048, d=1024) for the ~33% remat
@@ -100,7 +110,8 @@ class TransformerConfig:
     # ``config.json`` of such models; with ``layer_types`` None the
     # model is the one identical block above and these are refused
     layer_types: Optional[tuple] = None   # per layer "sliding_attention"
-    # (sees the last ``sliding_window`` positions) | "full_attention"
+    # (sees the last ``sliding_window`` positions) | "full_attention" |
+    # "mamba" (no attention: the state-space mixer below)
     sliding_window: Optional[int] = None
     rope_on_full_attention: bool = True   # False: rotary positions on
     # the sliding layers only
@@ -149,6 +160,31 @@ class TransformerConfig:
     # section 3).  The logits are the last pass's
     total_ut_steps: int = 1
     exit_entropy_coeff: float = 0.05
+    # -- a state-space layer ("mamba" in ``layer_types``): the Mamba-2
+    # mixer of models/mamba.py in attention's place, ``mamba_n_heads``
+    # heads of ``mamba_d_head`` with a (d_head, d_state) state each, B
+    # and C shared by the heads of a group; the keys are the published
+    # ``config.json``'s.  ``mamba_chunk_size`` is the chunk of the
+    # scan's chunked form: the program's to choose, it changes no
+    # result beyond rounding.  ``mamba_n_groups`` is kept as a published
+    # key: the benchmark's one such model has ONE group, more are held
+    # to the recurrence by the CPU tests alone
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # -- muP-style multipliers (Granite's ``config.json``), each a no-op
+    # at its default: the embedding times ``embedding_multiplier``, every
+    # layer's two branch outputs times ``residual_multiplier`` before
+    # the residual add, attention scores scaled by
+    # ``attention_multiplier`` in place of 1 / sqrt(head_dim), the
+    # logits divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -276,6 +312,13 @@ class Attention(nn.Module):
         if rope:
             q = apply_rope(q, angles)
             k = apply_rope(k, angles)
+        if cfg.attention_multiplier is not None:
+            # every attention inner (dense, flash, ring, the decode
+            # path) scales its scores by 1 / sqrt(D): q carries the
+            # rest, so none of them changes.  Granite's 1/64 at D = 64
+            # makes the factor 0.125, a power of two and exact in
+            # bfloat16; another value rounds q once more
+            q = q * (cfg.attention_multiplier * np.sqrt(D))
 
         def expand_kv(t):
             # training path only: each kv head serves H/KV query
@@ -433,15 +476,23 @@ class DecoderBlock(nn.Module):
     def __call__(self, x, angles, offset=0):
         cfg = self.cfg
         eps = cfg.rms_norm_eps
-        x = x + Attention(cfg, self.attention_fn, self.decode,
-                          name="attn")(
-            RMSNorm(cfg.dtype, eps, name="ln_attn")(x), angles, offset)
+        x = _residual(cfg, x, Attention(
+            cfg, self.attention_fn, self.decode, name="attn")(
+            RMSNorm(cfg.dtype, eps, name="ln_attn")(x), angles, offset))
         mlp = MoE(cfg, name="moe") if cfg.num_experts else \
             SwiGLU(cfg, name="mlp")
-        return x + mlp(RMSNorm(cfg.dtype, eps, name="ln_mlp")(x)), None
+        return _residual(
+            cfg, x, mlp(RMSNorm(cfg.dtype, eps, name="ln_mlp")(x))), None
 
 
-LAYER_TYPES = ("sliding_attention", "full_attention")
+def _residual(cfg, x, branch):
+    """``x + residual_multiplier * branch``."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
+LAYER_TYPES = ("sliding_attention", "full_attention", "mamba")
 
 #: the collection of what a routed layer's training loop keeps beside
 #: its parameters: ``expert_bias`` (num_experts,) a layer
@@ -465,15 +516,18 @@ MOE_AUX_LOSS_SUM = "horovod_moe_aux_loss_total"
 MOE_MAX_EXPERT_TOKENS_SUM = "horovod_moe_max_expert_tokens_total"
 
 
-def _layer_sums(cfg, counts=0, aux_loss=0, max_expert_tokens=0):
+def _layer_sums(cfg, counts=0, aux_loss=0, max_expert_tokens=0, ssm=0):
     """What a layer hands up the stack beside its output, summed over
     the layers on the way: the routed layer's counts
-    (``MOE_DEVICE_SUMS``) and, where the router has an auxiliary loss,
-    that loss and the busiest expert's tokens.  The defaults are the
-    sums' zero."""
+    (``MOE_DEVICE_SUMS``), where the router has an auxiliary loss, that
+    loss and the busiest expert's tokens, and in a model with mamba
+    layers what their scans processed (``SSM_DEVICE_SUMS``).  The
+    defaults are the sums' zero."""
     sums = {"counts": counts}
     if cfg.router_aux_loss_coef:
         sums.update(aux_loss=aux_loss, max_expert_tokens=max_expert_tokens)
+    if "mamba" in cfg.layer_types:
+        sums["ssm"] = ssm
     return sums
 
 
@@ -564,8 +618,9 @@ class RoutedExperts(nn.Module):
 
 class LayeredBlock(nn.Module):
     """One layer of a model whose layers differ: attention of this
-    layer's kind, then the dense SwiGLU or the routed experts (routed,
-    under ``router_before_attention``, from the layer's input)."""
+    layer's kind or the state-space mixer, then the dense SwiGLU or the
+    routed experts (routed, under ``router_before_attention``, from the
+    layer's input)."""
     cfg: TransformerConfig
     attention_fn: Callable
     layer_type: str
@@ -585,14 +640,20 @@ class LayeredBlock(nn.Module):
                 # issued ahead of attention, under the routed layer's
                 # own scope ``moe/route`` all the same
                 routing = moe(x, route_only=True)
-        # the device trace's path carries the layer's published kind
-        with jax.named_scope(self.layer_type):
-            h = Attention(cfg, self.attention_fn,
-                          layer_type=self.layer_type,
-                          name="attn")(norm("ln_attn")(x), angles)
+        ssm = None
+        if self.layer_type == "mamba":
+            # the module's name is the scope: ``attn`` would book the
+            # mixer to the attention's device time
+            h, ssm = Mamba2Mixer(cfg, name="mamba")(norm("ln_mamba")(x))
+        else:
+            # the device trace's path carries the layer's published kind
+            with jax.named_scope(self.layer_type):
+                h = Attention(cfg, self.attention_fn,
+                              layer_type=self.layer_type,
+                              name="attn")(norm("ln_attn")(x), angles)
         if cfg.sandwich_norm:
             h = norm("ln_post_attn")(h)
-        x = x + h
+        x = _residual(cfg, x, h)
         m = norm("ln_mlp")(x)
         if self.routed:
             f, sums = moe(m, routing)
@@ -600,9 +661,12 @@ class LayeredBlock(nn.Module):
             f, sums = SwiGLU(cfg, name="mlp")(m), _layer_sums(
                 cfg, jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32),
                 jnp.float32(0), jnp.int32(0))
+        if "ssm" in sums:
+            sums["ssm"] = jnp.zeros((len(SSM_DEVICE_SUMS),), jnp.int32) \
+                if ssm is None else ssm
         if cfg.sandwich_norm:
             f = norm("ln_post_mlp")(f)
-        return x + f, sums
+        return _residual(cfg, x, f), sums
 
 
 class LayerPeriod(nn.Module):
@@ -643,18 +707,23 @@ def _with_remat(block, cfg, prevent_cse=False):
     # (ops/pallas_kernels.py) nor a grouped product (parallel/moe.py)
     # is a dot, so without its name the backward replay runs it again
     routed = KEPT_PRODUCTS + (KEPT_OUTPUT,)
-    kept = {"dots": routed,
-            "dots_flash": ("flash_out", "flash_lse") + routed}
-    policy = None
-    if cfg.remat_policy in kept:
+    # a model with mamba layers keeps their scans' outputs under every
+    # policy, "full" too (67 MB a layer at 8,192 tokens for the second
+    # run of the scan); no other model's program sees the name
+    scans = (SSD_KEPT_OUTPUT,) if "mamba" in (cfg.layer_types or ()) else ()
+    kept = {"full": scans, "dots": routed + scans,
+            "dots_flash": ("flash_out", "flash_lse") + routed + scans}
+    if cfg.remat_policy not in kept:
+        raise ValueError(
+            f"remat_policy must be 'full', 'dots', or 'dots_flash', "
+            f"got {cfg.remat_policy!r}")
+    names = kept[cfg.remat_policy]
+    policy = jax.checkpoint_policies.save_only_these_names(*names) \
+        if names else None
+    if cfg.remat_policy != "full":
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names(
-                *kept[cfg.remat_policy]))
-    elif cfg.remat_policy != "full":
-        raise ValueError(
-            f"remat_policy must be 'full', 'dots', or "
-            f"'dots_flash', got {cfg.remat_policy!r}")
+            policy)
     return nn.remat(block, prevent_cse=prevent_cse,
                     static_argnums=(), policy=policy)
 
@@ -739,6 +808,10 @@ def _layered(module, x, angles):
     if "sliding_attention" in kinds and not cfg.sliding_window:
         raise ValueError("sliding_attention layers need "
                          "sliding_window")
+    if "mamba" in kinds and cfg.total_ut_steps > 1:
+        raise ValueError(
+            "a looped model (total_ut_steps > 1) has no mamba layers: "
+            "the device's sums count one pass")
     if cfg.num_experts:
         _check_router(cfg)
     groups = [("dense_layers", kinds[:lead], False),
@@ -809,11 +882,16 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         if cfg.total_ut_steps > 1:
             return loop_device_sums(cfg.total_ut_steps)
-        if cfg.layer_types is None or not self.routed_layers:
+        if cfg.layer_types is None:
             return ()
-        return MOE_DEVICE_SUMS + (
-            (MOE_AUX_LOSS_SUM, MOE_MAX_EXPERT_TOKENS_SUM)
-            if cfg.router_aux_loss_coef else ())
+        names = ()
+        if self.routed_layers:
+            names += MOE_DEVICE_SUMS + (
+                (MOE_AUX_LOSS_SUM, MOE_MAX_EXPERT_TOKENS_SUM)
+                if cfg.router_aux_loss_coef else ())
+        if "mamba" in cfg.layer_types:
+            names += SSM_DEVICE_SUMS
+        return names
 
     @property
     def routed_layers(self):
@@ -855,6 +933,8 @@ class TransformerLM(nn.Module):
             x = emb[tokens]
             if cfg.mup_enabled:
                 x = x * np.sqrt(cfg.d_model).astype(np.float32)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * np.float32(cfg.embedding_multiplier)
             x = x.astype(cfg.dtype)
         angles = jnp.asarray(
             rope_angles(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta))
@@ -885,8 +965,11 @@ class TransformerLM(nn.Module):
             x = states[-1]
         elif cfg.layer_types is not None:
             x, sums = _layered(self, x, angles)
-            if self.device_sums:
+            if self.routed_layers:
                 for name, value in zip(MOE_DEVICE_SUMS, sums["counts"]):
+                    device_sums.add(name, value)
+            if "ssm" in sums:
+                for name, value in zip(SSM_DEVICE_SUMS, sums["ssm"]):
                     device_sums.add(name, value)
             if cfg.router_aux_loss_coef and self.routed_layers:
                 device_sums.add_fraction(MOE_AUX_LOSS_SUM, sums["aux_loss"])
@@ -922,6 +1005,8 @@ class TransformerLM(nn.Module):
             logits = jnp.einsum("bsm,vm->bsv", x,
                                 head.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
         return logits
 
 
@@ -1025,14 +1110,17 @@ def _seq_unchunk(a):
     return a.reshape(a.shape[0], -1, *a.shape[3:])
 
 
-def _chunk_ce(xc, tc, embd):
+def _chunk_ce(xc, tc, embd, inv_scale):
     """One sequence chunk through the head: ``(e, sumexp, nll)`` with
     ``e = exp(logits - rowmax)`` (B, C, V), its row sums and the
-    per-token ``logsumexp - target logit``, all float32."""
+    per-token ``logsumexp - target logit``, all float32; the logits are
+    the product times ``inv_scale``."""
     # (B, C, M) @ (M, V): f32 accumulation on bf16 operands, same
     # numerics as the unfused logits einsum
     logits = jnp.einsum("bcm,vm->bcv", xc, embd,
                         preferred_element_type=jnp.float32)
+    if inv_scale != 1.0:
+        logits = logits * np.float32(inv_scale)
     top = jnp.max(logits, axis=-1)
     e = jnp.exp(logits - top[..., None])
     sumexp = jnp.sum(e, axis=-1)
@@ -1040,19 +1128,20 @@ def _chunk_ce(xc, tc, embd):
     return e, sumexp, (jnp.log(sumexp) + top) - tgt
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _mean_ce(x, embd, targets, weights, denom, n_chunks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _mean_ce(x, embd, targets, weights, denom, n_chunks, inv_scale):
     """``sum(nll * weights) / denom`` over the sequence chunks."""
     def body(total, inp):
         xc, tc, wc = inp
-        return total + jnp.sum(_chunk_ce(xc, tc, embd)[2] * wc), None
+        return total + jnp.sum(
+            _chunk_ce(xc, tc, embd, inv_scale)[2] * wc), None
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
                             _seq_chunks(n_chunks, x, targets, weights))
     return total / denom
 
 
-def _mean_ce_fwd(x, embd, targets, weights, denom, n_chunks):
+def _mean_ce_fwd(x, embd, targets, weights, denom, n_chunks, inv_scale):
     """The loss AND its gradients, each chunk's formed from the logits
     the loss was read from: ``(softmax - onehot) * weight / denom``
     against the head gives the chunk's dx, against the chunk of ``x``
@@ -1066,8 +1155,12 @@ def _mean_ce_fwd(x, embd, targets, weights, denom, n_chunks):
         # costs each a slower tiling on the chip (3.2 ms of the head's
         # 85.5 at (4, 4096, 4096) x 32000; PERF.md section 6, PR 33)
         xc = jax.lax.optimization_barrier(xc)
-        e, sumexp, nll = _chunk_ce(xc, tc, embd)
+        e, sumexp, nll = _chunk_ce(xc, tc, embd, inv_scale)
         scale = inv * wc
+        if inv_scale != 1.0:
+            # with respect to the product: the scaled logits' gradient
+            # times the scale
+            scale = scale * np.float32(inv_scale)
         onehot = jax.lax.broadcasted_iota(
             jnp.int32, e.shape, e.ndim - 1) == tc[..., None]
         dlogits = e * (scale / sumexp)[..., None] - jnp.where(
@@ -1094,7 +1187,7 @@ def _mean_ce_fwd(x, embd, targets, weights, denom, n_chunks):
         -total * jax.lax.integer_pow(denom, -2))
 
 
-def _mean_ce_bwd(n_chunks, res, g):
+def _mean_ce_bwd(n_chunks, inv_scale, res, g):
     # the step's cotangent is the literal 1, which the compiler drops:
     # no sweep over the head's gradient is spent on a scale
     dx, demb, dweights, ddenom = res
@@ -1105,7 +1198,8 @@ def _mean_ce_bwd(n_chunks, res, g):
 _mean_ce.defvjp(_mean_ce_fwd, _mean_ce_bwd)
 
 
-def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
+def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None,
+                    logits_scaling=1.0):
     """Cross-entropy fused with the logits projection, chunked over the
     sequence so the full (B, S, V) logits tensor is never materialized.
 
@@ -1139,6 +1233,9 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
         padding / the final position when feeding unshifted batches
         (``targets=roll(tokens)``, ``weights[:, -1]=0``); the mean is
         over the weight sum.
+      logits_scaling: the logits are the projection divided by this
+        (Granite's ``config.json``), in the loss and, inside the same
+        rule, in the gradients it forms.
     """
     b, s, m = x.shape
     if s % n_chunks:
@@ -1150,7 +1247,8 @@ def chunked_lm_loss(x, emb, targets, n_chunks=8, weights=None):
         denom = jnp.sum(weights)
         # all-padding batches (weight sum 0) yield loss 0, not 0/0 = NaN
         return _mean_ce(x, emb.astype(x.dtype), targets, weights,
-                        jnp.where(denom > 0, denom, 1.0), n_chunks)
+                        jnp.where(denom > 0, denom, 1.0), n_chunks,
+                        1.0 / logits_scaling)
 
 
 def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
@@ -1201,8 +1299,10 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
         targets = jnp.roll(tokens, -1, axis=1)
         w = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
         def cross_entropy(x):
-            return chunked_lm_loss(x, emb, targets, n_chunks=n_chunks,
-                                   weights=w)
+            # a plain callable (the pipelined apply) has no cfg
+            return chunked_lm_loss(
+                x, emb, targets, n_chunks=n_chunks, weights=w,
+                logits_scaling=getattr(cfg, "logits_scaling", 1.0))
 
         if aux_coef:
             # a loss term born inside the scanned, rematerialised layers
@@ -1230,7 +1330,8 @@ def make_fused_lm_loss(model: "TransformerLM", n_chunks: int = 16,
         expected = chunked_lm_loss(
             states.reshape((-1,) + states.shape[2:]), emb,
             jnp.tile(targets, (passes, 1)), n_chunks=n_chunks,
-            weights=weighted.reshape(-1, w.shape[-1]))
+            weights=weighted.reshape(-1, w.shape[-1]),
+            logits_scaling=cfg.logits_scaling)
         return expected - cfg.exit_entropy_coeff * bonus
 
     if with_state:

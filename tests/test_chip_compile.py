@@ -65,6 +65,9 @@ _QKV_S8K = [((2, 8192, 32, 128), jnp.bfloat16)] * 3
 # ... SmallThinker's 28 query heads (7 to each of 4 kv heads, expanded
 # as the model hands them over: 56 rows of heads, no power of two)
 _QKV_S8K_H28 = [((2, 8192, 28, 128), jnp.bfloat16)] * 3
+# ... Granite-4.0-H's one attention layer: 1 x 8192 tokens, 32 heads of
+# 64 (half a lane tile in the transposed score layout)
+_QKV_S8K_D64 = [((1, 8192, 32, 64), jnp.bfloat16)] * 3
 # ... and its s4k cells (4 x 4096 tokens; the 4096 window does not bind)
 _QKV_S4K = [((4, 4096, 32, 128), jnp.bfloat16)] * 3
 _N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
@@ -96,6 +99,8 @@ def _kernel_cases():
         "flash_fwd_s8k_w2048": (_flash(window=2048), _QKV_S8K, 1),
         "flash_fwd_s8k_h28": (_flash(), _QKV_S8K_H28, 1),
         "flash_fwd_s8k_h28_w4096": (_flash(window=4096), _QKV_S8K_H28, 1),
+        "flash_fwd_s8k_d64": (_flash(), _QKV_S8K_D64, 1),
+        "flash_bwd_s8k_d64": (_grad_of_sum(_flash(), 3), _QKV_S8K_D64, 2),
         "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 2),
         "flash_window_bwd": (
             _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 2),
@@ -243,12 +248,31 @@ def test_routed_cells_run_no_grouped_product_twice_and_fit(chip, cell,
     assert len(paths) - len(ran) == 4 * (3 + 9)
 
 
+@pytest.mark.slow
+def test_hybrid_cell_fits_with_its_scans_in_plain_operations(
+        chip, monkeypatch):
+    """Granite-4.0-H Micro's cell (nine Mamba-2 layers to one
+    attention layer, 798M parameters, 1 x 8,192 tokens, remat that
+    keeps the scans' outputs alone) compiles for the chip with room to
+    spare, and its only kernels are the attention layer's: the forward,
+    the forward again under remat, one backward."""
+    with jax.enable_x64(False):
+        compiled = _lowered_cell_step(
+            monkeypatch, "granite-4.0-h-micro-s8k-1chip").compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pytest.approx(12 * 797850560, rel=1e-3)
+    assert mem.alias_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.generated_code_size_in_bytes < 14e9
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
 @pytest.mark.parametrize("name", [
     "flash_fwd", "flash_fwd_s4k", "flash_fwd_s8k", "flash_fwd_s8k_w4096",
     "flash_fwd_s8k_w2048", "flash_fwd_s8k_h28", "flash_fwd_s8k_h28_w4096",
     "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
     "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "flash_bwd_s8k_h28",
-    "flash_bwd_s8k_h28_w4096", "quantize_int8",
+    "flash_bwd_s8k_h28_w4096", "flash_fwd_s8k_d64", "flash_bwd_s8k_d64",
+    "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
     "fused_scale_cast",
     pytest.param("lm436m_step", marks=pytest.mark.slow)])

@@ -22,7 +22,16 @@ chunks carries the state across their boundaries; a last product reads
 the carried state.  Every cumulative sum and decay is float32 and every
 exponent is <= 0; the products take operands in the activation dtype
 and accumulate in float32.  The chunk length changes no result beyond
-rounding.  Plain XLA operations: autodiff gives the backward pass.
+rounding.
+
+Two implementations of that form, chosen by shape and dtype alone
+(``ops/ssd_kernels.kernel_takes``): where a chunk, the state and a block
+of heads fill whole tiles, a pair of Pallas kernels under one
+``custom_vjp`` (``ssd_fwd`` sweeps a row's chunks with the state in VMEM
+scratch and the decay tile never in HBM; ``ssd_bwd`` sweeps them in
+reverse: the backward is the rule's own, not autodiff's); elsewhere (the
+CPU tests' tiny widths) ``ssd_chunked`` below, plain XLA operations with
+autodiff's backward, which is also what the kernels are tested against.
 
 Scopes (docs/observability.md "The compiled step"): the module is named
 ``mamba``; inside it ``in_proj``, ``conv``, ``ssd`` (everything between
@@ -37,14 +46,20 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import ssd_kernels
+
 #: the sums the state-space layers make on the device, a step call
 #: (``ops/device_sums.py``): the tokens and the chunks their scans
-#: processed, over the layers; their ratio is the chunk length that ran
-SSM_DEVICE_SUMS = ("horovod_ssm_tokens_total", "horovod_ssm_chunks_total")
+#: processed, over the layers (their ratio is the chunk length that
+#: ran), and the chunks of those that went through the kernel pair
+SSM_DEVICE_SUMS = ("horovod_ssm_tokens_total", "horovod_ssm_chunks_total",
+                   "horovod_ssm_kernel_chunks_total")
 
-#: the name the scan's output is checkpointed under: every remat policy
-#: of a model with mamba layers keeps it (``transformer._with_remat``)
+#: the names a scan's output and (where the kernel pair runs) the states
+#: its chunks start from are checkpointed under: every remat policy of a
+#: model with mamba layers keeps them (``transformer._with_remat``)
 KEPT_OUTPUT = "ssd_out"
+KEPT = (KEPT_OUTPUT, ssd_kernels.KEPT_STATES)
 
 
 def ssd_chunked(x, dt, a, b, c, *, chunk):
@@ -181,8 +196,8 @@ class GatedRMSNorm(nn.Module):
 
 class Mamba2Mixer(nn.Module):
     """(B, S, d_model) -> ``(out (B, S, d_model), counts)``; ``counts``
-    int32 (2,) are the tokens and the chunks the scan processed
-    (``SSM_DEVICE_SUMS``)."""
+    int32 (3,) are the tokens and the chunks the scan processed and the
+    chunks of those the kernel pair took (``SSM_DEVICE_SUMS``)."""
     cfg: Any      # a TransformerConfig
 
     @nn.compact
@@ -211,14 +226,21 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssd"):
             x, b, c = jnp.split(xbc, (inner, inner + bc), axis=-1)
             x = x.reshape(rows, seq, heads, width)
-            y, chunks = ssd_chunked(
-                x, jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
-                -jnp.exp(a_log), b.reshape(rows, seq, groups, state),
-                c.reshape(rows, seq, groups, state),
-                chunk=cfg.mamba_chunk_size)
-            y = y + skip[:, None] * x.astype(jnp.float32)
+            b = b.reshape(rows, seq, groups, state)
+            c = c.reshape(rows, seq, groups, state)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            kernels = ssd_kernels.kernel_takes(
+                x.shape, b.shape, cfg.mamba_chunk_size, cfg.dtype)
+            if kernels:
+                y, chunks = ssd_kernels.ssd_scan(
+                    x, dt, -jnp.exp(a_log), b, c, skip,
+                    chunk=cfg.mamba_chunk_size)
+            else:
+                y, chunks = ssd_chunked(x, dt, -jnp.exp(a_log), b, c,
+                                        chunk=cfg.mamba_chunk_size)
+                y = y + skip[:, None] * x.astype(jnp.float32)
             y = checkpoint_name(y.reshape(rows, seq, inner).astype(cfg.dtype),
                                 KEPT_OUTPUT)
         y = GatedRMSNorm(cfg.dtype, cfg.rms_norm_eps, name="gate_norm")(y, z)
         return dense(cfg.d_model, "out_proj")(y), jnp.array(
-            [rows * seq, rows * chunks], jnp.int32)
+            [rows * seq, rows * chunks, rows * chunks * kernels], jnp.int32)

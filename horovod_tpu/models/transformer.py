@@ -56,7 +56,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import device_sums, grad_hook
 from .mamba import SSM_DEVICE_SUMS, Mamba2Mixer
-from .mamba import KEPT_OUTPUT as SSD_KEPT_OUTPUT
+from .mamba import KEPT as SSD_KEPT
 
 
 @dataclass(frozen=True)
@@ -88,7 +88,7 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     remat: bool = False       # jax.checkpoint each block (HBM <-> FLOPs)
     remat_policy: str = "full"  # "full" recomputes everything (but a
-    # mamba layer's scan output, which every policy keeps by name);
+    # mamba layer's scan output and states, which every policy keeps);
     # "dots" saves matmul outputs (jax dots_with_no_batch_dims_saveable)
     # so the backward pass skips re-running the MXU work — ~400MB *
     # n_layers of HBM at (B=8, S=2048, d=1024) for the ~33% remat
@@ -707,10 +707,10 @@ def _with_remat(block, cfg, prevent_cse=False):
     # (ops/pallas_kernels.py) nor a grouped product (parallel/moe.py)
     # is a dot, so without its name the backward replay runs it again
     routed = KEPT_PRODUCTS + (KEPT_OUTPUT,)
-    # a model with mamba layers keeps their scans' outputs under every
-    # policy, "full" too (67 MB a layer at 8,192 tokens for the second
-    # run of the scan); no other model's program sees the name
-    scans = (SSD_KEPT_OUTPUT,) if "mamba" in (cfg.layer_types or ()) else ()
+    # a model with mamba layers keeps their scans' outputs and chunk
+    # states under every policy, "full" too (2 x 67 MB a layer at 8,192
+    # tokens: the replay runs no scan); no other program sees the names
+    scans = SSD_KEPT if "mamba" in (cfg.layer_types or ()) else ()
     kept = {"full": scans, "dots": routed + scans,
             "dots_flash": ("flash_out", "flash_lse") + routed + scans}
     if cfg.remat_policy not in kept:

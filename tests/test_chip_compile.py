@@ -70,6 +70,16 @@ _QKV_S8K_H28 = [((2, 8192, 28, 128), jnp.bfloat16)] * 3
 _QKV_S8K_D64 = [((1, 8192, 32, 64), jnp.bfloat16)] * 3
 # ... and its s4k cells (4 x 4096 tokens; the 4096 window does not bind)
 _QKV_S4K = [((4, 4096, 32, 128), jnp.bfloat16)] * 3
+
+
+def _ssd_shapes(seq, dtype=jnp.bfloat16, heads=64, groups=1):
+    """A state-space layer's scan (x, dt, A, B, C and the D skip) at
+    Granite-4.0-H's widths: heads of 64, groups of 128 states."""
+    return [((1, seq, heads, 64), dtype), ((1, seq, heads), jnp.float32),
+            ((heads,), jnp.float32), ((1, seq, groups, 128), dtype),
+            ((1, seq, groups, 128), dtype), ((heads,), jnp.float32)]
+
+
 _N = 1 << 20              # quantize codecs: 4096 scale blocks of 256
 
 
@@ -79,6 +89,19 @@ def _flash(**kw):
     def fwd(q, k, v):
         return flash_attention(q, k, v, interpret=False, **kw)
     return fwd
+
+
+def _ssd(*args):
+    from horovod_tpu.ops.ssd_kernels import ssd_scan
+
+    return ssd_scan(*args, chunk=256, interpret=False)[0]
+
+
+def _kernel_names(text):
+    """The names of a compiled program's Mosaic kernels, one a call."""
+    return re.findall(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)
 
 
 def _grad_of_sum(fn, n_args):
@@ -114,6 +137,19 @@ def _kernel_cases():
         "flash_bwd_s8k_h28": (_grad_of_sum(_flash(), 3), _QKV_S8K_H28, 2),
         "flash_bwd_s8k_h28_w4096": (
             _grad_of_sum(_flash(window=4096), 3), _QKV_S8K_H28, 2),
+        # the selective scan's pair, the forward sweep alone and with
+        # the reverse sweep (the gradient of all six operands), at
+        # Granite's cell (1 x 8192 tokens), at its rehearsal (one row
+        # of 128: ONE chunk of 128), and at what else ``kernel_takes``
+        # says yes to: float32 activations, two groups of B and C
+        "ssd_fwd_s8k": (_ssd, _ssd_shapes(8192), 1),
+        "ssd_bwd_s8k": (_grad_of_sum(_ssd, 6), _ssd_shapes(8192), 2),
+        "ssd_fwd_s128": (_ssd, _ssd_shapes(128), 1),
+        "ssd_bwd_s128": (_grad_of_sum(_ssd, 6), _ssd_shapes(128), 2),
+        "ssd_bwd_s8k_f32": (
+            _grad_of_sum(_ssd, 6), _ssd_shapes(8192, jnp.float32), 2),
+        "ssd_bwd_s1k_g2": (
+            _grad_of_sum(_ssd, 6), _ssd_shapes(1024, heads=16, groups=2), 2),
         "quantize_int8": (
             lambda x: pk.quantize_blockwise(x, interpret=False),
             [((_N,), jnp.float32)], 1),
@@ -249,13 +285,13 @@ def test_routed_cells_run_no_grouped_product_twice_and_fit(chip, cell,
 
 
 @pytest.mark.slow
-def test_hybrid_cell_fits_with_its_scans_in_plain_operations(
+def test_hybrid_cell_fits_with_its_scans_in_their_kernels(
         chip, monkeypatch):
     """Granite-4.0-H Micro's cell (nine Mamba-2 layers to one
     attention layer, 798M parameters, 1 x 8,192 tokens, remat that
-    keeps the scans' outputs alone) compiles for the chip with room to
-    spare, and its only kernels are the attention layer's: the forward,
-    the forward again under remat, one backward."""
+    keeps the scans' outputs and chunk states alone) compiles for the
+    chip with room to spare; its kernels are the attention layer's and
+    the scans' and no scan runs twice."""
     with jax.enable_x64(False):
         compiled = _lowered_cell_step(
             monkeypatch, "granite-4.0-h-micro-s8k-1chip").compile()
@@ -263,7 +299,15 @@ def test_hybrid_cell_fits_with_its_scans_in_plain_operations(
     assert mem.alias_size_in_bytes == pytest.approx(12 * 797850560, rel=1e-3)
     assert mem.alias_size_in_bytes + mem.temp_size_in_bytes \
         + mem.generated_code_size_in_bytes < 14e9
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    text = compiled.as_text()
+    # the attention layer's 3 (the forward, the forward again under
+    # remat, one backward) + 2 a mamba layer (``ssd_fwd`` once: the
+    # replay needs only what the policy kept by name; ``ssd_bwd``)
+    assert text.count("tpu_custom_call") == 3 + 2 * 9
+    kernels = _kernel_names(text)
+    assert sorted(set(kernels)) == ["flash_dkv", "flash_fwd", "ssd_bwd",
+                                    "ssd_fwd"]
+    assert kernels.count("ssd_fwd") == kernels.count("ssd_bwd") == 9
 
 
 @pytest.mark.parametrize("name", [
@@ -272,6 +316,8 @@ def test_hybrid_cell_fits_with_its_scans_in_plain_operations(
     "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
     "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "flash_bwd_s8k_h28",
     "flash_bwd_s8k_h28_w4096", "flash_fwd_s8k_d64", "flash_bwd_s8k_d64",
+    "ssd_fwd_s8k", "ssd_bwd_s8k", "ssd_fwd_s128", "ssd_bwd_s128",
+    "ssd_bwd_s8k_f32", "ssd_bwd_s1k_g2",
     "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
     "fused_scale_cast",
@@ -297,14 +343,15 @@ def test_chip_compiler_takes(chip, name):
     text = compiled.as_text()
     calls = text.count("tpu_custom_call")
     assert calls >= min_calls
-    if name.startswith("flash_"):
+    if name.startswith(("flash_", "ssd_")):
         # the forward, and ONE backward kernel: no more, under the
-        # names the benchmark's readers know (chipbench/scope_join.py)
+        # names the benchmark's readers know (chipbench/scope_join.py;
+        # the scan's are read by their scope, ``mamba`` + ``ssd``)
         assert calls == min_calls
-        kernels = re.findall(
-            r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
-            text)
-        assert sorted(kernels) == ["flash_dkv", "flash_fwd"][-min_calls:]
+        assert sorted(_kernel_names(text)) == (
+            ["flash_dkv", "flash_fwd"][-min_calls:]
+            if name.startswith("flash_")
+            else ["ssd_bwd", "ssd_fwd"][-min_calls:])
     if name == "lm436m_step":
         mem = compiled.memory_analysis()
         # donated state in, the same bytes out, and the step's

@@ -31,7 +31,10 @@ def param_shapes(config, workload):
     return variables["params"], variables["batch_stats"]
 
 
-def loss_fn(config):
+def loss_fn(config, workload, rehearse):
+    """``(params, batch_stats, batch) -> (loss, new batch_stats)``, the
+    adapters' shared signature (the mix and the rehearsal change
+    nothing of it)."""
     import jax
     import jax.numpy as jnp
 
@@ -49,17 +52,20 @@ def loss_fn(config):
     return loss
 
 
-def make_step(config, workload, rehearse):
+def optimizer(workload):
     import optax
-
-    import horovod_tpu as hvd
 
     opt = workload["optimizer"]
     if opt["name"] != "sgd":
         raise ValueError(f"cnn_train trains with sgd, not {opt['name']!r}")
+    return optax.sgd(opt["learning_rate"], momentum=opt["momentum"])
+
+
+def make_step(config, workload, rehearse):
+    import horovod_tpu as hvd
+
     return hvd.make_compiled_train_step(
-        loss_fn(config),
-        optax.sgd(opt["learning_rate"], momentum=opt["momentum"]),
+        loss_fn(config, workload, rehearse), optimizer(workload),
         has_aux=True)
 
 

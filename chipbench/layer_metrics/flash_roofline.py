@@ -1,14 +1,15 @@
-"""The flash-attention kernels (forward, dq, dkv) against the chip's
-bf16 peak: the attention FLOPs the traced steps need, from shapes, over
-the kernels' summed device time, over the peak.  The kernels are
-compute-bound: at these sizes the FLOP bound is above the HBM bound.
+"""The flash-attention kernels (the forward, and the one backward kernel
+that yields dq, dk and dv) against the chip's bf16 peak: the attention
+FLOPs the traced steps need, from shapes, over the kernels' summed
+device time, over the peak.  The kernels are compute-bound: at these
+sizes the FLOP bound is above the HBM bound.
 
 The trace names a Pallas kernel after the flax module it sits in
 (``attn.21``), not after the kernel, so this takes every Pallas kernel
-of the step: today those are the three flash kernels and nothing else
-(chip_smoke.py counts them).  A PR that adds another kernel to an LM
-step has to give its kernels names the trace shows, and a reader of
-their own."""
+of the step: in the cells that list this reader those are the two
+flash kernels and nothing else (chip_smoke.py counts them).  A PR that
+adds another kernel to an LM step has to give its kernels names the
+trace shows, and a reader of their own."""
 
 from chipbench import trace_reduce
 

@@ -1,8 +1,9 @@
 """``flash_roofline`` for a step that may hold other Pallas kernels: the
 attention FLOPs the traced steps need, from shapes and each layer's mask
 (the adapter's ``attention_flops_per_sample``), over the summed device
-time of the kernels under the scopes ``flash_fwd`` / ``flash_dq`` /
-``flash_dkv`` (``chipbench/scope_join.py``), over the chip's bf16 peak.
+time of the kernels under the scopes ``flash_fwd`` and ``flash_dkv``
+(the forward and the one backward kernel: ``scope_join.KERNELS``), over
+the chip's bf16 peak.
 The kernels are compute-bound."""
 
 from chipbench import scope_join

@@ -5,11 +5,14 @@ cross-entropy.
 
 Straightforward ``jax.numpy`` and ``lax.conv_general_dilated``: no
 bfloat16, no kernel, nothing imported from the program.  It makes its
-own weights from the key and follows the first steps of training on the
-whole batch (BatchNorm couples the rows), one bottleneck block
-recomputed at a time so that it fits, the like blocks of a stage under
-one ``lax.scan``.  The running statistics, which no
-training step reads, are not followed.
+own weights from the key and follows the first steps of training as
+the mix's ranks take them: each rank's rows are a batch of their own
+(BatchNorm couples the rows of one rank and no others: Horovod trains
+without a synchronised BatchNorm), the loss and the gradient are the
+means over the ranks.  One bottleneck block is recomputed at a time so
+that it fits, the like blocks of a stage under one ``lax.scan``, one
+rank's rows after another's.  The running statistics, which no training
+step reads, are not followed.
 """
 
 import functools
@@ -139,19 +142,38 @@ def batch_loss(config, products, params, images, labels):
     return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
 
 
-def follow(config, workload, key, batch, steps, mode="float32"):
-    """The first ``steps`` steps of training on the fixed ``batch``
-    (images, labels) from the weights of ``key``, on the host:
-    ``{"losses": [steps], "grad_norms": {leaf: norm of the first
-    gradient}, "delta_norms": {leaf: norm of the parameters' change}}``.
-    """
+def loss_and_grad(config, products, ranks):
+    """``(params, batch) -> (loss, gradient)`` of one step as ``ranks``
+    ranks compute it: the batch's rows in ``ranks`` groups in order, a
+    rank's rows each; ``batch_loss``, and so BatchNorm's mean and
+    variance, over each group alone; the loss and the gradient the
+    means over the groups, which is what the step's all-reduce makes of
+    them.  One group at a time, so that four ranks' rows fit where one
+    rank's do.  One rank is ``batch_loss`` itself on the whole batch."""
+    grad = jax.value_and_grad(
+        lambda p, b: batch_loss(config, products, p, *b))
+    if ranks == 1:
+        return grad
+
+    def mean_over_ranks(params, batch):
+        groups = jax.tree.map(
+            lambda a: a.reshape((ranks, -1) + a.shape[1:]), batch)
+        losses, grads = jax.lax.map(lambda b: grad(params, b), groups)
+        return jnp.mean(losses), jax.tree.map(
+            lambda g: jnp.mean(g, axis=0), grads)
+
+    return mean_over_ranks
+
+
+def step_program(config, workload, mode="float32"):
+    """The jitted ``(params, trace, batch) -> (params, trace, loss,
+    norms of the gradient's leaves)``: one step of SGD with momentum on
+    the mean gradient of the mix's ranks."""
     opt = workload["optimizer"]
     if opt["name"] != "sgd":
         raise NotImplementedError(f"optimizer {opt['name']!r}")
-    spec = param_spec(config)
-    products = precision.products(mode)
-    grad = jax.value_and_grad(
-        lambda p, b: batch_loss(config, products, p, *b))
+    grad = loss_and_grad(config, precision.products(mode),
+                         workload["ranks"])
 
     @jax.jit
     def one_step(params, trace, batch):
@@ -162,6 +184,18 @@ def follow(config, workload, key, batch, steps, mode="float32"):
                               params, trace)
         return params, trace, loss, weights.leaf_norms(grads)
 
+    return one_step
+
+
+def follow(config, workload, key, batch, steps, mode="float32"):
+    """The first ``steps`` steps of training on the fixed ``batch``
+    (images, labels: ``workload["ranks"]`` ranks' rows, a rank's after
+    another's) from the weights of ``key``, on the host: ``{"losses":
+    [steps], "grad_norms": {leaf: norm of the first gradient},
+    "delta_norms": {leaf: norm of the parameters' change}}``.
+    """
+    one_step = step_program(config, workload, mode)
+    spec = param_spec(config)
     params = jax.jit(lambda k: weights.make(k, spec))(key)
     trace = jax.tree.map(jnp.zeros_like, params)
     found = {"losses": []}

@@ -24,15 +24,6 @@ def under(*scopes):
     return ".*".join(component(s) for s in scopes)
 
 
-# The TPU compiler turns ``lax.ragged_dot`` into a kernel of its own and
-# names the call after itself (``ragged-dot-none.60``, ``op_name``
-# "ragged-dot-none"): the scope it was traced under is gone.  The
-# program's only grouped products are the routed experts', so such an
-# instruction is booked where they were written.
-GROUPED_PRODUCT = re.compile(r"^ragged-dot")
-GROUPED_PRODUCT_SCOPE = "hvd_step/loss_and_grad/moe/experts/"
-
-
 def split_ms(ops, table, trace_steps, patterns):
     """{name: milliseconds per traced step, the mean over the chips, of
     the leaf operations whose path matches ``patterns[name]``}."""
@@ -41,13 +32,10 @@ def split_ms(ops, table, trace_steps, patterns):
     seconds = dict.fromkeys(patterns, 0.0)
     for listed in by_device.values():
         for op in listed:
-            instruction = scope_join.instruction_of(op.name)
-            path = table.get(instruction)
+            path = table.get(scope_join.instruction_of(op.name))
             if path is None:
                 continue
             path = scope_join.step_path(path)
-            if GROUPED_PRODUCT.match(instruction):
-                path = GROUPED_PRODUCT_SCOPE + path
             if scope_join.phase_of(path) == "unattributed":
                 continue
             for name, pattern in compiled.items():

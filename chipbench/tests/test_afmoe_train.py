@@ -174,11 +174,14 @@ TABLE = {
     "fusion.10": FWD + "full_attention/attn/wq/dot_general",
     "attn.11": FWD.replace("layer_2", "layer_1")
     + "sliding_attention/attn/flash_fwd/flash_fwd",
-    "attn.12": BWD + "full_attention/attn/flash_dq/flash_dq",
+    "attn.12": BWD + "full_attention/attn/flash_dkv/flash_dkv",
     "fusion.13": FWD + "mlp/wo/dot_general",
     "fusion.14": "jit(prog)/vmap(jvp(TransformerLM))/moe/mul",  # no step scope
-    # the compiler's own kernel for a grouped product keeps no scope
-    "ragged-dot-none.15": "ragged-dot-none",
+    # the compiler's own kernel for a grouped product: the program's
+    # table recovers its path from its first stated user (PR 36) ...
+    "ragged-dot-none.15": FWD + "moe/while/body/closed_call/ragged-dot-none",
+    # ... and one with no such user keeps the compiler's string
+    "ragged-dot-none.16": "ragged-dot-none",
 }
 
 
@@ -201,7 +204,10 @@ def context(**more):
            "workload": load("workloads", "s8k-1chip-settled", False),
            "peaks": flops.peaks("TPU v5 lite"),
            "window": {"steps": 50, "samples_per_step": 16384},
-           "_program_report": {"scopes": TABLE, "module": "jit_prog"}}
+           "_program_report": {
+               "scopes": TABLE, "module": "jit_prog",
+               "renamed": {k: "ragged-dot-none" for k in TABLE
+                           if k.startswith("ragged-dot")}}}
     ctx.update(more)
     return ctx
 
@@ -232,7 +238,8 @@ def test_component_patterns():
 def test_time_under_the_moe_scopes():
     ctx = context()
     # eight of the instructions lie under ``moe`` with a step scope,
-    # and the compiler's grouped-product kernel is booked there
+    # and the compiler's grouped-product kernel by its recovered path;
+    # the one without a path is booked nowhere
     assert reader("moe_ms_per_step").read(ctx) == pytest.approx(9.0)
     # route x 2 (one recomputed), dispatch, combine x 2
     assert reader("moe_route_dispatch_ms_per_step").read(ctx) \
@@ -256,8 +263,9 @@ def test_experts_roofline_from_the_programs_count():
 
 
 def test_flash_scoped_roofline_counts_the_scoped_kernels_only():
-    """Three flash kernels of 1 ms a step; the grouped products are
-    Pallas kernels too and are not counted."""
+    """Three flash kernel calls of 1 ms a step (two forward, one
+    backward); the grouped products are Pallas kernels too and are not
+    counted."""
     from chipbench.adapters import afmoe_train as adapter
 
     ctx = context(adapter=adapter)
@@ -284,6 +292,53 @@ def test_readers_find_nothing_in_a_program_without_the_names(name):
         table = {k: v.replace("/moe/", "/mlp/").replace(
             "full_attention/", "") for k, v in TABLE.items()
             if not k.startswith("ragged-dot")}
-        mistral = window(context(
-            _program_report={"scopes": table, "module": "jit_prog"}))
+        mistral = window(context(_program_report={
+            "scopes": table, "module": "jit_prog", "renamed": {}}))
         assert reader(name).read(mistral) is None
+
+
+@pytest.mark.parametrize("cell, config, mix", [
+    ("smallthinker-21b-s8k-1chip", "smallthinker-21b-l4-ep4",
+     "s8k-1chip-settled-w200"),
+    ("trinity-mini-s8k-1chip", "trinity-mini-l5-ep8", "s8k-1chip-settled")])
+def test_moe_readers_read_a_recorded_step_as_the_parents_did(
+        cell, config, mix):
+    """One step of each routed cell as the chip traced it, with the
+    program's own table (tests/data_scopes): the ``moe_*`` readers of
+    the device trace read what the parent commit's read, which booked
+    every ``ragged-dot*`` instruction under ``moe/experts`` by its name
+    where these take the path the program recovered and, for the
+    experts' time, the report's ``renamed``."""
+    from chipbench.run import metrics_of
+
+    with open(os.path.join(HERE, "data_scopes", cell + "-moe.json")) as f:
+        data = json.load(f)
+    with open(os.path.join(HERE, "..", "..", "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    listed = [Op(d, line, name, s * 1e-9, e * 1e-9)
+              for d, line, name, s, e in data["events"]]
+    held = 4 * 16384.0          # a step's held assignments, say
+    ctx = window(context(
+        trace=listed, trace_steps=1, config=load("configs", config, False),
+        workload=load("workloads", mix, False),
+        _program_report={"scopes": data["scopes"], "module": "jit_prog",
+                         "renamed": data["renamed"]}), held=50 * held)
+    expect = dict(data["expect"])
+    experts_ms = expect.pop("moe_experts_roofline_ms")
+    names = [name for name in expect if name in {
+        m["name"] for m in metrics_of(bench, "per_layer", cell)}]
+    assert len(names) >= 4 and "moe_ms_per_step" in names
+    for name in names:
+        assert reader(name).read(ctx) == pytest.approx(
+            expect[name], rel=1e-12), name
+    flops = afmoe_flops.grouped_products_train_flops_per_assignment(
+        ctx["config"]) * held
+    assert reader("moe_experts_roofline").read(ctx) == pytest.approx(
+        100 * flops / (experts_ms / 1e3) / 197e12, rel=1e-12)
+    # a report without the table of renamed kernels (before PR 36) has
+    # no grouped product to its name: nothing to read, never a share of
+    # the activations' time alone
+    ctx = dict(ctx, _program_report={"scopes": data["scopes"],
+                                     "module": "jit_prog"})
+    assert reader("moe_experts_roofline").read(ctx) is None
+

@@ -47,9 +47,6 @@ def test_kernel_sum():
         "%psum.84 = f32[320,4]{1,0:T(8,128)} all-reduce(f32[320,4]{1,0} %x), "
         "channel_id=1, replica_groups={{0,1,2,3}}"
     ) == "psum.84 = all-reduce f32[320,4]"
-    assert tr.COLLECTIVE.search("psum.84 = all-reduce f32[320,4]")
-    assert tr.COLLECTIVE.search("ag = all-gather-start (f32[4], f32[16])")
-    assert not tr.COLLECTIVE.search("all-reduce.1 = fusion f32[4]")
     assert tr.ENVELOPE.search(tr.short_name(
         "%while.13 = (s32[]{:T(128)}, bf16[1,4]{1,0}) while(%tuple)"))
     fwd = "attn.21 = custom-call (bf16[8]) [tpu_custom_call]"
@@ -63,24 +60,6 @@ def test_kernel_sum():
     assert tr.top_ops(listed, n=2) == [["fusion.3 = fusion f32[4]", 2.0],
                                        [fwd, 1.25]]
     assert tr.busy_seconds(listed) == {0: 4.5}
-
-
-def test_exposed_collective_time():
-    assert tr.subtract([(0.0, 4.0)], [(1.0, 2.0), (3.0, 5.0)]) == 2.0
-    assert tr.subtract([(0.0, 1.0)], []) == 1.0
-    assert tr.subtract([(0.0, 1.0)], [(-1.0, 2.0)]) == 0.0
-    listed = ops(("while.1 = while (s32[])", 0.0, 9.0),     # hides nothing
-                 ("fusion.1 = fusion f32[4]", 0.0, 2.0),
-                 ("psum.7 = all-reduce f32[4]", 1.0, 4.0),  # 1 s hidden
-                 ("fusion.2 = fusion f32[4]", 6.0, 7.0),
-                 ("rs.1 = reduce-scatter f32[1]", 6.25, 6.75),  # all hidden
-                 ("all-reduce.9 = fusion f32[4]", 8.0, 9.0))    # not one
-    # an asynchronous one runs from its start to its done: all exposed
-    listed += ops(("ag.2 = all-gather-start (f32[1], f32[4])", 5.0, 5.5),
-                  line=tr.ASYNC_LINE)
-    listed += ops(("copy-start.3 = copy-start (f32[4])", 0.0, 9.0),
-                  line=tr.ASYNC_LINE)
-    assert tr.collective_seconds(listed) == {0: (4.0, 2.5)}
 
 
 def test_idle_gaps_are_named_by_the_host_span_over_them():
@@ -112,9 +91,10 @@ def test_recorded_trace(path):
         pytest.approx(expect["idle_share_worst"], rel=1e-6)
     assert max(tr.matching_seconds(listed, expect["kernel"]).values()) == \
         pytest.approx(expect["kernel_seconds"], rel=1e-6)
-    total, exposed = max(tr.collective_seconds(listed).values())
-    assert total == pytest.approx(expect["collective_seconds"], abs=1e-9)
-    assert exposed == pytest.approx(expect["collective_exposed_seconds"],
-                                    abs=1e-9)
-    assert exposed <= total
+    # PR 24's program reduced its gradients in synchronous all-reduces
+    # after the backward, each an event of the ops line: read by name
+    # (no reader does since PR 41; today's are asynchronous fusions,
+    # read through the program's table: test_report_time.py)
+    assert max(tr.matching_seconds(listed, " = all-reduce ").values()) \
+        == pytest.approx(expect["collective_seconds"], abs=1e-9)
     assert tr.top_ops(listed, n=1)[0][0] == expect["top_op"]

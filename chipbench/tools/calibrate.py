@@ -17,8 +17,16 @@ several ranks in one process hung the machine once): ``--control-only
 1`` reads the control, which needs no program, and ``--one-chip 1`` the
 sound readings from a stand-in for the ranks' step: the program's own
 loss function and optimizer, the ranks' rows one rank after another,
-their gradients averaged as the step's allreduce averages them.  Read a
-few of its seeds in the cell's own runs too: they have to agree.
+their gradients averaged as the step's allreduce averages them, as many
+steps as the cell follows.  Read a few of its seeds in the cell's own
+runs too.  Where the numbers compared are well-conditioned the two
+agree number for number (1e-7 on the CPU and for Mistral's four-chip
+cell on the chip); ResNet's do not (a third loss of 0.0008 against
+0.0143 on one seed): its first steps turn one rounding into another
+draw of every number (``tools/leaf_look.py``;
+``limits/resnet50-b128-dp4.json``, ``the_look``), so there the two are
+readings of one distribution and a limit is set from the largest of
+both.
 """
 
 import argparse
@@ -36,8 +44,11 @@ def ranks_on_one_chip(c, key, batch):
     """What ``Cell.first_steps`` finds, for a cell of several ranks
     whose adapter has ``loss_fn`` and ``optimizer``, without the ranks:
     each rank's loss and gradient from the program's loss function on
-    its own rows, the means of both over the ranks, one update of the
-    program's optimizer, and the loss after it."""
+    its own rows (with the model's state where the adapter has one:
+    BatchNorm's statistics, a rank's own in the step and averaged over
+    the ranks after it, as the compiled step does), the means of both
+    over the ranks, the program's optimizer over ``check_steps`` steps,
+    and the loss after them where the mix asks for it."""
     import jax
     import optax
 
@@ -46,37 +57,53 @@ def ranks_on_one_chip(c, key, batch):
     n = c.ranks
     loss_fn = c.adapter.loss_fn(c.config, c.workload, c.rehearse)
     optimizer = c.adapter.optimizer(c.workload)
+    has_aux = c.aux_spec is not None
     shards = [jax.tree.map(
         lambda a: a.reshape((n, -1) + a.shape[1:])[rank], batch)
         for rank in range(n)]
 
-    @functools.partial(jax.jit, donate_argnums=0)
-    def add(total, params, shard):
-        loss, grads = jax.value_and_grad(loss_fn)(params, shard)
-        return {"loss": total["loss"] + loss / n,
-                "grads": jax.tree.map(lambda t, g: t + g / n,
-                                      total["grads"], grads)}
+    def loss_of(params, aux, shard):
+        """(loss, the model's new state or None)"""
+        if has_aux:
+            return loss_fn(params, aux, shard)
+        return loss_fn(params, shard), None
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def update(params, grads):
-        updates, _ = optimizer.update(grads, optimizer.init(params), params)
-        return optax.apply_updates(params, updates), \
+    @functools.partial(jax.jit, donate_argnums=0)
+    def add(total, params, aux, shard):
+        (loss, new_aux), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(params, aux, shard)
+        return jax.tree.map(lambda t, x: t + x / n, total,
+                            {"loss": loss, "grads": grads, "aux": new_aux})
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    def update(params, opt_state, grads):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, \
             weights.leaf_norms(grads)
 
-    params, _ = c.make_weights(key)
-    total = {"loss": 0.0, "grads": jax.tree.map(jax.numpy.zeros_like,
-                                                params)}
-    for shard in shards:
-        total = add(total, params, shard)
-    losses = [float(total["loss"])]
-    params, grad_norms = update(params, total.pop("grads"))
+    params, aux = c.make_weights(key)
+    losses, grad_norms, opt_state = [], None, None
+    for i in range(c.workload["check_steps"]):
+        total = jax.tree.map(jax.numpy.zeros_like,
+                             {"loss": 0.0, "grads": params, "aux": aux})
+        for shard in shards:
+            total = add(total, params, aux, shard)
+        losses.append(float(total["loss"]))
+        aux = total["aux"]
+        if opt_state is None:       # not held beside the first gradients
+            opt_state = jax.jit(optimizer.init)(params)
+        params, opt_state, norms = update(params, opt_state,
+                                          total.pop("grads"))
+        if i == 0:
+            grad_norms = jax.device_get(norms)
+    del opt_state, total
     delta_norms = jax.jit(lambda p, k: weights.leaf_norms(jax.tree.map(
         lambda a, b: a - b, p, weights.make(k, c.spec))))(params, key)
     if c.workload.get("check_loss_after"):
-        loss_only = jax.jit(loss_fn)
-        losses.append(sum(float(loss_only(params, shard))
+        loss_only = jax.jit(lambda *args: loss_of(*args)[0])
+        losses.append(sum(float(loss_only(params, aux, shard))
                           for shard in shards) / n)
-    return {"losses": losses, "grad_norms": jax.device_get(grad_norms),
+    return {"losses": losses, "grad_norms": grad_norms,
             "delta_norms": jax.device_get(delta_norms)}
 
 

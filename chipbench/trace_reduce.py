@@ -1,6 +1,6 @@
 """From a profiler trace to numbers: device-op intervals per chip, their
-union (busy), the idle share, a kernel's summed time, collective time
-and the part of it that nothing else covers.
+union (busy), the idle share, a kernel's summed time, the operations
+that took longest and the idle gaps by what the host was doing.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes (with
 ``jax.profiler.ProfileData``, nothing but jax).  Everything else works
@@ -13,7 +13,9 @@ holds one event per program run (``jit_prog(<hash>)``); ``XLA Ops`` one
 per executed HLO instruction, named by the instruction's whole text
 (``%fusion.378 = (f32[4096]...) fusion(...)``), and nested: a
 ``%while.13`` covers the events of its body; ``Async XLA Ops`` the
-asynchronous copies and collectives from their start to their done.  A
+asynchronous copies and collectives from their start to their done
+(not loaded: a collective's time is read from the ops line by the
+program's own table of its collectives, ``chipbench/report_time.py``).  A
 Pallas kernel is a ``custom-call`` whose text has
 ``custom_call_target="tpu_custom_call"`` and whose instruction is named
 after the flax module it sits in (``%attn.21``), not after the kernel.
@@ -35,14 +37,10 @@ from typing import NamedTuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
-ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_LINE = "host"
 KERNEL_MARK = " [tpu_custom_call]"
 RESULT_CHARS = 72
-COLLECTIVE = re.compile(
-    r" = (all-reduce|all-gather|reduce-scatter|all-to-all|"
-    r"collective-permute)(-start|-done)? ")
 # operations that only cover the events of their bodies
 ENVELOPE = re.compile(r" = (while|conditional|call) ")
 
@@ -88,7 +86,7 @@ def short_name(text):
     return name
 
 
-def load(logdir, lines=(OPS_LINE, ASYNC_LINE, MODULES_LINE),
+def load(logdir, lines=(OPS_LINE, MODULES_LINE),
          host_prefix="chipbench"):
     """Every event of the device planes' ``lines`` as ``Op``s, and the
     benchmark's own annotations on the host's threads (``HOST_LINE``,
@@ -200,45 +198,6 @@ def matching_seconds(ops, pattern):
     for device, listed in leaf_ops(ops).items():
         out[device] = sum(op.end - op.start for op in listed
                           if pattern.search(op.name))
-    return out
-
-
-def subtract(intervals, covers):
-    """Seconds of ``intervals`` that no interval of ``covers``
-    overlaps."""
-    covers = merge(covers)
-    left = 0.0
-    for start, end in merge(intervals):
-        at = start
-        for c_start, c_end in covers:
-            if c_end <= at:
-                continue
-            if c_start >= end:
-                break
-            if c_start > at:
-                left += c_start - at
-            at = max(at, c_end)
-            if at >= end:
-                break
-        if at < end:
-            left += end - at
-    return left
-
-
-def collective_seconds(ops):
-    """{device: (total, exposed)}: the time of collective operations
-    (synchronous ones on the ops line, asynchronous ones from start to
-    done on the async line), and the part of it during which no other
-    operation runs on that chip."""
-    out = {}
-    asynchronous = device_ops(ops, ASYNC_LINE)
-    for device, listed in leaf_ops(ops).items():
-        mine = [(op.start, op.end)
-                for op in listed + asynchronous.get(device, [])
-                if COLLECTIVE.search(op.name)]
-        others = [(op.start, op.end) for op in listed
-                  if not COLLECTIVE.search(op.name)]
-        out[device] = (union_seconds(mine), subtract(mine, others))
     return out
 
 

@@ -617,6 +617,20 @@ def run(args):
     return line
 
 
+def flow(name):
+    """The run of this cell's kind: ``run`` above, the training flow, or
+    the ``run`` of the file of chipbench/ that the configuration's
+    adapter names as its ``FLOW`` (``serve_run``: a served run)."""
+    _, entry = find_cell(load_json("BENCHMARK.json"), name)
+    adapter = importlib.import_module(
+        "chipbench.adapters." + load_json(entry["file"])["adapter"])
+    if not hasattr(adapter, "FLOW"):
+        return run
+    # that file imports this one: under either name it is this module
+    sys.modules.setdefault("chipbench.run", sys.modules[__name__])
+    return importlib.import_module("chipbench." + adapter.FLOW).run
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--workload", required=True)
@@ -629,7 +643,7 @@ def main(argv=None):
                              ".chipbench_trace/ for tools/trace_dump.py")
     args = parser.parse_args(argv)
     try:
-        line = run(args)
+        line = flow(args.workload)(args)
     except Refused as exc:
         print(f"chipbench: {exc}", file=sys.stderr)
         return 1

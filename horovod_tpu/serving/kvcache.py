@@ -16,6 +16,17 @@ bucketed and fixed:
   design — every read of it is masked to a -1e30 score, which softmax
   turns into an exactly-0.0 probability, so the garbage is never
   observable in any output.
+* The decode tick carries both pools through its layer loop as the
+  loop's CARRY and updates them in place: viewed flat as ``(L *
+  n_blocks, block_tokens, KV, D)``, layer ``l`` writes its new rows
+  with one scatter at ``block + l * n_blocks`` and reads its block
+  views with one gather at ``table + l * n_blocks``.  They must NOT
+  ride the loop as its ``xs`` / ``ys``: the loop then slices each
+  layer's slab out of a pool, writes it into a new stacked output,
+  and the donated argument, which cannot alias an output built slab
+  by slab, is copied once more — three passes over both pools every
+  tick, whatever the batch (38 of an 82 ms tick on a v5e at 16
+  layers x 4,096 blocks; PERF.md section 6, PR 43).
 * One decode program per block-table width bucket (powers of two),
   always at batch ``max_slots`` with a per-slot active mask; one
   prefill + one ingest program per prompt-length bucket.  Warmup
@@ -269,10 +280,18 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
     current token at its own position, write the new K/V into its
     table's block (inactive slots write scratch), attend the gathered
     block view, return the greedy next token per slot plus the
-    updated pools."""
+    updated pools.
+
+    The pools are the layer loop's CARRY, viewed flat as ``(L *
+    n_blocks, block_tokens, KV, D)``: layer ``l`` scatters its rows at
+    ``blk + l * n_blocks`` and gathers its views at ``tables + l *
+    n_blocks``, so the donated buffers are updated in place (module
+    docstring: never as the scan's ``xs`` / ``ys``)."""
     dt = cfg.dtype
     B, NB = tables.shape
     KV, D = cfg.kv_heads, cfg.head_dim
+    pool_shape = k_pool.shape
+    L, n_blocks = pool_shape[:2]
     emb = params["embed"]
     x = emb[toks].astype(dt)                       # (B, 1, M)
     ang = jnp.asarray(angles)[pos][:, None, :]     # (B, 1, D//2)
@@ -282,18 +301,19 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
         0)
     off = jnp.where(active, pos % bt, 0)
 
-    def body(x, layer):
-        (wq, wk, wv, wo, s1, s2, wg, wu, w2, kp, vp) = layer
+    def body(carry, layer):
+        x, kp, vp, base = carry
+        (wq, wk, wv, wo, s1, s2, wg, wu, w2) = layer
         h = _rmsnorm(x, s1, dt)
         q = jnp.einsum("btm,mhd->bthd", h, wq.astype(dt))
         k = jnp.einsum("btm,mkd->btkd", h, wk.astype(dt))
         v = jnp.einsum("btm,mkd->btkd", h, wv.astype(dt))
         q = _rope_rows(q, ang)
         k = _rope_rows(k, ang)
-        kp = kp.at[blk, off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[blk, off].set(v[:, 0].astype(vp.dtype))
-        kv = kp[tables].reshape(B, NB * bt, KV, D)
-        vv = vp[tables].reshape(B, NB * bt, KV, D)
+        kp = kp.at[blk + base, off].set(k[:, 0].astype(kp.dtype))
+        vp = vp.at[blk + base, off].set(v[:, 0].astype(vp.dtype))
+        kv = kp[tables + base].reshape(B, NB * bt, KV, D)
+        vv = vp[tables + base].reshape(B, NB * bt, KV, D)
         o = _paged_attention(q, kv, vv, pos, cfg.attention_window)
         x = x + jnp.einsum("bthd,hdm->btm", o, wo.astype(dt))
         h2 = _rmsnorm(x, s2, dt)
@@ -301,15 +321,18 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
             jnp.einsum("btm,mf->btf", h2, wg.astype(dt)))
         up = jnp.einsum("btm,mf->btf", h2, wu.astype(dt))
         x = x + jnp.einsum("btf,fm->btm", gate * up, w2.astype(dt))
-        return x, (kp, vp)
+        return (x, kp, vp, base + n_blocks), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, _layer_stack(params) + (k_pool, v_pool))
+    flat = (L * n_blocks,) + pool_shape[2:]
+    (x, k_pool, v_pool, _), _ = jax.lax.scan(
+        body,
+        (x, k_pool.reshape(flat), v_pool.reshape(flat), jnp.int32(0)),
+        _layer_stack(params))
     x = _rmsnorm(x, params["ln_final"]["scale"], dt)
     logits = jnp.einsum("btm,vm->btv", x, emb.astype(dt),
                         preferred_element_type=jnp.float32)
     tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-    return tok, k_pool, v_pool
+    return tok, k_pool.reshape(pool_shape), v_pool.reshape(pool_shape)
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ decode-replica kill, the ``after_decodes`` chaos trigger, and the
 TTFT/tokens-per-sec SLO signals the autoscaler and fleet controller
 read."""
 
+import functools
 import json
 import os
 import urllib.request
@@ -34,8 +35,8 @@ from horovod_tpu.serving.continuous import (
     read_journal,
 )
 from horovod_tpu.serving.kvcache import (
-    BlocksExhausted, KVBlockPool, PagedKVPrograms, bucket_for,
-    pack_kv_blocks, pow2_buckets, unpack_kv_blocks,
+    BlocksExhausted, KVBlockPool, PagedKVPrograms, _decode_fwd,
+    bucket_for, pack_kv_blocks, pow2_buckets, unpack_kv_blocks,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -349,6 +350,166 @@ def test_paged_programs_reject_moe():
                             num_experts=4, dtype=jnp.float32)
     with pytest.raises(ValueError, match="dense-MLP"):
         PagedKVPrograms(cfg, max_slots=1, block_tokens=4, n_blocks=4)
+
+
+# -- the decode tick writes and reads the pools in place --------------------
+# (docs/serving.md "Continuous batching": the pools are the layer loop's
+# carry; as its xs / ys every tick copied both pools three times over)
+
+@pytest.fixture(scope="module")
+def deep():
+    """Three layers, four slots at different positions, slot 2
+    inactive with a table of its own, pools of random rows."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=3, n_heads=4,
+        n_kv_heads=2, d_ff=64, max_seq_len=64, dtype=jnp.float32)
+    params = TransformerLM(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    progs = PagedKVPrograms(cfg, max_slots=4, block_tokens=4,
+                            n_blocks=12)
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    pools = tuple(jax.random.normal(k, progs.pool_shape, jnp.float32)
+                  for k in keys)
+    tick = dict(
+        toks=jnp.asarray([[7], [21], [40], [3]], jnp.int32),
+        pos=jnp.asarray([5, 0, 9, 2], jnp.int32),
+        tables=jnp.asarray([[3, 7, 0, 0], [1, 0, 0, 0],
+                            [5, 6, 2, 0], [9, 0, 0, 0]], jnp.int32),
+        active=jnp.asarray([True, True, False, True]))
+    return cfg, params, progs, pools, tick
+
+
+def _decode_fn(cfg, progs):
+    return functools.partial(_decode_fwd, cfg=cfg, angles=progs._angles,
+                             bt=progs.block_tokens)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("width", [1, 2, 4])
+def test_decode_carries_the_pools_through_the_layer_loop(deep, width):
+    cfg, params, progs, pools, tick = deep
+    L, n_blocks = progs.pool_shape[:2]
+    slab = progs.pool_shape[1:]
+    # a pool or one layer's slab, in any grouping of the leading axes
+    pool_like = {progs.pool_shape, (L * n_blocks,) + slab[1:],
+                 slab, (1,) + slab}
+    size = int(np.prod(progs.pool_shape))
+    closed = jax.make_jaxpr(_decode_fn(cfg, progs))(
+        params, *pools, tick["toks"], tick["pos"],
+        tick["tables"][:, :width], tick["active"])
+    loops = scatters = gathers = 0
+    for eqn in _equations(closed.jaxpr):
+        name = eqn.primitive.name
+        if name == "scan":
+            loops += 1
+            n_in = eqn.params["num_consts"] + eqn.params["num_carry"]
+            through = eqn.invars[n_in:] + \
+                eqn.outvars[eqn.params["num_carry"]:]
+            assert not [v.aval.shape for v in through
+                        if v.aval.shape in pool_like], \
+                "a pool rides the layer scan as xs / ys"
+        elif name in ("dynamic_slice", "dynamic_update_slice"):
+            assert eqn.outvars[0].aval.shape not in pool_like, \
+                f"{name} yields a layer's slab or a pool"
+        elif name in ("scatter", "gather"):
+            whole = eqn.invars[0].aval.size == size
+            scatters += whole and name == "scatter"
+            gathers += whole and name == "gather"
+    # one write and one read a pool a layer, each on the WHOLE pool
+    assert (loops, scatters, gathers) == (1, 2, 2)
+
+
+def _plain_decode_rows(cfg, params, k_pool, v_pool, toks, pos, tables,
+                       active, bt):
+    """The tick layer by layer in a Python loop, each layer's slab
+    taken as a value: the roped key and the value every layer caches
+    for every slot, ``(L, B, KV, D)`` each."""
+    lp = params["layers"]
+    B, NB = tables.shape
+    half = cfg.head_dim // 2
+    freq = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos[:, None].astype(jnp.float32) * freq[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+
+    def norm(x, scale):
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6) * scale
+
+    def rope(x):
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+    blk = jnp.where(active, tables[jnp.arange(B), pos // bt], 0)
+    off = jnp.where(active, pos % bt, 0)
+    seen = jnp.arange(NB * bt)[None, :] <= pos[:, None]
+    x = params["embed"][toks[:, 0]]
+    groups = cfg.n_heads // cfg.kv_heads
+    ks, vs = [], []
+    for l in range(cfg.n_layers):
+        h = norm(x, lp["ln_attn"]["scale"][l])
+        q = rope(jnp.einsum("bm,mhd->bhd", h,
+                            lp["attn"]["wq"]["kernel"][l]))
+        k = rope(jnp.einsum("bm,mkd->bkd", h,
+                            lp["attn"]["wk"]["kernel"][l]))
+        v = jnp.einsum("bm,mkd->bkd", h, lp["attn"]["wv"]["kernel"][l])
+        ks.append(k)
+        vs.append(v)
+        kl = k_pool[l].at[blk, off].set(k)[tables].reshape(
+            B, NB * bt, cfg.kv_heads, cfg.head_dim)
+        vl = v_pool[l].at[blk, off].set(v)[tables].reshape(
+            B, NB * bt, cfg.kv_heads, cfg.head_dim)
+        qg = q.reshape(B, cfg.kv_heads, groups, cfg.head_dim)
+        scores = jnp.einsum("bkgd,bskd->bkgs", qg, kl) / \
+            cfg.head_dim ** 0.5
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None, None, :], scores, -1e30), axis=-1)
+        o = jnp.einsum("bkgs,bskd->bkgd", probs, vl).reshape(
+            B, cfg.n_heads, cfg.head_dim)
+        x = x + jnp.einsum("bhd,hdm->bm", o,
+                           lp["attn"]["wo"]["kernel"][l])
+        h2 = norm(x, lp["ln_mlp"]["scale"][l])
+        gate = jax.nn.silu(h2 @ lp["mlp"]["wi_gate"]["kernel"][l])
+        x = x + (gate * (h2 @ lp["mlp"]["wi_up"]["kernel"][l])) @ \
+            lp["mlp"]["wo"]["kernel"][l]
+    return jnp.stack(ks), jnp.stack(vs)
+
+
+def test_decode_writes_each_layers_rows_and_nothing_else(deep):
+    cfg, params, progs, pools, tick = deep
+    bt = progs.block_tokens
+    args = (tick["toks"], tick["pos"], tick["tables"], tick["active"])
+    _, k_new, v_new = jax.jit(_decode_fn(cfg, progs))(
+        params, *pools, *args)
+    k_rows, v_rows = jax.jit(functools.partial(
+        _plain_decode_rows, cfg, bt=bt))(params, *pools, *args)
+    pos, tables = np.asarray(tick["pos"]), np.asarray(tick["tables"])
+    active = np.asarray(tick["active"])
+    live, (idle,) = np.flatnonzero(active), np.flatnonzero(~active)
+    blk, off = tables[live, pos[live] // bt], pos[live] % bt
+    written = np.zeros(progs.pool_shape[:3], bool)
+    written[:, blk, off] = True
+    for old, new, rows in ((pools[0], k_new, k_rows),
+                           (pools[1], v_new, v_rows)):
+        old, new = np.asarray(old), np.asarray(new)
+        assert new.shape == progs.pool_shape
+        changed = (old != new).any(axis=(-2, -1))
+        # the inactive slot's row lands in scratch block 0, row 0
+        assert not changed[:, 0, 1:].any()
+        assert (changed[:, 1:] == written[:, 1:]).all()
+        np.testing.assert_allclose(
+            new[:, blk, off], np.asarray(rows)[:, live],
+            rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            new[:, 0, 0], np.asarray(rows)[:, idle], rtol=1e-5,
+            atol=1e-5)
 
 
 # -- chaos: the after_decodes trigger ---------------------------------------

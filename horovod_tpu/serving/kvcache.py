@@ -8,7 +8,7 @@ bucketed and fixed:
 
 * K/V live in two pools of shape ``(L, n_blocks, block_tokens, KV,
   D)``; a sequence owns an ordered list of block ids (its *block
-  table*) and its cache view is a gather of those blocks.  Pools never
+  table*) and decode reads its cache through that table.  Pools never
   change shape; sequences joining or leaving only changes table
   contents (operands, not shapes).
 * Block 0 is reserved **scratch**: padded table entries and
@@ -19,14 +19,29 @@ bucketed and fixed:
 * The decode tick carries both pools through its layer loop as the
   loop's CARRY and updates them in place: viewed flat as ``(L *
   n_blocks, block_tokens, KV, D)``, layer ``l`` writes its new rows
-  with one scatter at ``block + l * n_blocks`` and reads its block
-  views with one gather at ``table + l * n_blocks``.  They must NOT
+  with one scatter at ``block + l * n_blocks`` and then attends the
+  carry at ``table + l * n_blocks``.  They must NOT
   ride the loop as its ``xs`` / ``ys``: the loop then slices each
   layer's slab out of a pool, writes it into a new stacked output,
   and the donated argument, which cannot alias an output built slab
   by slab, is copied once more — three passes over both pools every
   tick, whatever the batch (38 of an 82 ms tick on a v5e at 16
   layers x 4,096 blocks; PERF.md section 6, PR 43).
+* Decode attends the cache IN PLACE where it can.  The attention of
+  a tick has two forms, one algorithm, one mask, one rounding
+  (:func:`kernel_interpret` is the rule, no knob): where the process
+  computes on a TPU and the shapes fill its tiles, a Pallas kernel
+  (:mod:`..ops.paged_kernels`) follows each slot's table through the
+  carried pools up to the slot's own position, a slot costing what it
+  holds; elsewhere (the CPU tests) the XLA form gathers every slot's
+  whole table into a dense view, keys and values, every layer, and
+  scores all of it, so every slot pays for the widest active table.
+  The XLA form is the kernel's reference in the tests.  The kernel
+  reads the carry as it is (a block as ``block_tokens x KV`` rows is
+  the same bytes): a block laid out otherwise is only to be had by
+  STORING the pools so (``pool_shape``, ``make_pools``, ingest, the
+  scatters, whatever reads rows back), never by reshaping the carry,
+  which copies a pool every tick when bytes change tiles.
 * One decode program per block-table width bucket (powers of two),
   always at batch ``max_slots`` with a per-slot active mask; one
   prefill + one ingest program per prompt-length bucket.  Warmup
@@ -62,6 +77,7 @@ from ..models.transformer import (
     rope_angles,
 )
 from ..ops import compiled as compiled_mod
+from ..ops import paged_kernels, pallas_kernels
 from ..ops import quantize as quantize_mod
 
 __all__ = [
@@ -274,19 +290,45 @@ def _ingest_fwd(k_pool, v_pool, k_all, v_all, blocks, length, *, bt):
     return k_pool, v_pool
 
 
+def kernel_interpret(cfg, pool_shape, interpret=None):
+    """Which form a decode tick's attention takes: ``None`` for the XLA
+    form (gathered views), else the ``interpret=`` the Pallas kernel
+    (:func:`..ops.paged_kernels.paged_decode_attention`) is called
+    with.  Left to itself (``interpret=None``) the kernel runs where the
+    process computes on a TPU and the shapes fill its tiles
+    (:func:`..ops.paged_kernels.kernel_takes`); everywhere else, the
+    CPU tests among them, the XLA form.  ``True`` / ``False`` force the
+    kernel through the interpreter / through Mosaic whatever the
+    backend and shapes (the tests; a compile for a described chip)."""
+    if interpret is not None:
+        return bool(interpret)
+    if pallas_kernels.default_interpret() or not paged_kernels.kernel_takes(
+            pool_shape, cfg.n_heads, cfg.dtype):
+        return None
+    return False
+
+
 def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
-                cfg, angles, bt):
+                cfg, angles, bt, interpret=None):
     """One decode tick for the whole slot batch: feed each slot's
     current token at its own position, write the new K/V into its
-    table's block (inactive slots write scratch), attend the gathered
-    block view, return the greedy next token per slot plus the
-    updated pools.
+    table's block (inactive slots write scratch), attend the slot's
+    blocks, return the greedy next token per slot plus the updated
+    pools.
 
     The pools are the layer loop's CARRY, viewed flat as ``(L *
     n_blocks, block_tokens, KV, D)``: layer ``l`` scatters its rows at
-    ``blk + l * n_blocks`` and gathers its views at ``tables + l *
+    ``blk + l * n_blocks`` and then attends the carry at ``tables + l *
     n_blocks``, so the donated buffers are updated in place (module
-    docstring: never as the scan's ``xs`` / ``ys``)."""
+    docstring: never as the scan's ``xs`` / ``ys``) and a slot attends
+    its own new row.  The attention has two forms, one algorithm
+    (:func:`kernel_interpret` chooses; ``interpret`` is its override):
+    the Pallas kernel reads each slot's blocks through its table where
+    they lie, up to the slot's own position; the XLA form gathers every
+    slot's whole table into a dense view and scores all of it.  The
+    kernel takes the carry as it is: any other block layout has to be
+    the one the pools are STORED in, because a reshape of the carry
+    that moves bytes between tiles copies a pool every tick."""
     dt = cfg.dtype
     B, NB = tables.shape
     KV, D = cfg.kv_heads, cfg.head_dim
@@ -300,6 +342,7 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
         jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0],
         0)
     off = jnp.where(active, pos % bt, 0)
+    kernel = kernel_interpret(cfg, pool_shape, interpret)
 
     def body(carry, layer):
         x, kp, vp, base = carry
@@ -312,9 +355,14 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
         k = _rope_rows(k, ang)
         kp = kp.at[blk + base, off].set(k[:, 0].astype(kp.dtype))
         vp = vp.at[blk + base, off].set(v[:, 0].astype(vp.dtype))
-        kv = kp[tables + base].reshape(B, NB * bt, KV, D)
-        vv = vp[tables + base].reshape(B, NB * bt, KV, D)
-        o = _paged_attention(q, kv, vv, pos, cfg.attention_window)
+        if kernel is None:
+            kv = kp[tables + base].reshape(B, NB * bt, KV, D)
+            vv = vp[tables + base].reshape(B, NB * bt, KV, D)
+            o = _paged_attention(q, kv, vv, pos, cfg.attention_window)
+        else:
+            o = paged_kernels.paged_decode_attention(
+                q[:, 0], kp, vp, tables, pos, base,
+                window=cfg.attention_window, interpret=kernel)[:, None]
         x = x + jnp.einsum("bthd,hdm->btm", o, wo.astype(dt))
         h2 = _rmsnorm(x, s2, dt)
         gate = jax.nn.silu(
@@ -346,7 +394,7 @@ class PagedKVPrograms:
     :func:`..ops.compiled.program_cache_stats`."""
 
     def __init__(self, cfg, *, max_slots, block_tokens, n_blocks,
-                 prompt_buckets=None, donate=None):
+                 prompt_buckets=None, donate=None, interpret=None):
         if cfg.num_experts:
             raise ValueError(
                 "paged-KV decode supports dense-MLP models only "
@@ -381,6 +429,11 @@ class PagedKVPrograms:
                      cfg.max_seq_len, cfg.attention_window,
                      cfg.rope_theta, jnp.dtype(cfg.dtype).name,
                      self.max_slots, self.block_tokens, self.n_blocks)
+        # whether the decode programs' attention is the kernel's: static
+        # a process, so decode() counts its ticks on the host
+        self._interpret = interpret
+        self.reads_in_place = kernel_interpret(
+            cfg, self.pool_shape, interpret) is not None
 
     # -- pools ---------------------------------------------------------------
 
@@ -428,13 +481,18 @@ class PagedKVPrograms:
         return compiled_mod.shared_program(key, build)
 
     def _decode_program(self, NB):
-        key = ("paged_kv", "decode", self._sig, NB)
+        key = ("paged_kv", "decode", self._sig, NB, self._interpret)
         cfg, ang, bt = self.cfg, self._angles, self.block_tokens
         donate = (1, 2) if self._donate else ()
+        # the override rides only where one was given: what stands in
+        # for _decode_fwd (chipbench/tools/serve_fault.py) knows the
+        # three keywords it always had
+        forced = {} if self._interpret is None \
+            else {"interpret": self._interpret}
 
         def build():
             return jax.jit(functools.partial(
-                _decode_fwd, cfg=cfg, angles=ang, bt=bt),
+                _decode_fwd, cfg=cfg, angles=ang, bt=bt, **forced),
                 donate_argnums=donate)
 
         return compiled_mod.shared_program(key, build)
@@ -492,6 +550,7 @@ class PagedKVPrograms:
             jnp.asarray(np.asarray(positions, np.int32)),
             jnp.asarray(tables),
             jnp.asarray(np.asarray(active, bool)))
+        telemetry.count_serve_decode_tick(self.reads_in_place)
         return np.asarray(tok), k_pool, v_pool
 
     def warmup(self, params):

@@ -420,6 +420,15 @@ SERVING_TOKENS_HELP = (
     "Tokens generated by the continuous batcher's decode loop "
     "(prefill first-tokens included) — the serving goodput unit "
     "tokens/sec signals derive from")
+SERVE_DECODE_TICKS_FAMILY = "horovod_serve_decode_ticks_total"
+SERVE_DECODE_TICKS_HELP = (
+    "Decode ticks PagedKVPrograms.decode dispatched (one program call "
+    "for the whole slot batch)")
+SERVE_PAGED_KERNEL_TICKS_FAMILY = "horovod_serve_paged_kernel_ticks_total"
+SERVE_PAGED_KERNEL_TICKS_HELP = (
+    "Of those, the ticks whose attention read the paged cache in place "
+    "through the Pallas kernel (ops/paged_kernels.py) and not through "
+    "the XLA form's gathered views; the choice is static a process")
 KV_BLOCKS_IN_USE_FAMILY = "horovod_kv_blocks_in_use"
 KV_BLOCKS_IN_USE_HELP = (
     "Paged KV cache blocks currently allocated to live decode "
@@ -622,6 +631,16 @@ def count_serving_tokens(n=1):
     process-current registry."""
     registry().counter(SERVING_TOKENS_FAMILY,
                        SERVING_TOKENS_HELP).inc(int(n))
+
+
+def count_serve_decode_tick(in_place):
+    """One decode tick dispatched, and whether its attention was the
+    paged kernel's, into the process-current registry (both counters
+    exist from the first tick on, advanced or not)."""
+    registry().counter(SERVE_DECODE_TICKS_FAMILY,
+                       SERVE_DECODE_TICKS_HELP).inc(1)
+    registry().counter(SERVE_PAGED_KERNEL_TICKS_FAMILY,
+                       SERVE_PAGED_KERNEL_TICKS_HELP).inc(int(in_place))
 
 
 def set_kv_blocks_in_use(n):

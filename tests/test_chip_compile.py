@@ -97,6 +97,21 @@ def _ssd(*args):
     return ssd_scan(*args, chunk=256, interpret=False)[0]
 
 
+def _paged(pool, heads, width, window=None, slots=32):
+    """Decode attention over the paged cache (ops/paged_kernels.py):
+    one layer's call inside the tick, on flat pools of 1,024 blocks."""
+    from horovod_tpu.ops.paged_kernels import paged_decode_attention
+
+    def fwd(q, k_pool, v_pool, tables, pos, base):
+        return paged_decode_attention(q, k_pool, v_pool, tables, pos,
+                                      base, window=window,
+                                      interpret=False)
+    flat = ((1024,) + pool, jnp.bfloat16)
+    return fwd, [((slots, heads, pool[-1]), jnp.bfloat16), flat, flat,
+                 ((slots, width), jnp.int32), ((slots,), jnp.int32),
+                 ((), jnp.int32)], 1
+
+
 def _kernel_names(text):
     """The names of a compiled program's Mosaic kernels, one a call."""
     return re.findall(
@@ -150,6 +165,14 @@ def _kernel_cases():
             _grad_of_sum(_ssd, 6), _ssd_shapes(8192, jnp.float32), 2),
         "ssd_bwd_s1k_g2": (
             _grad_of_sum(_ssd, 6), _ssd_shapes(1024, heads=16, groups=2), 2),
+        # the served cell's decode attention (Mistral's 32 heads over 8
+        # KV heads of 128, blocks of 16, its window) at its widest and
+        # its narrowest table, and what else ``kernel_takes`` says yes
+        # to: 16 KV heads of 256, blocks of two tokens (one tile of rows)
+        "paged_decode": _paged((16, 8, 128), 32, 256, window=4096),
+        "paged_decode_nb1": _paged((16, 8, 128), 32, 1, window=4096),
+        "paged_decode_kv16_d256": _paged((16, 16, 256), 64, 32),
+        "paged_decode_bt2": _paged((2, 8, 128), 16, 64, window=48),
         "quantize_int8": (
             lambda x: pk.quantize_blockwise(x, interpret=False),
             [((_N,), jnp.float32)], 1),
@@ -318,6 +341,8 @@ def test_hybrid_cell_fits_with_its_scans_in_their_kernels(
     "flash_bwd_s8k_h28_w4096", "flash_fwd_s8k_d64", "flash_bwd_s8k_d64",
     "ssd_fwd_s8k", "ssd_bwd_s8k", "ssd_fwd_s128", "ssd_bwd_s128",
     "ssd_bwd_s8k_f32", "ssd_bwd_s1k_g2",
+    "paged_decode", "paged_decode_nb1", "paged_decode_kv16_d256",
+    "paged_decode_bt2",
     "quantize_int8",
     "dequantize_int8", "quantize_int4", "dequantize_int4",
     "fused_scale_cast",
@@ -352,12 +377,72 @@ def test_chip_compiler_takes(chip, name):
             ["flash_dkv", "flash_fwd"][-min_calls:]
             if name.startswith("flash_")
             else ["ssd_bwd", "ssd_fwd"][-min_calls:])
+    if name.startswith("paged_"):
+        assert _kernel_names(text) == ["paged_decode_attention"]
+        # the kernel's view of a block as rows is the pools' own bytes
+        assert not re.search(
+            r"bf16\[1024,[\d,]+\][^ ]* (copy|reshape|transpose|fusion)\(",
+            text)
     if name == "lm436m_step":
         mem = compiled.memory_analysis()
         # donated state in, the same bytes out, and the step's
         # temporaries: what B5 needs of a 16 GB chip
         assert mem.alias_size_in_bytes > 5e9
         assert mem.temp_size_in_bytes < 12.5e9
+
+
+def test_served_decode_tick_reads_the_pools_in_place(chip):
+    """The widest decode program of the served cells (16 layers of
+    Mistral's widths, 32 slots, a table of 256 blocks of 16 tokens,
+    4,096 blocks a layer) with the kernel form forced through Mosaic
+    and the pools donated, as the chip runs it: the two pools aliased,
+    no temporary of a view's size, and nothing of a pool's or a gathered
+    view's shape but the two in-place scatters inside the loop."""
+    from horovod_tpu.models.transformer import TransformerConfig, TransformerLM
+    from horovod_tpu.serving.kvcache import PagedKVPrograms
+
+    cfg = TransformerConfig(
+        vocab_size=32000, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=4096,
+        attention_window=4096, rope_theta=10000.0, dtype=jnp.bfloat16)
+    programs = PagedKVPrograms(cfg, max_slots=32, block_tokens=16,
+                               n_blocks=4096, donate=True, interpret=False)
+    assert programs.reads_in_place
+    one_chip = SingleDeviceSharding(chip)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda s: shaped(s.shape, cfg.dtype),
+        jax.eval_shape(lambda t: TransformerLM(cfg).init(
+            jax.random.PRNGKey(0), t)["params"],
+            jax.ShapeDtypeStruct((1, 8), jnp.int32)))
+    pool = shaped(programs.pool_shape, cfg.dtype)
+    width = programs.table_buckets[-1]
+    assert width == 256
+    with jax.enable_x64(False):
+        compiled = programs._decode_program(width).lower(
+            params, pool, pool, shaped((32, 1), jnp.int32),
+            shaped((32,), jnp.int32), shaped((32, width), jnp.int32),
+            shaped((32,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(programs.pool_shape))
+    assert memory.alias_size_in_bytes == 2 * pool_bytes
+    assert memory.temp_size_in_bytes < 0.01e9
+    assert _kernel_names(text) == ["paged_decode_attention"]
+    # a pool (whole, flat or as the kernel views it), a layer's slab, a
+    # gathered view of 32 slots x 256 or 128 blocks
+    big = r"bf16\[(?:16,4096,16,8,128|65536,16,8,128|65536,128,128" \
+          r"|4096,16,8,128|8192,16,8,128|32,4096,8,128)\]"
+    opcodes = re.findall(
+        r"= " + big + r"[^ ]* ([\w-]+)\(", text)
+    assert opcodes and set(opcodes) <= {
+        "parameter", "bitcast", "get-tuple-element", "scatter", "fusion"}
+    # ... the fusions being the two in-place scatters of the tick's new
+    # rows (a fusion each, the scatter its body)
+    assert opcodes.count("fusion") == opcodes.count("scatter") == 2
 
 
 # the routed layer's way back to the tokens (parallel/moe._sum_by_owner)

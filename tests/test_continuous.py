@@ -512,6 +512,108 @@ def test_decode_writes_each_layers_rows_and_nothing_else(deep):
             atol=1e-5)
 
 
+# -- the tick's attention has two forms, one answer -------------------------
+# (ops/paged_kernels.py: the Pallas kernel reads each slot's blocks through
+# its table in place; the XLA form over gathered views is its reference)
+
+@pytest.fixture(scope="module")
+def in_place(bundle):
+    """The bundle's programs with the kernel form forced through the
+    interpreter."""
+    cfg = bundle[0]
+    return PagedKVPrograms(cfg, max_slots=3, block_tokens=8,
+                           n_blocks=24, interpret=True)
+
+
+def _serve(params, progs, budgets=(6, 11, 3, 9, 14, 7)):
+    """A few mixed requests, more than the slots hold: streams join
+    and leave while others decode."""
+    bat = ContinuousBatcher(params, progs, max_new_tokens=6)
+    prompts = PROMPTS + [list(range(1, 20)), [7] * 9]
+    handles = [bat.submit(p, n) for p, n in zip(prompts, budgets)]
+    bat.drain()
+    assert bat.pool.in_use == 0
+    return [h.tokens() for h in handles], bat.k_pool, bat.v_pool
+
+
+def test_kernel_form_emits_the_xla_forms_tokens_and_leaves_its_pools(
+        bundle, in_place):
+    _, _, params, progs = bundle
+    assert in_place.reads_in_place and not progs.reads_in_place
+    kernel0 = telemetry.counter_total(
+        "horovod_serve_paged_kernel_ticks_total")
+    want, k_want, v_want = _serve(params, progs)
+    assert telemetry.counter_total(
+        "horovod_serve_paged_kernel_ticks_total") == kernel0
+    ticks0 = telemetry.counter_total("horovod_serve_decode_ticks_total")
+    got, k_got, v_got = _serve(params, in_place)
+    assert got == want
+    np.testing.assert_allclose(k_got, k_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v_got, v_want, rtol=1e-5, atol=1e-5)
+    ticks = telemetry.counter_total(
+        "horovod_serve_decode_ticks_total") - ticks0
+    assert ticks > 0 and telemetry.counter_total(
+        "horovod_serve_paged_kernel_ticks_total") - kernel0 == ticks
+
+
+def test_off_the_tpu_the_tick_takes_the_xla_form_and_says_so(bundle):
+    cfg, _, params, progs = bundle
+    assert jax.default_backend() != "tpu"
+    assert not progs.reads_in_place
+    ticks0 = telemetry.counter_total("horovod_serve_decode_ticks_total")
+    kernel0 = telemetry.counter_total(
+        "horovod_serve_paged_kernel_ticks_total")
+    bat = ContinuousBatcher(params, progs, max_new_tokens=4)
+    bat.submit(PROMPTS[0])
+    bat.drain()
+    # the first token is the prefill's: three ticks decode the rest
+    assert telemetry.counter_total(
+        "horovod_serve_decode_ticks_total") - ticks0 == 3
+    assert telemetry.counter_total(
+        "horovod_serve_paged_kernel_ticks_total") == kernel0
+    # both counters exist from the first tick on (the benchmark's
+    # reader tells a zero from a commit without the kernel by that)
+    assert telemetry.registry().get(
+        "horovod_serve_paged_kernel_ticks_total") is not None
+
+
+@pytest.mark.parametrize("interpret, gathers, kernels", [
+    (None, 2, 0), (True, 0, 1)])
+def test_the_kernel_form_gathers_no_view(deep, interpret, gathers,
+                                         kernels):
+    cfg, params, progs, pools, tick = deep
+    size = int(np.prod(progs.pool_shape))
+    closed = jax.make_jaxpr(functools.partial(
+        _decode_fn(cfg, progs), interpret=interpret))(
+        params, *pools, tick["toks"], tick["pos"], tick["tables"],
+        tick["active"])
+    found = {"gather": 0, "scatter": 0, "pallas_call": 0}
+    for eqn in _equations(closed.jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            found[name] += 1
+            # both pools whole, as the loop carries them
+            assert sum(v.aval.size == size for v in eqn.invars) == 2
+        elif name in ("gather", "scatter") \
+                and eqn.invars[0].aval.size == size:
+            found[name] += 1
+    assert found == {"gather": gathers, "scatter": 2,
+                     "pallas_call": kernels}
+
+
+def test_the_two_forms_agree_on_a_tick_with_an_idle_slot(deep):
+    cfg, params, progs, pools, tick = deep
+    args = (tick["toks"], tick["pos"], tick["tables"], tick["active"])
+    want = jax.jit(_decode_fn(cfg, progs))(params, *pools, *args)
+    got = jax.jit(functools.partial(
+        _decode_fn(cfg, progs), interpret=True))(params, *pools, *args)
+    live = np.asarray(tick["active"])
+    np.testing.assert_array_equal(np.asarray(got[0])[live],
+                                  np.asarray(want[0])[live])
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 # -- chaos: the after_decodes trigger ---------------------------------------
 
 def test_after_decodes_is_its_own_deterministic_counter(

@@ -213,9 +213,12 @@ def rope_angles(head_dim: int, max_seq: int, theta: float) -> np.ndarray:
 
 
 def apply_rope(x, angles):
-    """x: (B, S, H, D); angles: (S, D//2) — rotate pairs of channels."""
-    sin = jnp.sin(angles)[None, :, None, :]
-    cos = jnp.cos(angles)[None, :, None, :]
+    """x: (B, S, H, D); angles: (S, D//2), or (B, S, D//2) where each
+    row of the batch sits at positions of its own (a serving tick's
+    slots) — rotate pairs of channels."""
+    heads = (None if angles.ndim == 2 else slice(None), slice(None), None)
+    sin = jnp.sin(angles)[heads]
+    cos = jnp.cos(angles)[heads]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -290,26 +293,29 @@ class Attention(nn.Module):
     # ``attention_window`` and rotary positions; else this layer's kind
     # of ``cfg.layer_types``
 
-    @nn.compact
-    def __call__(self, x, angles, offset=0):
+    # ``__call__`` is ``_qkv``, an attention inner, ``_out``; a caller
+    # with a cache of its own (serving/kvcache.py) puts ITS inner
+    # between ``qkv`` and ``out``
+
+    @nn.nowrap
+    def _heads(self, feats, name):
+        return nn.DenseGeneral(
+            feats, axis=-1, use_bias=False, dtype=self.cfg.dtype,
+            param_dtype=jnp.float32, name=name)
+
+    @nn.nowrap
+    def _qkv(self, x, angles):
         cfg = self.cfg
         H, D = cfg.n_heads, cfg.head_dim
         KV = cfg.kv_heads          # == H unless GQA/MQA configured
-        window, rope = cfg.attention_window, True
-        if self.layer_type is not None:
-            sliding = self.layer_type == "sliding_attention"
-            window = cfg.sliding_window if sliding else None
-            rope = sliding or cfg.rope_on_full_attention
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name=name)
-        q = dense((H, D), "wq")(x)
-        k = dense((KV, D), "wk")(x)
-        v = dense((KV, D), "wv")(x)
+        q = self._heads((H, D), "wq")(x)
+        k = self._heads((KV, D), "wk")(x)
+        v = self._heads((KV, D), "wv")(x)
         if cfg.qk_norm:
             q = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="q_norm")(q)
             k = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="k_norm")(k)
-        if rope:
+        if self.layer_type in (None, "sliding_attention") \
+                or cfg.rope_on_full_attention:
             q = apply_rope(q, angles)
             k = apply_rope(k, angles)
         if cfg.attention_multiplier is not None:
@@ -319,6 +325,38 @@ class Attention(nn.Module):
             # makes the factor 0.125, a power of two and exact in
             # bfloat16; another value rounds q once more
             q = q * (cfg.attention_multiplier * np.sqrt(D))
+        return q, k, v
+
+    @nn.nowrap
+    def _out(self, x, o):
+        cfg = self.cfg
+        if cfg.attention_gate:
+            o = o * nn.sigmoid(
+                self._heads((cfg.n_heads, cfg.head_dim), "wg")(x))
+        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
+                               dtype=cfg.dtype, param_dtype=jnp.float32,
+                               name="wo")(o)
+
+    @nn.compact
+    def qkv(self, x, angles):
+        """Before the inner: q ``(B, S, H, D)``, k and v at the KV
+        heads' own width ``(B, S, KV, D)``, never expanded."""
+        return self._qkv(x, angles)
+
+    @nn.compact
+    def out(self, x, o):
+        """After it: the gate on ``x``, the projection of ``o``."""
+        return self._out(x, o)
+
+    @nn.compact
+    def __call__(self, x, angles, offset=0):
+        cfg = self.cfg
+        H, D, KV = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+        window = cfg.attention_window
+        if self.layer_type is not None:
+            window = cfg.sliding_window \
+                if self.layer_type == "sliding_attention" else None
+        q, k, v = self._qkv(x, angles)
 
         def expand_kv(t):
             # training path only: each kv head serves H/KV query
@@ -382,11 +420,7 @@ class Attention(nn.Module):
                         f"support sliding windows)") from exc
             else:
                 o = self.attention_fn(q, expand_kv(k), expand_kv(v))
-        if cfg.attention_gate:
-            o = o * nn.sigmoid(dense((H, D), "wg")(x))
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
-                               dtype=cfg.dtype, param_dtype=jnp.float32,
-                               name="wo")(o)
+        return self._out(x, o)
 
 
 class SwiGLU(nn.Module):
@@ -468,21 +502,47 @@ class MoE(nn.Module):
 
 
 class DecoderBlock(nn.Module):
+    """One layer: ``__call__`` around the model's own attention;
+    ``qkv`` and ``finish`` are its two halves for a caller whose inner
+    goes around a cache of its own (serving/kvcache.py)."""
     cfg: TransformerConfig
     attention_fn: Callable = dense_causal_attention
     decode: bool = False
 
-    @nn.compact
-    def __call__(self, x, angles, offset=0):
+    @nn.nowrap
+    def _attn(self):
+        return Attention(self.cfg, self.attention_fn, self.decode,
+                         name="attn")
+
+    @nn.nowrap
+    def _ln_attn(self, x):
+        return RMSNorm(self.cfg.dtype, self.cfg.rms_norm_eps,
+                       name="ln_attn")(x)
+
+    @nn.nowrap
+    def _feed_forward(self, x, attended):
         cfg = self.cfg
-        eps = cfg.rms_norm_eps
-        x = _residual(cfg, x, Attention(
-            cfg, self.attention_fn, self.decode, name="attn")(
-            RMSNorm(cfg.dtype, eps, name="ln_attn")(x), angles, offset))
+        x = _residual(cfg, x, attended)
         mlp = MoE(cfg, name="moe") if cfg.num_experts else \
             SwiGLU(cfg, name="mlp")
-        return _residual(
-            cfg, x, mlp(RMSNorm(cfg.dtype, eps, name="ln_mlp")(x))), None
+        return _residual(cfg, x, mlp(RMSNorm(
+            cfg.dtype, cfg.rms_norm_eps, name="ln_mlp")(x)))
+
+    @nn.compact
+    def __call__(self, x, angles, offset=0):
+        return self._feed_forward(x, self._attn()(
+            self._ln_attn(x), angles, offset)), None
+
+    @nn.compact
+    def qkv(self, x, angles):
+        """``(h, q, k, v)``: the normed input, ``Attention.qkv`` of it."""
+        h = self._ln_attn(x)
+        return (h,) + self._attn().qkv(h, angles)
+
+    @nn.compact
+    def finish(self, x, h, o):
+        """The layer's output from the inner's ``o``."""
+        return self._feed_forward(x, self._attn().out(h, o))
 
 
 def _residual(cfg, x, branch):
@@ -869,6 +929,33 @@ def loop_device_sums(total_ut_steps):
         loop_exit_mass_sum(t + 1) for t in range(total_ut_steps))
 
 
+def embed_tokens(cfg, emb, tokens):
+    """``tokens``' rows of ``emb`` (V, M) under the config's
+    multipliers, in ``cfg.dtype``: the model's first step, also for a
+    caller that runs the layers itself (serving/kvcache.py)."""
+    with jax.named_scope("embed"):
+        x = emb[tokens]
+        if cfg.mup_enabled:
+            x = x * np.sqrt(cfg.d_model).astype(np.float32)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * np.float32(cfg.embedding_multiplier)
+        return x.astype(cfg.dtype)
+
+
+def lm_logits(cfg, x, head):
+    """Float32 logits of the normed states ``x`` against ``head``
+    (V, M), the tied embedding or ``lm_head``: the model's last step."""
+    # logits matmul in the activation dtype with f32 accumulation:
+    # a (B*S, M) @ (M, V) f32 matmul would run at a fraction of the
+    # MXU's bf16 rate and dominate the step at large vocab
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsm,vm->bsv", x, head.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
+
+
 class TransformerLM(nn.Module):
     """Token ids (B, S) -> logits (B, S, V)."""
     cfg: TransformerConfig
@@ -929,13 +1016,7 @@ class TransformerLM(nn.Module):
         head = emb if cfg.tie_word_embeddings else self.param(
             "lm_head", nn.initializers.normal(0.02),
             (cfg.vocab_size, cfg.d_model), jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb[tokens]
-            if cfg.mup_enabled:
-                x = x * np.sqrt(cfg.d_model).astype(np.float32)
-            if cfg.embedding_multiplier != 1.0:
-                x = x * np.float32(cfg.embedding_multiplier)
-            x = x.astype(cfg.dtype)
+        x = embed_tokens(cfg, emb, tokens)
         angles = jnp.asarray(
             rope_angles(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta))
         angles = jax.lax.dynamic_slice_in_dim(
@@ -998,16 +1079,7 @@ class TransformerLM(nn.Module):
             # beside the states, where the router has one, the mean over
             # the routed layers of its auxiliary loss
             return (x if aux_loss is None else (x, aux_loss)), head
-        # logits matmul in the activation dtype with f32 accumulation:
-        # a (B*S, M) @ (M, V) f32 matmul would run at a fraction of the
-        # MXU's bf16 rate and dominate the step at large vocab
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum("bsm,vm->bsv", x,
-                                head.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-            if cfg.logits_scaling != 1.0:
-                logits = logits / cfg.logits_scaling
-        return logits
+        return lm_logits(cfg, x, head)
 
 
 def make_generate_fn(model: "TransformerLM", *, max_new_tokens: int,

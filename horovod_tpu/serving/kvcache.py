@@ -56,11 +56,14 @@ inserts the quantized wire (:func:`pack_kv_blocks` /
 :func:`unpack_kv_blocks`) between the same two programs, so both
 deployments share one compiled vocabulary.
 
-Decode math mirrors :mod:`..models.transformer`'s flax decode path
-op for op (same einsum contractions, f32 score accumulation, RMSNorm
-epsilon, rope pairing), so continuous-batched greedy decode is
-token-identical to :func:`..models.transformer.make_generate_fn` —
-the parity property the tests and the serve smoke pin.
+What a layer, the embedding and the head COMPUTE is
+:mod:`..models.transformer`'s own code: each forward scans the stacked
+parameters with ``DecoderBlock``'s two halves (``qkv``, ``finish``)
+applied around the one thing that is serving's, the attention inner
+over the cache, so continuous-batched greedy decode is
+token-identical to :func:`..models.transformer.make_generate_fn` under
+every field of the config the layer reads — the parity property the
+tests and the serve smoke pin.
 """
 
 import functools
@@ -73,8 +76,8 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..models.transformer import (
-    apply_rope, dense_causal_attention, grouped_causal_attention,
-    rope_angles,
+    DecoderBlock, RMSNorm, dense_causal_attention, embed_tokens,
+    grouped_causal_attention, lm_logits, rope_angles,
 )
 from ..ops import compiled as compiled_mod
 from ..ops import paged_kernels, pallas_kernels
@@ -183,36 +186,6 @@ class KVBlockPool:
 # pure forwards (jitted once per bucket through the shared program cache)
 
 
-def _layer_stack(params):
-    """Per-layer param arrays in scan order, straight off the flax
-    tree ``TransformerLM.init`` produces (nn.scan stacks dim 0 = L)."""
-    lp = params["layers"]
-    return (lp["attn"]["wq"]["kernel"], lp["attn"]["wk"]["kernel"],
-            lp["attn"]["wv"]["kernel"], lp["attn"]["wo"]["kernel"],
-            lp["ln_attn"]["scale"], lp["ln_mlp"]["scale"],
-            lp["mlp"]["wi_gate"]["kernel"],
-            lp["mlp"]["wi_up"]["kernel"], lp["mlp"]["wo"]["kernel"])
-
-
-def _rmsnorm(x, scale, dtype):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(
-        jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
-    return (y * scale).astype(dtype)
-
-
-def _rope_rows(x, ang):
-    """Rotate (B, T, H, D) by per-row angles (B, T, D//2) — the
-    per-slot-position twin of transformer.apply_rope (each slot in the
-    running batch sits at its own offset)."""
-    sin = jnp.sin(ang)[:, :, None, :]
-    cos = jnp.cos(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
-
-
 def _paged_attention(q, k, v, q_pos, window):
     """q (B, 1, H, D) against gathered block views k/v (B, S, KV, D)
     with per-slot query positions (B,): valid keys are k_pos <= q_pos
@@ -235,43 +208,34 @@ def _paged_attention(q, k, v, q_pos, window):
     return o.reshape(B, T, H, D)
 
 
+def _logits(params, x, cfg):
+    """The model's final norm, then its head (the embedding where the
+    two are tied), over the last layer's output ``x``."""
+    x = RMSNorm(cfg.dtype, cfg.rms_norm_eps).apply(
+        {"params": params["ln_final"]}, x)
+    return lm_logits(cfg, x, params[
+        "embed" if cfg.tie_word_embeddings else "lm_head"])
+
+
 def _prefill_fwd(params, tokens, length, *, cfg, angles):
     """tokens (1, P) right-padded; returns the greedy token after
     position ``length - 1`` plus the roped per-layer K/V
-    ``(L, P, KV, D)`` (rows >= length are garbage ingest discards)."""
-    dt = cfg.dtype
-    emb = params["embed"]
-    x = emb[tokens].astype(dt)
+    ``(L, P, KV, D)`` (rows >= length are garbage ingest discards).
+    Attention is dense and causal over the bucket's own P rows."""
     ang = jnp.asarray(angles[:tokens.shape[1]])
-    kv_eq = cfg.kv_heads == cfg.n_heads
-    window = cfg.attention_window
+    attend = dense_causal_attention if cfg.kv_heads == cfg.n_heads \
+        else grouped_causal_attention
+    block = DecoderBlock(cfg)
 
     def body(x, layer):
-        wq, wk, wv, wo, s1, s2, wg, wu, w2 = layer
-        h = _rmsnorm(x, s1, dt)
-        q = jnp.einsum("bsm,mhd->bshd", h, wq.astype(dt))
-        k = jnp.einsum("bsm,mkd->bskd", h, wk.astype(dt))
-        v = jnp.einsum("bsm,mkd->bskd", h, wv.astype(dt))
-        q = apply_rope(q, ang)
-        k = apply_rope(k, ang)
-        if kv_eq:
-            o = dense_causal_attention(q, k, v, offset=0,
-                                       window=window)
-        else:
-            o = grouped_causal_attention(q, k, v, offset=0,
-                                         window=window)
-        x = x + jnp.einsum("bshd,hdm->bsm", o, wo.astype(dt))
-        h2 = _rmsnorm(x, s2, dt)
-        gate = jax.nn.silu(
-            jnp.einsum("bsm,mf->bsf", h2, wg.astype(dt)))
-        up = jnp.einsum("bsm,mf->bsf", h2, wu.astype(dt))
-        x = x + jnp.einsum("bsf,fm->bsm", gate * up, w2.astype(dt))
-        return x, (k[0], v[0])
+        layer = {"params": layer}
+        h, q, k, v = block.apply(layer, x, ang, method="qkv")
+        o = attend(q, k, v, offset=0, window=cfg.attention_window)
+        return block.apply(layer, x, h, o, method="finish"), (k[0], v[0])
 
-    x, (k_all, v_all) = jax.lax.scan(body, x, _layer_stack(params))
-    x = _rmsnorm(x, params["ln_final"]["scale"], dt)
-    logits = jnp.einsum("bsm,vm->bsv", x, emb.astype(dt),
-                        preferred_element_type=jnp.float32)
+    x, (k_all, v_all) = jax.lax.scan(
+        body, embed_tokens(cfg, params["embed"], tokens), params["layers"])
+    logits = _logits(params, x, cfg)
     last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1, axis=1)
     tok0 = jnp.argmax(last[:, 0], axis=-1).astype(jnp.int32)
     return tok0[0], k_all, v_all
@@ -329,13 +293,10 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
     kernel takes the carry as it is: any other block layout has to be
     the one the pools are STORED in, because a reshape of the carry
     that moves bytes between tiles copies a pool every tick."""
-    dt = cfg.dtype
     B, NB = tables.shape
     KV, D = cfg.kv_heads, cfg.head_dim
     pool_shape = k_pool.shape
     L, n_blocks = pool_shape[:2]
-    emb = params["embed"]
-    x = emb[toks].astype(dt)                       # (B, 1, M)
     ang = jnp.asarray(angles)[pos][:, None, :]     # (B, 1, D//2)
     blk = jnp.where(
         active,
@@ -343,16 +304,12 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
         0)
     off = jnp.where(active, pos % bt, 0)
     kernel = kernel_interpret(cfg, pool_shape, interpret)
+    block = DecoderBlock(cfg)
 
     def body(carry, layer):
         x, kp, vp, base = carry
-        (wq, wk, wv, wo, s1, s2, wg, wu, w2) = layer
-        h = _rmsnorm(x, s1, dt)
-        q = jnp.einsum("btm,mhd->bthd", h, wq.astype(dt))
-        k = jnp.einsum("btm,mkd->btkd", h, wk.astype(dt))
-        v = jnp.einsum("btm,mkd->btkd", h, wv.astype(dt))
-        q = _rope_rows(q, ang)
-        k = _rope_rows(k, ang)
+        layer = {"params": layer}
+        h, q, k, v = block.apply(layer, x, ang, method="qkv")
         kp = kp.at[blk + base, off].set(k[:, 0].astype(kp.dtype))
         vp = vp.at[blk + base, off].set(v[:, 0].astype(vp.dtype))
         if kernel is None:
@@ -363,23 +320,16 @@ def _decode_fwd(params, k_pool, v_pool, toks, pos, tables, active, *,
             o = paged_kernels.paged_decode_attention(
                 q[:, 0], kp, vp, tables, pos, base,
                 window=cfg.attention_window, interpret=kernel)[:, None]
-        x = x + jnp.einsum("bthd,hdm->btm", o, wo.astype(dt))
-        h2 = _rmsnorm(x, s2, dt)
-        gate = jax.nn.silu(
-            jnp.einsum("btm,mf->btf", h2, wg.astype(dt)))
-        up = jnp.einsum("btm,mf->btf", h2, wu.astype(dt))
-        x = x + jnp.einsum("btf,fm->btm", gate * up, w2.astype(dt))
+        x = block.apply(layer, x, h, o, method="finish")
         return (x, kp, vp, base + n_blocks), None
 
     flat = (L * n_blocks,) + pool_shape[2:]
     (x, k_pool, v_pool, _), _ = jax.lax.scan(
         body,
-        (x, k_pool.reshape(flat), v_pool.reshape(flat), jnp.int32(0)),
-        _layer_stack(params))
-    x = _rmsnorm(x, params["ln_final"]["scale"], dt)
-    logits = jnp.einsum("btm,vm->btv", x, emb.astype(dt),
-                        preferred_element_type=jnp.float32)
-    tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        (embed_tokens(cfg, params["embed"], toks),     # (B, 1, M)
+         k_pool.reshape(flat), v_pool.reshape(flat), jnp.int32(0)),
+        params["layers"])
+    tok = jnp.argmax(_logits(params, x, cfg)[:, 0], axis=-1).astype(jnp.int32)
     return tok, k_pool.reshape(pool_shape), v_pool.reshape(pool_shape)
 
 
@@ -395,6 +345,11 @@ class PagedKVPrograms:
 
     def __init__(self, cfg, *, max_slots, block_tokens, n_blocks,
                  prompt_buckets=None, donate=None, interpret=None):
+        if cfg.layer_types is not None:
+            raise ValueError(
+                "paged-KV serving holds one kind of layer in one cache: "
+                "a model with layer_types (layers of several kinds, "
+                "state-space layers, a looped model) has no KV-cache path")
         if cfg.num_experts:
             raise ValueError(
                 "paged-KV decode supports dense-MLP models only "
@@ -424,11 +379,9 @@ class PagedKVPrograms:
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
-        self._sig = (cfg.vocab_size, cfg.d_model, cfg.n_layers,
-                     cfg.n_heads, cfg.kv_heads, cfg.d_ff,
-                     cfg.max_seq_len, cfg.attention_window,
-                     cfg.rope_theta, jnp.dtype(cfg.dtype).name,
-                     self.max_slots, self.block_tokens, self.n_blocks)
+        # the whole config: every field of it the layer reads is traced
+        # into the programs
+        self._sig = (cfg, self.max_slots, self.block_tokens, self.n_blocks)
         # whether the decode programs' attention is the kernel's: static
         # a process, so decode() counts its ticks on the host
         self._interpret = interpret

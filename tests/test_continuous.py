@@ -9,6 +9,7 @@ decode-replica kill, the ``after_decodes`` chaos trigger, and the
 TTFT/tokens-per-sec SLO signals the autoscaler and fleet controller
 read."""
 
+import dataclasses
 import functools
 import json
 import os
@@ -125,8 +126,41 @@ def test_kv_pool_publishes_gauge():
 
 # -- parity: continuous decode vs the unbatched flax generate path ----------
 
-def test_continuous_matches_unbatched_generate(bundle):
-    cfg, model, params, progs = bundle
+# one field of the model a case, each away from its default: what the
+# layer, the embedding and the head compute is models/transformer.py's
+# own code around serving's cache, so a served stream is the model's
+SERVED_FIELDS = [
+    {}, {"rms_norm_eps": 1e-5}, {"rms_norm_eps": 0.5}, {"qk_norm": True},
+    {"attention_gate": True}, {"residual_multiplier": 0.3},
+    {"attention_multiplier": 0.05}, {"embedding_multiplier": 3.0},
+    {"tie_word_embeddings": False},
+]
+
+
+def _served(bundle, fields):
+    """``bundle`` with ``fields`` set and every weight moved off its
+    initial value (a norm's scale starts at one: a norm or a gate left
+    out has to show)."""
+    if not fields:
+        return bundle
+    cfg = dataclasses.replace(bundle[0], **fields)
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    params = jax.tree.unflatten(tree, [
+        leaf * (1.0 + 0.3 * jax.random.normal(key, leaf.shape, leaf.dtype))
+        for leaf, key in zip(leaves, keys)])
+    return cfg, model, params, PagedKVPrograms(
+        cfg, max_slots=3, block_tokens=8, n_blocks=24)
+
+
+@pytest.mark.parametrize(
+    "fields", SERVED_FIELDS,
+    ids=lambda f: "-".join(f"{k}={v}" for k, v in f.items()) or "base")
+def test_continuous_matches_unbatched_generate(bundle, fields):
+    cfg, model, params, progs = _served(bundle, fields)
     gen = make_generate_fn(model, max_new_tokens=6)
     refs = [np.asarray(gen(params, jnp.asarray(
         [p], jnp.int32)))[0].tolist() for p in PROMPTS]
@@ -349,6 +383,21 @@ def test_paged_programs_reject_moe():
                             n_heads=2, d_ff=16, max_seq_len=16,
                             num_experts=4, dtype=jnp.float32)
     with pytest.raises(ValueError, match="dense-MLP"):
+        PagedKVPrograms(cfg, max_slots=1, block_tokens=4, n_blocks=4)
+
+
+@pytest.mark.parametrize("fields", [
+    dict(layer_types=("mamba", "full_attention"), mamba_n_heads=2,
+         mamba_d_head=4),
+    dict(layer_types=("full_attention",) * 2, total_ut_steps=2),
+], ids=["mamba", "looped"])
+def test_paged_programs_refuse_layer_types(fields):
+    """Models the paged cache cannot hold are refused by the field's
+    name, not served by programs that cannot be right."""
+    cfg = TransformerConfig(vocab_size=8, d_model=8, n_layers=2,
+                            n_heads=2, d_ff=16, max_seq_len=16,
+                            dtype=jnp.float32, **fields)
+    with pytest.raises(ValueError, match="layer_types"):
         PagedKVPrograms(cfg, max_slots=1, block_tokens=4, n_blocks=4)
 
 

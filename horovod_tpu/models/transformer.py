@@ -58,6 +58,11 @@ from ..ops import device_sums, grad_hook
 from .mamba import SSM_DEVICE_SUMS, Mamba2Mixer
 from .mamba import KEPT as SSD_KEPT
 
+#: the names the flash kernels' forward outputs are checkpointed under
+#: (``ops/pallas_kernels.py::_flash_vjp_fwd``; the dense reference inner
+#: has no such names): every remat policy keeps them (``_with_remat``)
+FLASH_KEPT = ("flash_out", "flash_lse")
+
 
 @dataclass(frozen=True)
 class TransformerConfig:
@@ -87,14 +92,19 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
     remat: bool = False       # jax.checkpoint each block (HBM <-> FLOPs)
-    remat_policy: str = "full"  # "full" recomputes everything (but a
-    # mamba layer's scan output and states, which every policy keeps);
-    # "dots" saves matmul outputs (jax dots_with_no_batch_dims_saveable)
-    # so the backward pass skips re-running the MXU work — ~400MB *
-    # n_layers of HBM at (B=8, S=2048, d=1024) for the ~33% remat
-    # recompute FLOPs; "dots_flash" also saves the flash kernels'
-    # outputs (out + lse, checkpoint-named); both keep a routed layer's
-    # named products (``_with_remat``), which are no dots either
+    remat_policy: str = "full"  # every policy keeps every Pallas
+    # kernel's outputs by name, so a replay runs no kernel again: the
+    # flash kernels' out + lse, a mamba layer's scan output and states.
+    # "full" recomputes everything else from the layer's input; with the
+    # flash inner it so holds the kernel's output beside that input, as
+    # large again (+ lse, 1/64 of it at head_dim 128): still the policy
+    # for when memory is shortest, an order of magnitude under "dots".
+    # "dots" also saves matmul outputs (jax
+    # dots_with_no_batch_dims_saveable) so the backward pass skips
+    # re-running the MXU work — ~400MB * n_layers of HBM at (B=8,
+    # S=2048, d=1024) for the ~33% remat recompute FLOPs — and a routed
+    # layer's named products (``_with_remat``), which are no dots
+    # either; "dots_flash" is the same policy under its older name
     head_dim: Optional[int] = None     # None => d_model // n_heads
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = True   # False => an output head of its
@@ -763,23 +773,28 @@ def _with_remat(block, cfg, prevent_cse=False):
     from ..parallel.moe import KEPT_OUTPUT, KEPT_PRODUCTS
 
     # what a policy keeps beside the dense products, by the names the
-    # kernels' outputs are checkpointed under: neither a pallas call
+    # values are checkpointed under: neither a pallas call
     # (ops/pallas_kernels.py) nor a grouped product (parallel/moe.py)
     # is a dot, so without its name the backward replay runs it again
     routed = KEPT_PRODUCTS + (KEPT_OUTPUT,)
-    # a model with mamba layers keeps their scans' outputs and chunk
-    # states under every policy, "full" too (2 x 67 MB a layer at 8,192
-    # tokens: the replay runs no scan); no other program sees the names
+    # one rule for the Pallas kernels: EVERY policy keeps their outputs,
+    # "full" too, and a replay runs no kernel's forward again.  The
+    # flash kernels' out + lse are a layer's input over again (16.8 +
+    # 0.26 MB a layer application at 4,096 tokens x 2,048) for half the
+    # forward kernel's time; the names exist only where the attention
+    # inner is the flash kernel.  A model with mamba layers keeps their
+    # scans' outputs and chunk states (2 x 67 MB a layer at 8,192
+    # tokens); no other program sees those names
     scans = SSD_KEPT if "mamba" in (cfg.layer_types or ()) else ()
-    kept = {"full": scans, "dots": routed + scans,
-            "dots_flash": ("flash_out", "flash_lse") + routed + scans}
+    kernels = FLASH_KEPT + scans
+    dots = kernels + routed     # one policy under two names
+    kept = {"full": kernels, "dots": dots, "dots_flash": dots}
     if cfg.remat_policy not in kept:
         raise ValueError(
             f"remat_policy must be 'full', 'dots', or 'dots_flash', "
             f"got {cfg.remat_policy!r}")
-    names = kept[cfg.remat_policy]
-    policy = jax.checkpoint_policies.save_only_these_names(*names) \
-        if names else None
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *kept[cfg.remat_policy])
     if cfg.remat_policy != "full":
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,

@@ -534,10 +534,10 @@ def _flash_vjp_fwd(qf, kf, vf, block_q, block_k, bwd_block_q,
                    bwd_block_k, window, interpret):
     out, lse = _flash_fwd_call(qf, kf, vf, block_q, block_k, window,
                                interpret)
-    # named so a checkpoint policy can SAVE the kernel's outputs:
-    # they are a pallas custom call, not a dot, so the "dots" policy
-    # alone re-runs every flash forward during the backward replay
-    # (models/transformer.py remat_policy="dots_flash" saves them)
+    # named so a checkpoint policy can SAVE the kernel's outputs: they
+    # are a pallas custom call, not a dot, so no policy of dots keeps
+    # them.  Every remat policy of models/transformer.py does, by these
+    # names (``_with_remat``), and its backward replay runs no kernel
     from jax.ad_checkpoint import checkpoint_name
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")

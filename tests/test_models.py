@@ -1,5 +1,7 @@
 """Model zoo smoke + correctness tests (single device)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -290,35 +292,105 @@ def test_chunked_lm_loss_projects_each_chunk_once():
         jax.jvp(lambda p: fused(p, tokens), (params,), (params,))
 
 
-def test_remat_dots_flash_matches_dots():
-    """remat_policy='dots_flash' (save the checkpoint-named flash
-    kernel outputs so the backward replay skips the pallas forward)
-    computes identical loss and grads to 'dots'."""
-    from horovod_tpu.models import make_fused_lm_loss
+def _flash_lm(flash=True, **changes):
+    """A three-layer model (ONE scan's body) on the flash inner, the
+    interpreter's here, or on the dense reference inner."""
     from horovod_tpu.ops.pallas_kernels import flash_attention
 
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=32, n_layers=3, n_heads=2, d_ff=64,
+        max_seq_len=32, dtype=jnp.float32, **changes)
+    model = TransformerLM(cfg, attention_fn=flash_attention if flash
+                          else dense_causal_attention)
     toks = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0, 128)
-    out = {}
-    for pol in ("dots", "dots_flash"):
-        cfg = TransformerConfig(
-            vocab_size=128, d_model=32, n_layers=2, n_heads=2, d_ff=64,
-            max_seq_len=32, dtype=jnp.float32, remat=True,
-            remat_policy=pol)
-        model = TransformerLM(cfg, attention_fn=flash_attention)
-        params = model.init(jax.random.PRNGKey(1), toks)["params"]
-        out[pol] = jax.jit(jax.value_and_grad(
+    return model, model.init(jax.random.PRNGKey(1), toks)["params"], toks
+
+
+def _remat(policy):
+    return {"remat": False} if policy is None \
+        else {"remat": True, "remat_policy": policy}
+
+
+REMAT_POLICIES = ["full", "dots", "dots_flash", None]
+
+
+@pytest.mark.parametrize("policy", REMAT_POLICIES)
+def test_every_remat_policy_gives_the_flash_inners_loss_and_gradients(
+        policy):
+    """Every policy keeps the flash kernels' outputs (out + lse,
+    checkpoint-named) and the backward pass reads the kept values where
+    a replay would compute them again: the loss and gradients of the
+    flash inner without remat.  Without remat: those of the dense
+    reference inner."""
+    from horovod_tpu.models import make_fused_lm_loss
+
+    def run(flash, **changes):
+        model, params, toks = _flash_lm(flash, **changes)
+        return jax.jit(jax.value_and_grad(
             make_fused_lm_loss(model, 4)))(params, toks)
-    assert abs(float(out["dots"][0]) - float(out["dots_flash"][0])) \
-        < 1e-6
-    for a, b in zip(jax.tree.leaves(out["dots"][1]),
-                    jax.tree.leaves(out["dots_flash"][1])):
+
+    got = run(True, **_remat(policy))
+    want = run(policy is not None, remat=False)
+    assert abs(float(got[0]) - float(want[0])) < 1e-6
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
+
+def test_an_unknown_remat_policy_is_refused():
     with pytest.raises(ValueError, match="remat_policy"):
         cfg = TransformerConfig(
             vocab_size=128, d_model=32, n_layers=2, n_heads=2, d_ff=64,
             max_seq_len=32, remat=True, remat_policy="bogus")
-        TransformerLM(cfg).init(jax.random.PRNGKey(1), toks)
+        TransformerLM(cfg).init(jax.random.PRNGKey(1),
+                                jnp.zeros((2, 32), jnp.int32))
+
+
+def _flash_calls(**changes):
+    """``(flash_fwd, flash_dkv)`` calls in the gradient of the model's
+    jaxpr: the forward's scan body and the backward's, each once."""
+    model, params, toks = _flash_lm(**changes)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: model.apply({"params": p}, toks).sum()))(params))
+    return tuple(len(re.findall(rf"name={name}\b", text))
+                 for name in ("flash_fwd", "flash_dkv"))
+
+
+@pytest.mark.parametrize("policy", REMAT_POLICIES)
+def test_a_remat_replay_runs_no_flash_kernel(policy):
+    """One rule for the Pallas kernels (the scan's:
+    ``test_ssd_kernels.py::test_a_remat_replay_runs_no_scan``): every
+    policy keeps the forward kernel's two outputs by name, so the
+    gradient holds one ``flash_fwd`` and one ``flash_dkv`` a layer body
+    and no second forward, as without remat.  With the names NOT kept
+    the replay runs the forward again (the next test: the count can
+    tell)."""
+    assert _flash_calls(**_remat(policy)) == (1, 1)
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_a_replay_without_the_kept_outputs_runs_the_flash_forward_again(
+        monkeypatch, policy):
+    from horovod_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "FLASH_KEPT", ())
+    assert _flash_calls(remat=True, remat_policy=policy) == (2, 1)
+
+
+def test_full_remat_keeps_a_layers_input_and_the_flash_outputs_alone(
+        capsys):
+    """What ``full`` holds of a layer application: its input, the
+    forward kernel's output of the same size (heads x head_dim wide)
+    and the row sums' logarithms (a float32 a head and position), each
+    stacked over the three layers, and no other value of a layer."""
+    model, params, toks = _flash_lm(remat=True)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p: model.apply({"params": p}, toks).sum(), params)
+    kept = sorted(
+        line.split(" ")[0] for line in capsys.readouterr().out.splitlines()
+        if "from the argument" not in line and line.startswith("f32[3,"))
+    # (layers, B, S, d_model); (layers, B x heads, S, head_dim);
+    # (layers, B x heads, 1, S)
+    assert kept == ["f32[3,2,32,32]", "f32[3,4,1,32]", "f32[3,4,32,16]"]
 
 
 def test_transformer_scan_layer_axis(tiny_lm):

@@ -199,6 +199,8 @@ class Mamba2Mixer(nn.Module):
     int32 (3,) are the tokens and the chunks the scan processed and the
     chunks of those the kernel pair took (``SSM_DEVICE_SUMS``)."""
     cfg: Any      # a TransformerConfig
+    dot_general: Any = None    # the two projections' product (None:
+    # flax's own), as ``transformer.Attention``'s
 
     @nn.compact
     def __call__(self, h):
@@ -214,7 +216,8 @@ class Mamba2Mixer(nn.Module):
 
         def dense(feats, name):
             return nn.Dense(feats, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=jnp.float32, name=name)
+                            param_dtype=jnp.float32,
+                            dot_general=self.dot_general, name=name)
 
         z, xbc, dt = jnp.split(dense(2 * inner + 2 * bc + heads,
                                      "in_proj")(h),

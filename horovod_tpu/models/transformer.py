@@ -55,6 +55,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import device_sums, grad_hook
+from .dense import WRITTEN_BACKWARD_ROWS, dense_product
 from .mamba import SSM_DEVICE_SUMS, Mamba2Mixer
 from .mamba import KEPT as SSD_KEPT
 
@@ -302,6 +303,8 @@ class Attention(nn.Module):
     layer_type: Optional[str] = None   # None: the model-wide
     # ``attention_window`` and rotary positions; else this layer's kind
     # of ``cfg.layer_types``
+    dot_general: Optional[Callable] = None    # the projections' product
+    # (None: flax's own): ``LayerPeriod`` chooses
 
     # ``__call__`` is ``_qkv``, an attention inner, ``_out``; a caller
     # with a cache of its own (serving/kvcache.py) puts ITS inner
@@ -311,7 +314,7 @@ class Attention(nn.Module):
     def _heads(self, feats, name):
         return nn.DenseGeneral(
             feats, axis=-1, use_bias=False, dtype=self.cfg.dtype,
-            param_dtype=jnp.float32, name=name)
+            param_dtype=jnp.float32, dot_general=self.dot_general, name=name)
 
     @nn.nowrap
     def _qkv(self, x, angles):
@@ -345,7 +348,7 @@ class Attention(nn.Module):
                 self._heads((cfg.n_heads, cfg.head_dim), "wg")(x))
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=jnp.float32,
-                               name="wo")(o)
+                               dot_general=self.dot_general, name="wo")(o)
 
     @nn.compact
     def qkv(self, x, angles):
@@ -436,6 +439,7 @@ class Attention(nn.Module):
 class SwiGLU(nn.Module):
     cfg: TransformerConfig
     d_ff: Optional[int] = None    # None => cfg.d_ff
+    dot_general: Optional[Callable] = None    # as ``Attention``'s
 
     @nn.compact
     def __call__(self, x):
@@ -443,7 +447,7 @@ class SwiGLU(nn.Module):
         d_ff = self.d_ff or cfg.d_ff
         dense = lambda feats, name: nn.Dense(  # noqa: E731
             feats, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name=name)
+            param_dtype=jnp.float32, dot_general=self.dot_general, name=name)
         gate = nn.silu(dense(d_ff, "wi_gate")(x))
         up = dense(d_ff, "wi_up")(x)
         return dense(cfg.d_model, "wo")(gate * up)
@@ -625,6 +629,7 @@ class RoutedExperts(nn.Module):
     on held experts, those of them not computed, the passes through the
     buffer and those of them beyond the first (``MOE_DEVICE_SUMS``)."""
     cfg: TransformerConfig
+    dot_general: Optional[Callable] = None    # the shared experts'
 
     @nn.compact
     def __call__(self, x, routing=None, route_only=False):
@@ -682,7 +687,7 @@ class RoutedExperts(nn.Module):
                             moe_mod.KEPT_OUTPUT)
         if cfg.num_shared_experts:
             y = y + SwiGLU(cfg, F * cfg.num_shared_experts,
-                           name="shared")(x)
+                           self.dot_general, name="shared")(x)
         return y, _layer_sums(cfg, counts, **balance)
 
 
@@ -695,6 +700,7 @@ class LayeredBlock(nn.Module):
     attention_fn: Callable
     layer_type: str
     routed: bool
+    dot_general: Optional[Callable] = None    # every dense projection's
 
     @nn.compact
     def __call__(self, x, angles):
@@ -705,7 +711,7 @@ class LayeredBlock(nn.Module):
 
         routing = None
         if self.routed:
-            moe = RoutedExperts(cfg, name="moe")
+            moe = RoutedExperts(cfg, self.dot_general, name="moe")
             if cfg.router_before_attention:
                 # issued ahead of attention, under the routed layer's
                 # own scope ``moe/route`` all the same
@@ -714,12 +720,14 @@ class LayeredBlock(nn.Module):
         if self.layer_type == "mamba":
             # the module's name is the scope: ``attn`` would book the
             # mixer to the attention's device time
-            h, ssm = Mamba2Mixer(cfg, name="mamba")(norm("ln_mamba")(x))
+            h, ssm = Mamba2Mixer(cfg, self.dot_general, name="mamba")(
+                norm("ln_mamba")(x))
         else:
             # the device trace's path carries the layer's published kind
             with jax.named_scope(self.layer_type):
                 h = Attention(cfg, self.attention_fn,
                               layer_type=self.layer_type,
+                              dot_general=self.dot_general,
                               name="attn")(norm("ln_attn")(x), angles)
         if cfg.sandwich_norm:
             h = norm("ln_post_attn")(h)
@@ -728,7 +736,8 @@ class LayeredBlock(nn.Module):
         if self.routed:
             f, sums = moe(m, routing)
         else:
-            f, sums = SwiGLU(cfg, name="mlp")(m), _layer_sums(
+            f, sums = SwiGLU(cfg, dot_general=self.dot_general,
+                             name="mlp")(m), _layer_sums(
                 cfg, jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32),
                 jnp.float32(0), jnp.int32(0))
         if "ssm" in sums:
@@ -755,10 +764,14 @@ class LayerPeriod(nn.Module):
         # pass and keep every activation after all
         block = _with_remat(LayeredBlock, self.cfg,
                             prevent_cse=self.repeats == 1)
+        # the projections' product by the rows a step's product
+        # contracts over (models/dense.py has the measurements)
+        product = dense_product if x.shape[0] * x.shape[1] \
+            <= WRITTEN_BACKWARD_ROWS else None
         sums = _layer_sums(self.cfg)
         for i, kind in enumerate(self.layer_types):
             x, layer = block(self.cfg, self.attention_fn, kind, self.routed,
-                             name=f"layer_{i}")(x, angles)
+                             product, name=f"layer_{i}")(x, angles)
             sums = jax.tree.map(jnp.add, sums, layer)
         return x, sums
 

@@ -2,7 +2,8 @@
 the hash of its StableHLO (to show that a change leaves a cell's program
 alone: run from both trees at ONE path and compare), and with
 ``--compile`` the chip's compiler's own program, where every gather
-shows the memory space of its table and the strategy it took.
+shows the memory space of its table and the strategy it took, and every
+large float32 copy whether it changes the layout of what it copies.
 
     python tools/described_step.py <cell> [--compile] [--out <file>]
                                                   (cwd = a checkout)
@@ -17,14 +18,27 @@ A gather of rows is a ``kind=kCustom`` fusion with ``gather`` in its
 operand's layout (``bf16[30720,1280]{1,0:T(8,128)(2,1)S(1)}``) is the
 on-chip memory, no ``S(...)`` is HBM; the fusion's ``integer_config`` is
 0 for the fast strategy over a table in ``S(1)`` and 128 for the one
-over HBM (6 against 46 ns a row on the chip, PERF.md PR 35).  Nothing
-runs here, so this gives no times.
+over HBM (6 against 46 ns a row on the chip, PERF.md PR 35).
+
+A ``copy`` whose result's layout (the ``{1,2,0`` of
+``f32[1,2048,8192]{1,2,0:T(8,128)}``: minor to major) differs from its
+operand's is a transpose through HBM.  Where a leaf of the state is
+copied so on the way into the optimizer's fusion and back on the way
+out, the gradient reached that fusion in another layout than the
+state's: a ``transpose`` after the weight-gradient product, folded into
+the product's result (PERF.md section 6, PR 49;
+``models/dense.dense_product`` is the cure).  ``layout_copies`` lists
+them, each with the leaf of the state it copies where its name, its
+operand or the output it becomes says so.  Nothing runs here, so this
+gives no times.
 """
 
 import argparse
+import collections
 import hashlib
 import importlib
 import json
+import math
 import os
 import re
 import sys
@@ -126,6 +140,74 @@ def gathers(text):
     return found
 
 
+TYPE = re.compile(r"(\w+)\[([\d,]*)\](?:\{([\d,]*))?")
+STATE_LEAF = re.compile(r"state[\W_]+(params|opt_state[\W_]+0[\W_]+(mu|nu))[\W_]")
+
+
+def _state_leaf(name):
+    """``parameter`` / ``mu`` / ``nu`` for a leaf of the step's state by
+    its name, as a parameter's instruction or an ``op_name`` spells it
+    (``state['opt_state'][0].mu['embed']``,
+    ``state__opt_state___0__mu__embed__.1``), else None."""
+    match = STATE_LEAF.match(name)
+    return match and (match.group(2) or "parameter")
+
+
+def layout_copies(text, min_bytes=4_000_000):
+    """[(instruction, result's type, operand's layout, what, bytes,
+    fused)] of every ``copy`` of a compiled program's text whose result
+    is float32, at least ``min_bytes`` large and laid out otherwise than
+    its operand.  ``what`` is ``parameter``, ``mu``, ``nu`` or
+    ``other``: the leaf of the state the copy's ``op_name`` or its
+    operand names, or (a copy of a result, which carries no name) the
+    leaf that the output it becomes is donated from.  ``fused`` is
+    whether it sits inside a fusion's computation: a transposed read or
+    write of that fusion, and no pass over HBM of its own."""
+    types, parameters, outputs, copies = {}, {}, [], []
+    entry = fused = False
+    for line in text.splitlines():
+        if line[:1] not in (" ", "}", ""):      # a computation's header
+            entry = line.startswith("ENTRY ")
+            fused = "fus" in line.split("(")[0]
+        match = INSTRUCTION.match(line)
+        if not match:
+            continue
+        name = match.group(1)
+        types[name] = match.group(2)
+        number = re.search(r" parameter\((\d+)\)", line)
+        if entry and number:
+            parameters[int(number.group(1))] = _state_leaf(name)
+        if entry and line.lstrip().startswith("ROOT ") and " tuple(" in line:
+            outputs = [operand.strip().lstrip("%") for operand in re.sub(
+                r"/\*.*?\*/", "", line.split(" tuple(", 1)[1].split(")")[0]
+            ).split(",")]
+        operand = re.search(r" copy\(%?([\w.\-]+)\)", line)
+        if operand:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            copies.append((name, operand.group(1), fused,
+                           op_name.group(1).replace("\\", "")
+                           if op_name else ""))
+    donated = {int(out): int(number) for out, number in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", text.split("\n", 1)[0])}
+    found = []
+    for name, operand, fused, op_name in copies:
+        result = TYPE.match(types[name])
+        source = TYPE.match(types.get(operand, ""))
+        if not result or not source or result.group(1) != "f32" \
+                or result.group(3) == source.group(3):
+            continue
+        size = 4 * math.prod(
+            int(extent) for extent in result.group(2).split(",") if extent)
+        if size < min_bytes:
+            continue
+        what = _state_leaf(op_name) or _state_leaf(operand)
+        if not what and name in outputs:
+            what = parameters.get(donated.get(outputs.index(name)))
+        found.append((name, types[name].split(":")[0] + "}", source.group(3),
+                      what or "other", size, fused))
+    return found
+
+
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("cell")
@@ -151,6 +233,20 @@ def main():
         copies = [line.strip()[:110] for line in text.splitlines()
                   if re.search(r" = bf16\[\d+,\d+,\d+\]\S* reshape\(", line)]
         print("  reshapes that are instructions of their own:", copies)
+        counts, sizes, shapes = (collections.Counter() for _ in range(3))
+        for _, result, _, what, size, fused in layout_copies(text):
+            for key in ("fused",) if fused else (what, "all"):
+                counts[key] += 1
+                sizes[key] += size
+            shapes[result.split("{")[0] + (" fused" if fused else "")] += 1
+        print("  float32 copies of 4 MB or more that change the layout,"
+              " by what they copy:")
+        for what in ("parameter", "mu", "nu", "other", "all", "fused"):
+            print(f"    {what:9s} {counts[what]:4d} copies "
+                  f"{sizes[what] / 1e9:7.2f} GB" + (
+                "  (inside a fusion: no pass over HBM of their own, and"
+                " in no row above)" if what == "fused" else ""))
+        print("    shapes:", dict(shapes))
     if args.out:
         with open(args.out, "w") as f:
             f.write(text)

@@ -161,9 +161,11 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 class CausalConv(nn.Module):
     """``silu(causal depthwise convolution + bias)`` over the positions
     of (B, S, C): position t reads t - width + 1 .. t of its own
-    channel, zeros before the row's start."""
+    channel, zeros before the row's start.  ``use_bias`` off: no bias
+    (models/kda.py's three)."""
     width: int
     dtype: Any
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -175,7 +177,8 @@ class CausalConv(nn.Module):
         back = jnp.pad(x, ((0, 0), (self.width - 1, 0), (0, 0)))
         y = sum(back[:, k:k + seq].astype(jnp.float32) * kernel[k]
                 for k in range(self.width))
-        y = y + self.param("bias", init, (x.shape[-1],), jnp.float32)
+        if self.use_bias:
+            y = y + self.param("bias", init, (x.shape[-1],), jnp.float32)
         return nn.silu(y).astype(self.dtype)
 
 

@@ -38,6 +38,13 @@ TPU-first choices:
   norm, out-projection), in one period with attention layers; and four
   muP-style multipliers on the embedding, the residual branches, the
   attention scores and the logits.
+* Two more kinds (Kimi-Linear's ``kimi_linear``): ``"kda"``, a gated
+  delta rule (``models/kda.py``: a state a head that decays by a rate a
+  key channel and is corrected by a rank-one delta, in its chunked
+  form), and ``"mla"``, latent attention (``MLAAttention``: keys and
+  values expanded from one normed low-rank latent, a key part shared by
+  the heads, queries and keys wider than the values, no position
+  encoding).
 * A looped model (``total_ut_steps`` > 1, the key of Ouro's published
   ``config.json``): that stack applied several times over the same
   weights, an exit after every pass, and in ``make_fused_lm_loss`` the
@@ -56,6 +63,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import device_sums, grad_hook
 from .dense import WRITTEN_BACKWARD_ROWS, dense_product
+from .kda import KDA_DEVICE_SUMS, KDAMixer
+from .kda import KEPT as KDA_KEPT
 from .mamba import SSM_DEVICE_SUMS, Mamba2Mixer
 from .mamba import KEPT as SSD_KEPT
 
@@ -122,7 +131,8 @@ class TransformerConfig:
     # model is the one identical block above and these are refused
     layer_types: Optional[tuple] = None   # per layer "sliding_attention"
     # (sees the last ``sliding_window`` positions) | "full_attention" |
-    # "mamba" (no attention: the state-space mixer below)
+    # "mamba" (no attention: the state-space mixer below) | "kda" (none
+    # either: the delta rule below) | "mla" (latent attention, below)
     sliding_window: Optional[int] = None
     rope_on_full_attention: bool = True   # False: rotary positions on
     # the sliding layers only
@@ -186,6 +196,31 @@ class TransformerConfig:
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # -- a delta-rule layer ("kda" in ``layer_types``): the mixer of
+    # models/kda.py in attention's place, ``kda_n_heads`` heads with a
+    # (kda_d_head, kda_d_head) state each and causal convolutions of
+    # ``kda_d_conv`` taps (None: 4); the keys mirror the published
+    # ``linear_attn_config``.  ``kda_chunk_size`` (None: 64; a power of
+    # two) is the chunk of the rule's chunked form: the program's to
+    # choose, it changes no result beyond rounding.  Each key is refused
+    # in a model with no such layer
+    kda_n_heads: Optional[int] = None
+    kda_d_head: Optional[int] = None
+    kda_d_conv: Optional[int] = None
+    kda_chunk_size: Optional[int] = None
+    # -- a latent-attention layer ("mla" in ``layer_types``;
+    # ``MLAAttention``): ``n_heads`` heads whose keys and values are
+    # expanded from ONE normed latent of ``kv_lora_rank`` a token: a key
+    # part of ``qk_nope_head_dim`` and a value of ``v_head_dim`` a head,
+    # beside a key part of ``qk_rope_head_dim`` that all the heads share
+    # (the published key's name; this model applies NO rotary position
+    # to it, Kimi-Linear's ``mla_use_nope``).  Queries are projected
+    # from the layer's input at the keys' width (``q_lora_rank`` null).
+    # Each key is refused in a model with no such layer
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
     # -- muP-style multipliers (Granite's ``config.json``), each a no-op
     # at its default: the embedding times ``embedding_multiplier``, every
     # layer's two branch outputs times ``residual_multiplier`` before
@@ -436,6 +471,58 @@ class Attention(nn.Module):
         return self._out(x, o)
 
 
+class MLAAttention(nn.Module):
+    """Latent attention without position encoding (the ``"mla"`` kind;
+    DeepSeek-V2's multi-head latent attention as Kimi-Linear runs it)::
+
+        q = x Wq -> H heads of (nope + rope)
+        [c | k_s] = x Wkv_a   (kv_lora_rank | rope);  c = rmsnorm(c)
+        [k_n | v] = c Wkv_b -> H heads of (nope | v_head_dim)
+        k = [k_n | k_s for every head]
+        out = concat(causal_softmax(q k^T / sqrt(nope + rope)) v) Wo
+
+    The attention inners take ONE head size: ``v`` goes in filled up
+    with zeros to the keys' width and the output's filled columns are
+    dropped, which is exact (a zero column of ``v`` is a zero column of
+    ``P v``) and costs the ``P v`` products (nope + rope) / v_head_dim
+    times their FLOPs; the inner's own 1 / sqrt(head size) is then the
+    published scale."""
+    cfg: TransformerConfig
+    attention_fn: Callable = dense_causal_attention
+    dot_general: Optional[Callable] = None    # as ``Attention``'s
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        heads, rank = cfg.n_heads, cfg.kv_lora_rank
+        nope, shared = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        value = cfg.v_head_dim
+        if not rank or not nope or not value or shared is None \
+                or value > nope + shared:
+            raise ValueError(
+                "an mla layer needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and a v_head_dim no wider than its "
+                f"keys, got {rank}, {nope}, {shared}, {value}")
+
+        def dense(feats, name, axis=-1):
+            return nn.DenseGeneral(
+                feats, axis=axis, use_bias=False, dtype=cfg.dtype,
+                param_dtype=jnp.float32, dot_general=self.dot_general,
+                name=name)
+
+        q = dense((heads, nope + shared), "wq")(x)
+        latent, k_shared = jnp.split(dense(rank + shared, "kv_a")(x),
+                                     (rank,), axis=-1)
+        latent = RMSNorm(cfg.dtype, cfg.rms_norm_eps, name="kv_norm")(latent)
+        k_own, v = jnp.split(dense((heads, nope + value), "kv_b")(latent),
+                             (nope,), axis=-1)
+        k = jnp.concatenate([k_own, jnp.broadcast_to(
+            k_shared[:, :, None, :], k_own.shape[:-1] + (shared,))], axis=-1)
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + shared - value),))
+        o = self.attention_fn(q, k, v)[..., :value]
+        return dense(cfg.d_model, "wo", axis=(-2, -1))(o)
+
+
 class SwiGLU(nn.Module):
     cfg: TransformerConfig
     d_ff: Optional[int] = None    # None => cfg.d_ff
@@ -566,7 +653,26 @@ def _residual(cfg, x, branch):
     return x + branch
 
 
-LAYER_TYPES = ("sliding_attention", "full_attention", "mamba")
+LAYER_TYPES = ("sliding_attention", "full_attention", "mamba", "kda", "mla")
+
+#: the configuration keys that only one kind of layer reads: set in a
+#: model with no layer of that kind, each is refused by name
+_KEYS_OF_KIND = {
+    "kda": ("kda_n_heads", "kda_d_head", "kda_d_conv", "kda_chunk_size"),
+    "mla": ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim"),
+}
+
+
+def _check_keys_of_kinds(cfg):
+    kinds = cfg.layer_types or ()
+    for kind, keys in _KEYS_OF_KIND.items():
+        unread = [key for key in keys if getattr(cfg, key) is not None]
+        if kind not in kinds and unread:
+            raise ValueError(
+                f"{', '.join(unread)}: no layer of this model reads "
+                f"{'them' if len(unread) > 1 else 'it'} (set layer_types "
+                f"with a {kind!r} layer)")
 
 #: the collection of what a routed layer's training loop keeps beside
 #: its parameters: ``expert_bias`` (num_experts,) a layer
@@ -590,18 +696,21 @@ MOE_AUX_LOSS_SUM = "horovod_moe_aux_loss_total"
 MOE_MAX_EXPERT_TOKENS_SUM = "horovod_moe_max_expert_tokens_total"
 
 
-def _layer_sums(cfg, counts=0, aux_loss=0, max_expert_tokens=0, ssm=0):
+def _layer_sums(cfg, counts=0, aux_loss=0, max_expert_tokens=0, ssm=0,
+                kda=0):
     """What a layer hands up the stack beside its output, summed over
     the layers on the way: the routed layer's counts
     (``MOE_DEVICE_SUMS``), where the router has an auxiliary loss, that
-    loss and the busiest expert's tokens, and in a model with mamba
-    layers what their scans processed (``SSM_DEVICE_SUMS``).  The
-    defaults are the sums' zero."""
+    loss and the busiest expert's tokens, and in a model with mamba or
+    kda layers what their scans processed (``SSM_DEVICE_SUMS``,
+    ``KDA_DEVICE_SUMS``).  The defaults are the sums' zero."""
     sums = {"counts": counts}
     if cfg.router_aux_loss_coef:
         sums.update(aux_loss=aux_loss, max_expert_tokens=max_expert_tokens)
     if "mamba" in cfg.layer_types:
         sums["ssm"] = ssm
+    if "kda" in cfg.layer_types:
+        sums["kda"] = kda
     return sums
 
 
@@ -693,7 +802,8 @@ class RoutedExperts(nn.Module):
 
 class LayeredBlock(nn.Module):
     """One layer of a model whose layers differ: attention of this
-    layer's kind or the state-space mixer, then the dense SwiGLU or the
+    layer's kind, the state-space mixer or the delta rule, then the
+    dense SwiGLU or the
     routed experts (routed, under ``router_before_attention``, from the
     layer's input)."""
     cfg: TransformerConfig
@@ -716,12 +826,19 @@ class LayeredBlock(nn.Module):
                 # issued ahead of attention, under the routed layer's
                 # own scope ``moe/route`` all the same
                 routing = moe(x, route_only=True)
-        ssm = None
+        scanned = {}
         if self.layer_type == "mamba":
             # the module's name is the scope: ``attn`` would book the
             # mixer to the attention's device time
-            h, ssm = Mamba2Mixer(cfg, self.dot_general, name="mamba")(
-                norm("ln_mamba")(x))
+            h, scanned["ssm"] = Mamba2Mixer(
+                cfg, self.dot_general, name="mamba")(norm("ln_mamba")(x))
+        elif self.layer_type == "kda":
+            h, scanned["kda"] = KDAMixer(
+                cfg, self.dot_general, name="kda")(norm("ln_kda")(x))
+        elif self.layer_type == "mla":
+            with jax.named_scope("mla"):
+                h = MLAAttention(cfg, self.attention_fn, self.dot_general,
+                                 name="attn")(norm("ln_attn")(x))
         else:
             # the device trace's path carries the layer's published kind
             with jax.named_scope(self.layer_type):
@@ -740,9 +857,11 @@ class LayeredBlock(nn.Module):
                              name="mlp")(m), _layer_sums(
                 cfg, jnp.zeros((len(MOE_DEVICE_SUMS),), jnp.int32),
                 jnp.float32(0), jnp.int32(0))
-        if "ssm" in sums:
-            sums["ssm"] = jnp.zeros((len(SSM_DEVICE_SUMS),), jnp.int32) \
-                if ssm is None else ssm
+        for key, names in (("ssm", SSM_DEVICE_SUMS),
+                           ("kda", KDA_DEVICE_SUMS)):
+            if key in sums:
+                sums[key] = scanned[key] if key in scanned \
+                    else jnp.zeros((len(names),), jnp.int32)
         if cfg.sandwich_norm:
             f = norm("ln_post_mlp")(f)
         return _residual(cfg, x, f), sums
@@ -797,8 +916,11 @@ def _with_remat(block, cfg, prevent_cse=False):
     # forward kernel's time; the names exist only where the attention
     # inner is the flash kernel.  A model with mamba layers keeps their
     # scans' outputs and chunk states (2 x 67 MB a layer at 8,192
-    # tokens); no other program sees those names
-    scans = SSD_KEPT if "mamba" in (cfg.layer_types or ()) else ()
+    # tokens), one with kda layers theirs (134 + 268 MB a layer at
+    # 16,384 tokens); no other program sees those names
+    kinds = cfg.layer_types or ()
+    scans = (SSD_KEPT if "mamba" in kinds else ()) \
+        + (KDA_KEPT if "kda" in kinds else ())
     kernels = FLASH_KEPT + scans
     dots = kernels + routed     # one policy under two names
     kept = {"full": kernels, "dots": dots, "dots_flash": dots}
@@ -896,10 +1018,11 @@ def _layered(module, x, angles):
     if "sliding_attention" in kinds and not cfg.sliding_window:
         raise ValueError("sliding_attention layers need "
                          "sliding_window")
-    if "mamba" in kinds and cfg.total_ut_steps > 1:
+    if {"mamba", "kda"} & set(kinds) and cfg.total_ut_steps > 1:
         raise ValueError(
-            "a looped model (total_ut_steps > 1) has no mamba layers: "
-            "the device's sums count one pass")
+            "a looped model (total_ut_steps > 1) has no mamba or kda "
+            "layers: the device's sums count one pass")
+    _check_keys_of_kinds(cfg)
     if cfg.num_experts:
         _check_router(cfg)
     groups = [("dense_layers", kinds[:lead], False),
@@ -1006,6 +1129,8 @@ class TransformerLM(nn.Module):
                 if cfg.router_aux_loss_coef else ())
         if "mamba" in cfg.layer_types:
             names += SSM_DEVICE_SUMS
+        if "kda" in cfg.layer_types:
+            names += KDA_DEVICE_SUMS
         return names
 
     @property
@@ -1077,9 +1202,11 @@ class TransformerLM(nn.Module):
             if self.routed_layers:
                 for name, value in zip(MOE_DEVICE_SUMS, sums["counts"]):
                     device_sums.add(name, value)
-            if "ssm" in sums:
-                for name, value in zip(SSM_DEVICE_SUMS, sums["ssm"]):
-                    device_sums.add(name, value)
+            for key, names in (("ssm", SSM_DEVICE_SUMS),
+                               ("kda", KDA_DEVICE_SUMS)):
+                if key in sums:
+                    for name, value in zip(names, sums[key]):
+                        device_sums.add(name, value)
             if cfg.router_aux_loss_coef and self.routed_layers:
                 device_sums.add_fraction(MOE_AUX_LOSS_SUM, sums["aux_loss"])
                 device_sums.add(MOE_MAX_EXPERT_TOKENS_SUM,
@@ -1093,6 +1220,7 @@ class TransformerLM(nn.Module):
                     "sandwich_norm, mup_enabled, num_dense_layers, "
                     "num_shared_experts and num_experts_held belong "
                     "to a model with layer_types")
+            _check_keys_of_kinds(cfg)
             stack = _stack(self, DecoderBlock, "layers", cfg.n_layers,
                            remat=True, cache=0)(
                 cfg, self.attention_fn, decode, name="layers")

@@ -507,10 +507,48 @@ def _flash(qf, kf, vf, block_q, block_k, bwd_block_q, bwd_block_k,
     return out
 
 
+#: the compiler's default scoped VMEM limit: a kernel that asks for no
+#: more passes no ``vmem_limit_bytes``
+_VMEM_DEFAULT_BYTES = 16 << 20
+
+
+def _flash_fwd_vmem_bytes(S, D, block_q, block_k, q_dtype, kv_dtype):
+    """An upper bound, from the shapes, on what the forward kernel holds
+    in VMEM: k and v whole and the q / out blocks (each double-buffered
+    by the pipeline; a row fills whole 128-lane tiles), the lse row, the
+    body's two score-shaped pairs and its (block_q, D) accumulator, and
+    2 MiB to spare.  S 8192 at D 128 or 64 in bfloat16 (the widest call
+    of every cell before heads wider than 128) gives 15.0 MiB, under
+    the compiler's default."""
+    lanes = -(-D // 128) * 128
+    held = 2 * 2 * S * lanes * jnp.dtype(kv_dtype).itemsize \
+        + 2 * 2 * block_q * lanes * jnp.dtype(q_dtype).itemsize \
+        + 2 * 8 * block_q * 4
+    body = 4 * block_q * block_k * 4 + 2 * block_q * lanes * 4
+    return held + body + (2 << 20)
+
+
 def _flash_fwd_call(qf, kf, vf, block_q, block_k, window,
                     interpret):
     BH, S, D = qf.shape
     scale = 1.0 / np.sqrt(D)
+    # k and v of a (batch, head) wider or longer than the default limit
+    # holds (S 8192 at D 192: latent attention's keys): ask for what the
+    # shapes need, as the backward kernel does.  Under the default the
+    # call is what it was
+    vmem = _flash_fwd_vmem_bytes(S, D, block_q, block_k, qf.dtype,
+                                 kf.dtype)
+    more = {}
+    if not interpret and vmem > _VMEM_DEFAULT_BYTES:
+        if vmem > _VMEM_USABLE_BYTES:
+            raise ValueError(
+                f"flash_attention: the forward kernel keeps k and v of "
+                f"one (batch, head) in VMEM and asks {vmem} bytes for "
+                f"S={S}, D={D}, {qf.dtype.name}; a chip has "
+                f"{_VMEM_USABLE_BYTES} to give: shard the sequence "
+                f"(parallel/ring_attention.py) or shorten it")
+        more["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem)
     out, lse = _named_kernel(
         "flash_fwd",
         functools.partial(_flash_kernel, block_k=block_k, scale=scale,
@@ -525,7 +563,7 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, window,
         ],
         out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))),
-        interpret=interpret,
+        interpret=interpret, **more,
     )(qf, kf, vf)
     return out, lse
 
@@ -560,7 +598,7 @@ def _flash_bwd_vmem_bytes(S, D, block_q, block_k, dtype):
     bf16 at 27 MiB where this gives 36.8, and S 32768 at 99 where this
     gives 108.8."""
     item = jnp.dtype(dtype).itemsize
-    lanes = max(D, 128)                    # the lanes a row occupies
+    lanes = -(-D // 128) * 128             # a row fills whole lane tiles
     held = 4 * 2 * S * lanes * item + 2 * S * lanes * 4 \
         + 3 * 2 * block_q * lanes * item + 2 * 2 * 8 * block_q * 4
     body = 8 * block_q * block_k * 4 \

@@ -68,6 +68,11 @@ _QKV_S8K_H28 = [((2, 8192, 28, 128), jnp.bfloat16)] * 3
 # ... Granite-4.0-H's one attention layer: 1 x 8192 tokens, 32 heads of
 # 64 (half a lane tile in the transposed score layout)
 _QKV_S8K_D64 = [((1, 8192, 32, 64), jnp.bfloat16)] * 3
+# ... Kimi-Linear's one latent-attention layer: 2 x 8192 tokens, 32
+# heads whose queries and keys are 192 wide (the values go in filled up
+# to 192): k and v whole pass the compiler's default 16 MiB of scoped
+# VMEM, so both kernels ask for what their shapes need
+_QKV_S8K_D192 = [((2, 8192, 32, 192), jnp.bfloat16)] * 3
 # ... and its s4k cells (4 x 4096 tokens; the 4096 window does not bind)
 _QKV_S4K = [((4, 4096, 32, 128), jnp.bfloat16)] * 3
 
@@ -139,6 +144,8 @@ def _kernel_cases():
         "flash_fwd_s8k_h28_w4096": (_flash(window=4096), _QKV_S8K_H28, 1),
         "flash_fwd_s8k_d64": (_flash(), _QKV_S8K_D64, 1),
         "flash_bwd_s8k_d64": (_grad_of_sum(_flash(), 3), _QKV_S8K_D64, 2),
+        "flash_fwd_s8k_d192": (_flash(), _QKV_S8K_D192, 1),
+        "flash_bwd_s8k_d192": (_grad_of_sum(_flash(), 3), _QKV_S8K_D192, 2),
         "flash_bwd": (_grad_of_sum(_flash(), 3), _QKV, 2),
         "flash_window_bwd": (
             _grad_of_sum(_flash(window=1024), 3), _QKV_LONG, 2),
@@ -351,6 +358,7 @@ def test_hybrid_cell_fits_with_its_scans_in_their_kernels(
     "flash_bwd", "flash_window_bwd", "flash_bwd_s8k",
     "flash_bwd_s8k_w4096", "flash_bwd_s8k_w2048", "flash_bwd_s8k_h28",
     "flash_bwd_s8k_h28_w4096", "flash_fwd_s8k_d64", "flash_bwd_s8k_d64",
+    "flash_fwd_s8k_d192", "flash_bwd_s8k_d192",
     "ssd_fwd_s8k", "ssd_bwd_s8k", "ssd_fwd_s128", "ssd_bwd_s128",
     "ssd_bwd_s8k_f32", "ssd_bwd_s1k_g2",
     "paged_decode", "paged_decode_nb1", "paged_decode_kv16_d256",
